@@ -10,7 +10,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_CHAOS_SEED="${BENCH_CHAOS_SEED:-20260804}"
 export BENCH_CHAOS_CLIENTS="${BENCH_CHAOS_CLIENTS:-4}"
 export BENCH_CHAOS_SECS="${BENCH_CHAOS_SECS:-12}"

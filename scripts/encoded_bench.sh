@@ -10,7 +10,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_ENCODED_SF="${BENCH_ENCODED_SF:-0.05}"
 export BENCH_ENCODED_ITERS="${BENCH_ENCODED_ITERS:-3}"
 

@@ -10,7 +10,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_FLEET_SERVERS="${BENCH_FLEET_SERVERS:-4}"
 export BENCH_FLEET_CLIENTS="${BENCH_FLEET_CLIENTS:-4}"
 export BENCH_FLEET_ROUNDS="${BENCH_FLEET_ROUNDS:-1}"

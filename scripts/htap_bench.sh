@@ -8,7 +8,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_HTAP_ROWS="${BENCH_HTAP_ROWS:-40000}"
 export BENCH_HTAP_SECS="${BENCH_HTAP_SECS:-4}"
 export BENCH_HTAP_RATES="${BENCH_HTAP_RATES:-0,20,100}"

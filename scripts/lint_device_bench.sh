@@ -12,7 +12,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_LINTCHECK_SF="${BENCH_LINTCHECK_SF:-0.02}"
 export BENCH_LINTCHECK_ITERS="${BENCH_LINTCHECK_ITERS:-2}"
 

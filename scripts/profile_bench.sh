@@ -10,7 +10,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_PROFILE_SF="${BENCH_PROFILE_SF:-0.02}"
 export BENCH_PROFILE_ITERS="${BENCH_PROFILE_ITERS:-3}"
 

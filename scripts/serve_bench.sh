@@ -8,7 +8,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_SERVE_CLIENTS="${BENCH_SERVE_CLIENTS:-4}"
 export BENCH_SERVE_ROUNDS="${BENCH_SERVE_ROUNDS:-1}"
 export BENCH_SERVE_LOOKUPS="${BENCH_SERVE_LOOKUPS:-4}"

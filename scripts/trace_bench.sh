@@ -10,7 +10,6 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export BENCH_TRACE_SF="${BENCH_TRACE_SF:-0.02}"
 export BENCH_TRACE_ITERS="${BENCH_TRACE_ITERS:-3}"
 export BENCH_TRACE_LOOKUPS="${BENCH_TRACE_LOOKUPS:-16}"

@@ -1,18 +1,13 @@
-"""Warm-run persistent-compile-cache regression (ISSUE 6 satellite).
+"""Persistent compile cache: placed from outside, fixed otherwise, warm
+across processes.
 
-BENCH r05 showed Q1 `first_run_secs: 48.82` DESPITE the persistent XLA
-cache from PR 3 — the bench's CPU-fallback path disabled the cache
-outright (to avoid loading AOT entries compiled for a different
-virtualized feature set), so every bench process re-paid the first
-compile. The fix scopes the cache to a per-host-feature-set CPU
-subdirectory (`util/compile_cache.scoped_cpu_dir`) instead of
-disabling it. Pinned here:
-
-  * the scoping helper is stable, distinct from the base dir, and
-    distinct per feature set;
-  * the bench-level contract — a SECOND process over the same scoped
-    cache dir reports compile-cache misses == 0 (everything loads from
-    disk) and at least one hit.
+The contract (`util/compile_cache.py`): with `JAX_COMPILATION_CACHE_DIR`
+set no code sets `jax_compilation_cache_dir` — before or after a mesh
+change; unset, the directory is `<checkout>/.jax_cache` for every
+process and every mesh size; and a SECOND process over the same
+directory reports misses == 0 (everything loads from disk) and at
+least one hit. Plus the start-up pin that rides the same children: a
+server asked for a larger mesh than the host has does not start.
 """
 
 import json
@@ -20,19 +15,29 @@ import os
 import subprocess
 import sys
 
-from tidb_tpu.util import compile_cache
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_PROG = r"""
-import json, os
-from tidb_tpu.util import compile_cache
-# the package enables the cache at import with the production 1s
-# min-compile floor; this probe's programs compile in ms, so lower the
-# floor to catch them (bench's real Q1 program is far above the floor)
-compile_cache.enable(min_compile_secs=0.0)
+_ENV8 = dict(JAX_PLATFORMS="cpu",
+             XLA_FLAGS="--xla_force_host_platform_device_count=8")
+
+_DIR_PROG = r"""
+import json
+import jax
+import tidb_tpu
+from tidb_tpu import devplane
+before = jax.config.jax_compilation_cache_dir
+devplane.enable_mesh(8)
+during = jax.config.jax_compilation_cache_dir
+devplane.disable_mesh()
+print("DIRS " + json.dumps([before, during,
+                            jax.config.jax_compilation_cache_dir]))
+"""
+
+_WARM_PROG = r"""
+import json
 import jax
 import jax.numpy as jnp
+from tidb_tpu.util import compile_cache
 
 @jax.jit
 def f(x):
@@ -43,43 +48,53 @@ print("STATS " + json.dumps(compile_cache.stats()))
 """
 
 
-def test_scoped_cpu_dir_stable_and_distinct():
-    base = os.path.join("/tmp", "cc-base")
-    d1 = compile_cache.scoped_cpu_dir(base)
-    assert d1 == compile_cache.scoped_cpu_dir(base)     # deterministic
-    assert d1.startswith(os.path.join(base, "cpu-"))
-    assert len(os.path.basename(d1)) == len("cpu-") + 12
-    # the tag really fingerprints the feature set (arch+jax+cpu flags)
-    assert compile_cache.cpu_feature_tag() == \
-        compile_cache.cpu_feature_tag()
-
-
-def _run(cache_dir: str) -> dict:
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               TIDB_TPU_COMPILE_CACHE=cache_dir,
-               JAX_COMPILATION_CACHE_DIR=cache_dir,
-               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
-    proc = subprocess.run([sys.executable, "-c", _PROG],
+def _child(prog: str, tag: str, **env):
+    full = dict(os.environ, **_ENV8)
+    full.pop("JAX_COMPILATION_CACHE_DIR", None)
+    full.update(env)
+    proc = subprocess.run([sys.executable, "-c", prog],
                           capture_output=True, text=True, timeout=240,
-                          env=env, cwd=REPO)
+                          env=full, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     for line in proc.stdout.splitlines():
-        if line.startswith("STATS "):
-            return json.loads(line[len("STATS "):])
-    raise AssertionError(f"no STATS line in: {proc.stdout!r}")
+        if line.startswith(tag + " "):
+            return json.loads(line[len(tag) + 1:])
+    raise AssertionError(f"no {tag} line in: {proc.stdout!r}")
+
+
+def test_env_var_places_the_cache_across_mesh_changes(tmp_path):
+    want = str(tmp_path)
+    assert _child(_DIR_PROG, "DIRS",
+                  JAX_COMPILATION_CACHE_DIR=want) == [want] * 3
+
+
+def test_default_dir_is_the_checkout_for_every_mesh_size():
+    assert _child(_DIR_PROG, "DIRS") == \
+        [os.path.join(REPO, ".jax_cache")] * 3
 
 
 def test_warm_run_compile_cache_misses_zero(tmp_path):
-    """The bench regression pin: process 1 compiles into the scoped
-    dir; process 2 (the 'warm bench run') must load everything —
-    misses == 0 — exactly what kills the 48.8s Q1 first-run stall."""
-    scoped = compile_cache.scoped_cpu_dir(str(tmp_path))
-    cold = _run(scoped)
-    assert cold["dir"] == scoped          # cache ENABLED, not poisoned
+    """Process 1 compiles into the directory; process 2 must load
+    everything — misses == 0. The probe's program compiles in ms, so
+    the children lower jax's 1s persistence floor through jax's own
+    environment variable."""
+    env = dict(JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    cold = _child(_WARM_PROG, "STATS", **env)
+    assert cold["dir"] == str(tmp_path)
     assert cold["misses"] >= 1            # really compiled
     assert cold["entries"] >= 1           # really persisted
-    warm = _run(scoped)
-    assert warm["dir"] == scoped
-    assert warm["misses"] == 0, warm      # the whole point of the fix
+    warm = _child(_WARM_PROG, "STATS", **env)
+    assert warm["dir"] == str(tmp_path)
+    assert warm["misses"] == 0, warm
     assert warm["hits"] >= 1, warm
+
+
+def test_server_refuses_a_mesh_larger_than_the_host():
+    proc = subprocess.run(
+        [sys.executable, "-m", "tidb_tpu", "--mesh", "16", "-P", "0",
+         "--no-status"],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, **_ENV8), cwd=REPO)
+    assert proc.returncode != 0, proc.stdout + proc.stderr
+    assert "16 devices requested but only 8 visible" in proc.stderr

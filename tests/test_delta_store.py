@@ -173,6 +173,45 @@ class TestDeltaMVCC:
         assert not engine.locked_in_range(s, e,
                                           sess.storage.current_ts())
 
+    def test_lease_commits_do_not_veto_cache_fills(self, sess):
+        """A live server's lease workers commit ephemeral meta keys
+        about once a second. They lie in no table range, so they move
+        neither max_commit_ts nor the cache-veto lock set: a snapshot
+        taken BEFORE such a commit still fills the chunk cache (it
+        used to lose that race on every scan longer than one tick, so
+        a serving process never warmed a real-size region)."""
+        from tidb_tpu.kv import Mutation, MutationOp
+        total = _load(sess, "t")
+        st = sess.storage
+        engine, cc = st.engine, st.chunk_cache
+        sess.execute("BEGIN")
+        sess.query("SELECT 1")          # pins the snapshot ts
+        mc0 = engine.max_commit_ts
+        lease = st.begin()
+        lease.set(b"m_owner_ddl", b"lease")
+        lease.commit()
+        assert engine.max_commit_ts == mc0
+        ts = st.current_ts()
+        engine.prewrite([Mutation(MutationOp.PUT, b"m_member_x", b"hb")],
+                        b"m_member_x", ts, ttl_ms=30000)
+        assert not engine._locked_keys      # pending, but vetoes nothing
+        assert sess.query("SELECT SUM(v) FROM t").rows[0][0] == total
+        engine.rollback([b"m_member_x"], ts)
+        sess.execute("COMMIT")
+        assert len(cc._entries) >= 1        # the older snapshot filled
+        hits0 = cc.hits
+        assert sess.query("SELECT SUM(v) FROM t").rows[0][0] == total
+        assert cc.hits > hits0
+        # an auto-analyze save is the same class: statistics, no row
+        dv0 = engine.data_version
+        sess.execute("ANALYZE TABLE t")
+        assert engine.data_version == dv0
+        assert sess.query("SELECT SUM(v) FROM t").rows[0][0] == total
+        assert cc.hits > hits0 + 1
+        # a ROW commit still moves the fill contract's watermark
+        sess.execute("UPDATE t SET v = v WHERE id = 1")
+        assert engine.max_commit_ts > mc0
+
     def test_index_commit_invalidates_index_entries_only(self, sess):
         _load(sess, "a")
         sess.execute("CREATE TABLE ix (id BIGINT PRIMARY KEY, "

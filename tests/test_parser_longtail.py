@@ -373,18 +373,6 @@ class TestThirdReviewRegressions:
                       "AFTER b2")
         s.close()
 
-    def test_pallas_dispatcher_1d_shape(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from tidb_tpu.ops import pallas_agg as pa
-        v = jnp.asarray(np.ones(10, dtype=np.float32))
-        ids = jnp.asarray(np.zeros(10, dtype=np.int32))
-        out = pa.segment_sum(v, ids, 4)
-        assert out.ndim == 1 and out.shape[0] == 4
-        # the pallas path itself also squeezes via the dispatcher
-        out2 = pa.segment_sum_pallas(v, ids, 4, interpret=True)
-        assert out2.shape == (4, 1)      # raw kernel keeps the lane axis
-
 
 class TestMinedExprCases:
     """Harvested from the reference's executor test corpus (table-free

@@ -61,6 +61,51 @@ class TestAutoAnalyze:
         d.stop_stats_worker()
         d.stop_stats_worker()
 
+    def test_interrupted_analyze_raises_and_tick_keeps_table_pending(
+            self, sess):
+        from tidb_tpu.executor import ExecError
+        from tidb_tpu.statistics import analyze_table
+        sess.execute("CREATE TABLE x (id BIGINT PRIMARY KEY)")
+        sess.execute("INSERT INTO x VALUES (1), (2), (3)")
+        info = sess.domain.info_schema().table("d", "x")
+        with pytest.raises(ExecError, match="interrupted"):
+            analyze_table(sess.storage, sess.storage.current_ts(), info,
+                          interrupted=lambda: True)
+        # the scan passes; the per-column build sees the probe
+        probes = iter([False, False, True])
+        with pytest.raises(ExecError, match="interrupted"):
+            analyze_table(sess.storage, sess.storage.current_ts(), info,
+                          interrupted=lambda: next(probes, True))
+        assert sess.domain.auto_analyze_tick(lambda: True) == []
+        assert info.id in sess.domain.stats_handle().pending_tables()
+
+    def test_stop_cancels_analyze_in_flight_and_joins(self, sess,
+                                                      monkeypatch):
+        """Shutdown must not leave an ANALYZE running: the worker's
+        stop event reaches the analyze as its interrupt probe and
+        stop_stats_worker() returns only once the thread is gone."""
+        import threading
+
+        from tidb_tpu import statistics
+        from tidb_tpu.executor import ExecError
+        sess.execute("CREATE TABLE y (id BIGINT PRIMARY KEY)")
+        sess.execute("INSERT INTO y VALUES (1)")
+        entered = threading.Event()
+
+        def slow_analyze(_storage, _ts, _info, interrupted=None):
+            entered.set()
+            while not interrupted():
+                threading.Event().wait(0.01)
+            raise ExecError("Query execution was interrupted")
+
+        monkeypatch.setattr(statistics, "analyze_table", slow_analyze)
+        d = sess.domain
+        d.start_stats_worker(interval=0.01)
+        thread = d._stats_thread
+        assert entered.wait(timeout=30)
+        d.stop_stats_worker()
+        assert not thread.is_alive()
+
 
 class TestQueryFeedback:
     def _setup(self, sess, n=10000):

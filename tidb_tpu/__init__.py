@@ -45,9 +45,9 @@ _jax.config.update("jax_enable_x64", True)
 # (program, shape-bucket) and identical HLO must never recompile — not
 # across kernel instances, not across processes. Large-batch programs
 # cost tens of seconds of XLA compile; this turns them into disk hits.
-# util/compile_cache owns the wiring (directory from TIDB_TPU_COMPILE_CACHE
-# or ~/.cache/tidb_tpu_xla; "0" disables) and counts hits/misses for
-# bench.py / the server log.
+# util/compile_cache owns the wiring (JAX_COMPILATION_CACHE_DIR where
+# set, else <checkout>/.jax_cache) and counts hits/misses for
+# chip_smoke.py / bench.py / the server log.
 from tidb_tpu.util import compile_cache as _compile_cache
 
 _compile_cache.enable()

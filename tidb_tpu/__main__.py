@@ -52,6 +52,106 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+class Running:
+    """What `start()` brought up; `close()` tears it down in reverse."""
+
+    def __init__(self, storage, server, status):
+        self.storage = storage
+        self.server = server
+        self.status = status
+
+    def close(self) -> None:
+        if self.status is not None:
+            from tidb_tpu import member
+            member.stop_heartbeat()
+            self.status.close()
+        self.server.close()
+        self.storage.close()
+
+
+def start(*, host: str = "127.0.0.1", port: int = 4000,
+          status_port: int | None = 10080, mesh: int | None = None,
+          no_mesh: bool = False, token_limit: int = 1000,
+          store: str | None = None) -> Running:
+    """Bring up the device plane, storage, the MySQL wire server and
+    (unless status_port is None) the HTTP status server — THE start-up
+    path: `python -m tidb_tpu` and chip_smoke.py both call it, so the
+    smoke cannot drift from the entry point. Port 0 binds an ephemeral
+    port (read it back from `.server.port` / `.status.port`).
+
+    A process that cannot see its devices does not start: no backend,
+    or fewer devices than `mesh`, raises. Host-only serving is only
+    what was asked for — `no_mesh` together with tidb_tpu_device=0 —
+    and never a consequence of a failed device bring-up."""
+    log = logging.getLogger("tidb_tpu.server")
+    from tidb_tpu import config
+    from tidb_tpu.util import compile_cache
+    cc = compile_cache.stats()
+    log.info("XLA compile cache: %s (%s entries)", cc["dir"],
+             cc["entries"])
+    # the kernel profiling plane rides every dispatch; say up front
+    # whether it is armed and how much history it may keep
+    from tidb_tpu import profiler
+    ks = profiler.stats()
+    log.info("kernel profiler: %s (cap %d profiles, compile-cache "
+             "hits=%d misses=%d)",
+             "on" if ks["enabled"] else "off", ks["cap"],
+             cc["hits"], cc["misses"])
+    log.info("serving: scheduler inflight=%d (bytes gate %d), "
+             "server mem quota=%d (admission %s, timeout %dms)",
+             config.sched_inflight(), config.sched_inflight_bytes(),
+             config.server_mem_quota(),
+             "on" if config.server_mem_quota() else "off",
+             config.admission_timeout_ms())
+
+    from tidb_tpu import devplane
+    if no_mesh and not config.device_enabled():
+        log.info("devices: none requested (--no-mesh, tidb_tpu_device=0)"
+                 "; host execution only")
+    else:
+        import jax
+        devs = jax.devices()
+        log.info("devices: platform=%s device_kind=%s count=%d",
+                 devs[0].platform, devs[0].device_kind, len(devs))
+    if no_mesh:
+        devplane.disable_mesh()
+    else:
+        devplane.enable_mesh(mesh)
+        log.info("device mesh: %s", devplane.active_mesh().devices.shape)
+
+    from tidb_tpu.server import Server
+    from tidb_tpu.server.status import StatusServer
+
+    if store:
+        from tidb_tpu.store.remote import connect
+        h, _, pt = store.rpartition(":")
+        storage = connect(h or "127.0.0.1", int(pt), local_cache=True)
+        log.info("fleet mode: store plane at %s", store)
+    else:
+        from tidb_tpu.store.storage import new_mock_storage
+        storage = new_mock_storage()
+    server = Server(storage, host=host, port=port,
+                    token_limit=token_limit)
+    server.start()
+    log.info("MySQL protocol on %s:%d", host, server.port)
+    status = None
+    if status_port is not None:
+        status = StatusServer(storage, server, host=host,
+                              port=status_port)
+        status.start()
+        log.info("status API on %s:%d", host, status.port)
+        # fleet membership (tidb_tpu/member.py): identity = the status
+        # port peers fan cluster_* queries out to, so registration is
+        # tied to the status server being up. The heartbeat publishes
+        # through whichever storage this process uses — the shared
+        # store plane in fleet mode, the in-process store standalone
+        # (where this member is then the whole visible fleet).
+        from tidb_tpu import member
+        member.set_identity(host, status.port, "sql")
+        member.start_heartbeat(storage)
+    return Running(storage, server, status)
+
+
 def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
@@ -88,70 +188,11 @@ def main(argv=None) -> int:
         name, _, val = kv.partition("=")
         config.set_var(name, val)
 
-    # the package import already pointed jax at the persistent compile
-    # cache; surface where (first-compile stalls vanish on warm starts)
-    from tidb_tpu.util import compile_cache
-    cc = compile_cache.stats()
-    log.info("XLA compile cache: %s (%s entries)",
-             cc["dir"] or "disabled", cc["entries"])
-    # the kernel profiling plane rides every dispatch; say up front
-    # whether it is armed and how much history it may keep
-    from tidb_tpu import profiler
-    ks = profiler.stats()
-    log.info("kernel profiler: %s (cap %d profiles, compile-cache "
-             "hits=%d misses=%d)",
-             "on" if ks["enabled"] else "off", ks["cap"],
-             compile_cache.counters()["hits"],
-             compile_cache.counters()["misses"])
-    log.info("serving: scheduler inflight=%d (bytes gate %d), "
-             "server mem quota=%d (admission %s, timeout %dms)",
-             config.sched_inflight(), config.sched_inflight_bytes(),
-             config.server_mem_quota(),
-             "on" if config.server_mem_quota() else "off",
-             config.admission_timeout_ms())
-
-    from tidb_tpu import devplane as mesh_config
-    if args.no_mesh:
-        mesh_config.disable_mesh()
-    else:
-        try:
-            mesh_config.enable_mesh(args.mesh)
-            mesh = mesh_config.active_mesh()
-            log.info("device mesh: %s", mesh.devices.shape
-                     if mesh is not None else None)
-        except Exception as e:  # noqa: BLE001 - no devices is survivable
-            log.warning("mesh unavailable (%s); host execution only", e)
-
-    from tidb_tpu.server import Server
-    from tidb_tpu.server.status import StatusServer
-
-    if args.store:
-        from tidb_tpu.store.remote import connect
-        h, _, pt = args.store.rpartition(":")
-        storage = connect(h or "127.0.0.1", int(pt), local_cache=True)
-        log.info("fleet mode: store plane at %s", args.store)
-    else:
-        from tidb_tpu.store.storage import new_mock_storage
-        storage = new_mock_storage()
-    server = Server(storage, host=args.host, port=args.port,
-                    token_limit=args.token_limit)
-    server.start()
-    log.info("MySQL protocol on %s:%d", args.host, server.port)
-    status = None
-    if not args.no_status:
-        status = StatusServer(storage, server, host=args.host,
-                              port=args.status_port)
-        status.start()
-        log.info("status API on %s:%d", args.host, status.port)
-        # fleet membership (tidb_tpu/member.py): identity = the status
-        # port peers fan cluster_* queries out to, so registration is
-        # tied to the status server being up. The heartbeat publishes
-        # through whichever storage this process uses — the shared
-        # store plane in fleet mode, the in-process store standalone
-        # (where this member is then the whole visible fleet).
-        from tidb_tpu import member
-        member.set_identity(args.host, status.port, "sql")
-        member.start_heartbeat(storage)
+    running = start(host=args.host, port=args.port,
+                    status_port=None if args.no_status
+                    else args.status_port,
+                    mesh=args.mesh, no_mesh=args.no_mesh,
+                    token_limit=args.token_limit, store=args.store)
 
     stop = threading.Event()
 
@@ -162,12 +203,7 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, _on_signal)
     stop.wait()
     log.info("shutting down")
-    if status is not None:
-        from tidb_tpu import member
-        member.stop_heartbeat()
-        status.close()
-    server.close()
-    storage.close()
+    running.close()
     return 0
 
 

@@ -36,17 +36,14 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:        # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
+from jax import shard_map as _shard_map_fn
 
 __all__ = [
     "AXIS", "build_mesh", "configure_mesh", "enable_mesh", "disable_mesh",
     "active_mesh", "mesh_generation", "on_topology_change", "ndev",
     "batch_spec", "replicated_spec", "batch_sharding", "replicated",
     "chip_device", "chip_scope", "mesh_fingerprint", "shard_map",
-    "plane_jit",
+    "pmax", "plane_jit",
 ]
 
 #: the one data-parallel axis name of the device plane
@@ -60,10 +57,18 @@ _listeners: list = []
 # -- construction ----------------------------------------------------------
 
 def build_mesh(n_devices: int | None = None, devices=None) -> Mesh:
-    """A 1-D ``("batch",)`` mesh over the first n_devices jax devices."""
+    """A 1-D ``("batch",)`` mesh over the first n_devices jax devices.
+    Asking for more devices than are visible raises: a plane silently
+    smaller than the one requested would run, and be measured, as if
+    it were the requested one."""
     if devices is None:
         devices = jax.devices()
     if n_devices is not None:
+        if n_devices > len(devices):
+            raise RuntimeError(
+                f"mesh of {n_devices} devices requested but only "
+                f"{len(devices)} visible "
+                f"(platform {devices[0].platform})")
         devices = devices[:n_devices]
     return Mesh(np.array(devices), axis_names=(AXIS,))
 
@@ -175,13 +180,19 @@ def mesh_fingerprint(mesh: Mesh | None = None, *,
 
 def shard_map(fn, mesh: Mesh, in_specs, out_specs):
     """shard_map with replication checking off (our kernels mix manually
-    replicated scalars with sharded lanes), spanning the jax spelling
-    change (check_vma vs the older check_rep)."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map_fn(fn, check_vma=False, **kwargs)
-    except TypeError:       # older jax spells it check_rep
-        return _shard_map_fn(fn, check_rep=False, **kwargs)
+    replicated scalars with sharded lanes)."""
+    return _shard_map_fn(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def pmax(x, axes=(AXIS,)):
+    """Cross-chip maximum of `x` over `axes`, as all_gather + a local
+    max. Every lane of the plane is int64, which the TPU emulates, and
+    XLA:TPU lowers only the SUM all-reduce for emulated types
+    ("UNIMPLEMENTED: Supported lowering only of Sum all reduce" — what
+    `lax.pmax` on an s64 scalar answered on four v5e chips); all_gather
+    moves bytes and lowers for any type."""
+    return jax.numpy.max(jax.lax.all_gather(x, axes), axis=0)
 
 
 def plane_jit(fn, **kwargs):

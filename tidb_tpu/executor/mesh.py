@@ -31,7 +31,8 @@ from tidb_tpu import config as sysconf
 from tidb_tpu import devplane, memtrack, profiler, runtime_stats, sched, trace
 from tidb_tpu.chunk import Chunk, Column
 from tidb_tpu.ops import runtime as op_runtime
-from tidb_tpu.ops.hashagg import CapacityError, CollisionError, HashAggregator
+from tidb_tpu.ops.hashagg import (CapacityError, CollisionError,
+                                  DeviceRejectError, HashAggregator)
 from tidb_tpu.ops.hostagg import host_hash_agg
 from tidb_tpu.ops.meshagg import MeshAggKernel
 from tidb_tpu.ops.meshjoin import (BuildError, LookupSpec,
@@ -247,7 +248,7 @@ class _MeshExecBase:
                 capacity = 1 << max(needed * 2 - 1, 1).bit_length()
                 if capacity > MAX_CAPACITY:
                     return None
-            except (CollisionError, BuildError, ValueError) as e:
+            except (CollisionError, BuildError, DeviceRejectError) as e:
                 profiler.note_kernel_fallback(profiler.profile_of(kernel),
                                               _fallback_reason(e))
                 return None
@@ -275,7 +276,7 @@ class _MeshExecBase:
         try:
             state["kernel"] = get_kernel(
                 getattr(plan, "_mesh_capacity", DEFAULT_CAPACITY))
-        except (ValueError, BuildError):
+        except (DeviceRejectError, BuildError):
             state["kernel"] = None      # every batch goes host
 
         def dispatch(sc):
@@ -295,7 +296,7 @@ class _MeshExecBase:
             memtrack.consume(plan, device=db)
             try:
                 outs = k.launch(batch, bucket=True)
-            except (ValueError, CollisionError, BuildError) as e:
+            except (DeviceRejectError, CollisionError, BuildError) as e:
                 memtrack.release(plan, device=db)
                 runtime_stats.note_fallback(plan, _fallback_reason(e))
                 return None
@@ -341,10 +342,11 @@ class _MeshExecBase:
                         return gr
                     except CapacityError as e2:
                         needed = getattr(e2, "needed", None)
-                    except (CollisionError, BuildError, ValueError) as e2:
+                    except (CollisionError, BuildError,
+                            DeviceRejectError) as e2:
                         reason = _fallback_reason(e2)
                         break
-            except (CollisionError, BuildError, ValueError) as e:
+            except (CollisionError, BuildError, DeviceRejectError) as e:
                 reason = _fallback_reason(e)
             finally:
                 memtrack.release(plan, device=db)

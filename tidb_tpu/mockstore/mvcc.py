@@ -41,10 +41,19 @@ __all__ = ["MVCCStore", "WriteType", "physical_ms",
 # must NOT bump data_version — one heartbeat (or id-batch refill)
 # would otherwise invalidate every columnar chunk-cache and HBM-cache
 # entry, keeping both caches permanently cold exactly when the server
-# is serving. max_commit_ts and the lock set still advance/track for
-# these keys, so the MVCC fill contract is untouched.
+# is serving. For the same reason they move neither max_commit_ts nor
+# the cache-veto lock set: both exist to keep a cache FILL from
+# recording a table range's state before the newest commit (or pending
+# lock) that could be inside it, and these keys lie in no table range.
+# Letting them count made every fill race the lease workers — a scan
+# that outlasted one tick (any real-size region) was never cached, so
+# the warm path never engaged on a serving process. Reads of the keys
+# themselves still see their locks (scan/get check the entry's lock).
+# Table statistics blobs (statistics._STATS_PREFIX) are in the same
+# class: an auto-analyze save changes what the planner estimates, which
+# the plan cache keys on separately (stats version), and no decoded row.
 EPHEMERAL_PREFIXES = (b"m_owner_", b"m_schema_sync_", b"m_member_",
-                      b"msAutoID:")
+                      b"msAutoID:", b"m_stats/")
 
 
 # key classes for the delta-capture path (store/delta.py): committed
@@ -346,7 +355,8 @@ class MVCCStore:
             for m in mutations:
                 e = self._entry(m.key)
                 e.lock = _Lock(primary, start_ts, ttl_ms, m.op, m.value)
-                self._locked_keys.add(m.key)
+                if not m.key.startswith(EPHEMERAL_PREFIXES):
+                    self._locked_keys.add(m.key)
 
     def commit(self, keys: list[bytes], start_ts: int, commit_ts: int) -> None:
         """Ref: mvcc_leveldb.go Commit — idempotent for already-committed.
@@ -405,7 +415,8 @@ class MVCCStore:
 
     def _commit_locked(self, key: bytes, e: _Entry, start_ts: int,
                        commit_ts: int) -> None:
-        if commit_ts > self.max_commit_ts:
+        if commit_ts > self.max_commit_ts and \
+                not key.startswith(EPHEMERAL_PREFIXES):
             self.max_commit_ts = commit_ts
         lock = e.lock
         if lock.op == MutationOp.PUT:

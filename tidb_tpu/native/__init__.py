@@ -1,15 +1,19 @@
 """Native (C++) kernels, loaded via ctypes with graceful fallback.
 
 The shared library is compiled on first use with the system g++ (cached
-next to the source, keyed by source mtime) — no pybind11 or build step in
-the critical path; environments without a compiler simply run the pure-
-Python implementations. Ref: SURVEY.md §7 — the reference's storage-side
+next to the source, named by a hash of the source, so a library is only
+ever loaded for the exact source it was built from) — no pybind11 or
+build step in the critical path; environments without a compiler run
+the pure-Python implementations and log that they do (`decoder_kind()`
+says which is in use). Ref: SURVEY.md §7 — the reference's storage-side
 hot loops live in Rust TiKV; this is our C++ equivalent layer.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -17,7 +21,7 @@ from pathlib import Path
 
 import numpy as np
 
-__all__ = ["lib", "decode_rows_native", "scan_rows_native",
+__all__ = ["lib", "decoder_kind", "decode_rows_native", "scan_rows_native",
            "NATIVE_KIND_INT", "NATIVE_KIND_FLOAT", "NATIVE_KIND_DECIMAL",
            "NATIVE_KIND_HANDLE"]
 
@@ -32,13 +36,15 @@ _tried = False
 
 
 def _compile(name: str) -> ctypes.CDLL | None:
-    """Build native/<name>.cc into _build/<name>.so (mtime-cached) and
-    load it; None when no compiler / load failure."""
+    """Build native/<name>.cc into _build/<name>-<source hash>.so and
+    load it; None (logged) when there is no compiler or the build or
+    load fails."""
     src = Path(__file__).parent / f"{name}.cc"
     build_dir = Path(__file__).parent / "_build"
-    so = build_dir / f"{name}.so"
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    so = build_dir / f"{name}-{digest}.so"
     try:
-        if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
+        if not so.exists():
             build_dir.mkdir(exist_ok=True)
             tmp = so.with_suffix(".so.tmp%d" % os.getpid())
             subprocess.run(
@@ -47,7 +53,10 @@ def _compile(name: str) -> ctypes.CDLL | None:
                 check=True, capture_output=True, timeout=120)
             os.replace(tmp, so)
         return ctypes.CDLL(str(so))
-    except Exception:  # noqa: BLE001 - no compiler / load failure
+    except (OSError, subprocess.SubprocessError) as e:
+        logging.getLogger("tidb_tpu.native").warning(
+            "native %s unavailable (%s); using the pure-Python path",
+            name, e)
         return None
 
 
@@ -145,6 +154,12 @@ def lib() -> ctypes.CDLL | None:
             _lib = _build()
             _tried = True
     return _lib
+
+
+def decoder_kind() -> str:
+    """Which row decoder serves `table.kvrows_to_chunk`: "native" (the
+    compiled codec.cc) or "python" (no compiler / build failed)."""
+    return "native" if lib() is not None else "python"
 
 
 def decode_rows_native(kvrows, col_specs):

@@ -140,7 +140,7 @@ def group_merge_program(xp, cols, mask, ln, offs, group_exprs, aggs,
         muniq = xp.where((muniq == _SENTINEL_MASKED) &
                          (any_real != _I64_MIN) & (any_real != _FILL),
                          any_real, muniq)
-        tot = lax.pmax(local_tot, ax)
+        tot = devplane.pmax(local_tot, ax)
         merged = []
         _RED = {"sum": xp.sum, "min": xp.min, "max": xp.max}
         for lane, op in lanes:
@@ -156,7 +156,7 @@ def group_merge_program(xp, cols, mask, ln, offs, group_exprs, aggs,
     # gathered fill/sentinel slots can add up to 2 phantom values to
     # gtot relative to a single table; they are excluded on the host
     # via the live mask, and capacity is checked with slack for them
-    tot = xp.maximum(gtot, lax.pmax(local_tot, ax))
+    tot = xp.maximum(gtot, devplane.pmax(local_tot, ax))
     # batched re-reduce: stack same-(op,dtype) lanes, one all_gather +
     # one segment op per kind instead of one per lane
     groups: dict = {}

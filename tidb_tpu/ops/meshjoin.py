@@ -345,7 +345,7 @@ class MeshLookupAggKernel(MeshKernelBase):
         rid = xp.zeros(ln, dtype=jnp.int64).at[idx].set(row_ids,
                                                         mode="drop")
         smax = s_local if self.ndev == 1 else \
-            lax.pmax(s_local, (AXIS,))
+            devplane.pmax(s_local, (AXIS,))
         return tuple(compacted), live, rid, smax
 
     def _stage1(self, cols, nrows, build0):

@@ -87,8 +87,7 @@ def route_mesh(plan: ph.PhysPlan) -> ph.PhysPlan:
     sharding over one chip only adds gather/replication overhead, and it
     routes scans around the storage-side columnar caches (the copTask
     path serves repeated scans from the HBM device cache and fuses
-    scan->filter->partial-agg into one dispatch; measured 1.2-2.6x
-    faster warm on TPC-H Q1/Q3/Q5 than the 1-device mesh kernels). The
+    scan->filter->partial-agg into one dispatch). The
     decision depends only on the mesh itself, so plans stay coherent
     with the mesh_generation() plan-cache key."""
     from tidb_tpu import devplane as config

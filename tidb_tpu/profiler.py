@@ -465,8 +465,10 @@ class dispatch_section:
 # -- roofline (hoisted from bench.py — ONE estimator for bench and the
 # continuous in-server numbers) ---------------------------------------------
 
-# HBM peak per chip family (public figures, GB/s); the CPU fallback
-# measures its own memcpy bandwidth instead
+# HBM peak per chip, keyed by jax's device_kind (public datasheet
+# figures, GB/s). An accelerator that is not in the table is an error,
+# not a guess; the CPU backend (tests) measures its own memcpy
+# bandwidth and says so in the source label
 HBM_PEAK_GBPS = {"TPU v2": 700.0, "TPU v3": 900.0, "TPU v4": 1228.0,
                  "TPU v5 lite": 819.0, "TPU v5e": 819.0,
                  "TPU v5p": 2765.0, "TPU v6 lite": 1640.0,
@@ -490,16 +492,16 @@ def platform_peak_gbps() -> tuple[float, str]:
 
 
 def _measure_peak() -> tuple[float, str]:
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 - no backend: measure host anyway
-        kind = "cpu"
+    import jax
+    dev = jax.devices()[0]
+    kind = dev.device_kind
     if kind in HBM_PEAK_GBPS:
         return HBM_PEAK_GBPS[kind], f"datasheet({kind})"
-    for k, v in HBM_PEAK_GBPS.items():
-        if k.lower() in kind.lower():
-            return v, f"datasheet({kind})"
+    if dev.platform != "cpu":
+        raise KeyError(
+            f"no HBM peak recorded for device_kind {kind!r} (platform "
+            f"{dev.platform}): add its datasheet figure to "
+            f"profiler.HBM_PEAK_GBPS")
     import numpy as np
     buf = np.empty(1 << 27, dtype=np.uint8)   # 128 MB
     t0 = time.perf_counter()

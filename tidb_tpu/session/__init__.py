@@ -118,6 +118,7 @@ class Domain:
         self._ddl_owner = None
         self._schema_stop = None
         self._stats_stop = None
+        self._stats_thread = None
 
     def priv_cache(self):
         """Grant-table cache (ref: privilege/privileges/cache.go:104)."""
@@ -247,14 +248,18 @@ class Domain:
     # -- auto analyze (ref: statistics/handle.go auto-analyze +
     # RunAutoAnalyze wiring, tidb-server/main.go:341) -------------------------
 
-    def auto_analyze_tick(self) -> list[int]:
+    def auto_analyze_tick(self, interrupted=None) -> list[int]:
         """Analyze every table whose DML delta crossed the ratio; returns
         the analyzed table ids. Called by the background stats worker and
-        directly by tests."""
+        directly by tests. `interrupted` is the worker's stop probe: once
+        it answers true the ANALYZE in flight ends at its next scan frame
+        or column and the table stays pending."""
         from tidb_tpu.statistics import analyze_table
         handle = self.stats_handle()
         done = []
         for tid in handle.pending_tables():
+            if interrupted is not None and interrupted():
+                break
             located = self.info_schema().table_by_id(tid)
             if located is None:
                 handle._deltas.pop(tid, None)   # dropped table
@@ -262,7 +267,8 @@ class Domain:
             _db, info = located
             try:
                 stats = analyze_table(self.storage,
-                                      self.storage.current_ts(), info)
+                                      self.storage.current_ts(), info,
+                                      interrupted=interrupted)
                 handle.save(stats)
                 done.append(tid)
             except Exception:  # noqa: BLE001 - next tick retries
@@ -271,22 +277,33 @@ class Domain:
 
     def start_stats_worker(self, interval: float = 30.0) -> None:
         """Idempotent background auto-analyze loop."""
+        from tidb_tpu.util import supervisor
+        stop = threading.Event()
+
+        def beat():
+            return self.auto_analyze_tick(stop.is_set)
+
         with self._mu:
             if self._stats_stop is not None:
                 return
-            self._stats_stop = threading.Event()
-            stop = self._stats_stop
-
-        from tidb_tpu.util import supervisor
-        supervisor.supervise("stats-auto-analyze",
-                             self.auto_analyze_tick, stop, interval)
+            self._stats_stop = stop
+            self._stats_thread = supervisor.supervise(
+                "stats-auto-analyze", beat, stop, interval)
 
     def stop_stats_worker(self) -> None:
+        """Stop the loop and wait for it: an ANALYZE in flight is
+        cancelled through its interrupt probe, so its scan's pool
+        workers are not left for interpreter exit to join."""
         with self._mu:
-            stop = self._stats_stop
-            self._stats_stop = None
-        if stop is not None:
-            stop.set()
+            stop, thread = self._stats_stop, self._stats_thread
+            self._stats_stop = self._stats_thread = None
+        if stop is None:
+            return
+        stop.set()
+        thread.join(timeout=60)
+        if thread.is_alive():
+            logging.getLogger("tidb_tpu.domain").warning(
+                "stats worker still running 60s after stop")
 
     def stats_handle(self):
         """Lazy per-store stats cache (ref: statistics/handle.go:32)."""

@@ -495,10 +495,13 @@ def _index_key_stats(chunk_cols_rows, n_buckets: int) -> IndexStats:
 
 
 def analyze_table(storage, read_ts: int, info: TableInfo,
-                  n_buckets: int = DEFAULT_BUCKETS) -> TableStats:
+                  n_buckets: int = DEFAULT_BUCKETS,
+                  interrupted=None) -> TableStats:
     """Full-scan ANALYZE (ref: executor/analyze.go:42 AnalyzeExec; sample
     collection mocktikv/analyze.go). Reads the table through the normal
-    coprocessor fan-out, then builds per-column and per-index stats."""
+    coprocessor fan-out, then builds per-column and per-index stats.
+    `interrupted` is ExecContext's probe: the scan checks it per
+    response, the build per column and every 64k distinct keys."""
     from tidb_tpu.executor import ExecContext, TableReaderExec
     from tidb_tpu.plan.physical import CopPlan, PhysTableReader
     from tidb_tpu.plan.resolver import PlanSchema, SchemaCol
@@ -508,7 +511,7 @@ def analyze_table(storage, read_ts: int, info: TableInfo,
                          for c in cols])
     cop = CopPlan(table=info, cols=list(cols))
     reader = TableReaderExec(PhysTableReader(schema=schema, cop=cop))
-    ctx = ExecContext(storage, read_ts, None)
+    ctx = ExecContext(storage, read_ts, None, interrupted)
 
     parts = []
     total = 0
@@ -520,6 +523,7 @@ def analyze_table(storage, read_ts: int, info: TableInfo,
                     pseudo=False)
     from tidb_tpu.chunk import Column
     for ci, cinfo in enumerate(cols):
+        ctx.check_interrupt()
         # concatenate once, one whole-column sort (device for big numerics)
         if parts:
             whole = Column(
@@ -533,7 +537,9 @@ def analyze_table(storage, read_ts: int, info: TableInfo,
         keys = [v.item() if hasattr(v, "item") else v for v in vals]
         hist = build_histogram(keys, counts, n_buckets, null_count=nulls)
         cms = CMSketch()
-        for k, c in zip(keys, counts):
+        for i, (k, c) in enumerate(zip(keys, counts)):
+            if not i & 0xFFFF:
+                ctx.check_interrupt()
             cms.insert(_cm_key(k), int(c))
         ts.columns[cinfo.id] = ColumnStats(hist, cms)
 

@@ -157,7 +157,7 @@ def _encoded_agg(plan: CopPlan, chunk, sources: int,
     eff = enc if plan.filter is None else func(Op.AND, plan.filter, enc)
     try:
         k = kernel_for(eff, plan.group_exprs or [], plan.aggs)
-    except (DeviceRejectError, NotImplementedError, ValueError):
+    except (DeviceRejectError, NotImplementedError):
         runtime_stats.note_fallback(plan, "encoding")
         return None
     try:
