@@ -1,0 +1,104 @@
+"""The trace reduction against hand counts on a written trace, and
+against a brute-force count on a trace recorded on the chip."""
+
+import os
+
+import pytest
+from xplane_writer import encode
+
+from benchlib import tracered
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+# one device: busy [100,400] u [600,700] of a window [0,1000]
+_PLANES = [
+    {"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [("jit_kernel(123)", 100, 900)]},
+        {"name": "XLA Ops", "events": [
+            ("%fusion.1 = u32[8]{0} fusion(u32[8] %p), kind=kLoop", 100, 300),
+            ("%fusion.2 = u32[8]{0} fusion(u32[8] %p), kind=kCustom", 250, 400),
+            ("%all-reduce.1 = u32[8]{0} all-reduce(u32[8] %p)", 600, 700)]},
+        {"name": "Async XLA Ops", "events": [
+            ("%all-gather-start.1 = (u32[8], u32[32]) all-gather-start(u32[8] %p)",
+             650, 800),
+            ("%slice-start.4 = (u32[8]) async-start(u32[8] %p)", 0, 1000)]},
+        {"name": "Steps", "events": [("step", 0, 1000)]}]},
+    {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+        ("trace_begin", 0, 0), ("inside_q1", 50, 500),
+        ("inside_q1", 550, 800), ("trace_end", 1000, 1000)]}]},
+]
+
+
+@pytest.fixture()
+def written(tmp_path):
+    path = tmp_path / "written.xplane.pb"
+    path.write_bytes(encode(_PLANES))
+    return str(path)
+
+
+def test_busy_union_and_idle_share(written):
+    r = tracered.reduce_file(written)
+    assert r["devices"] == 1
+    assert r["window_s"] == pytest.approx(1000e-9)
+    assert r["busy_s"] == pytest.approx(400e-9)        # overlap counted once
+    assert 1 - r["busy_s_fullest"] / r["window_s"] == pytest.approx(0.6)
+    # sync all-reduce [600,700] u async all-gather [650,800]; busy is
+    # the executed-ops line alone
+    assert r["collective_s"] == pytest.approx(200e-9)
+
+
+def test_op_ranking_names_and_order(written):
+    ops = tracered.reduce_file(written)["device_ops"]
+    assert [n for n, _s in ops] == ["jit_kernel:fusion.1 u32[8] kLoop",
+                                    "jit_kernel:fusion.2 u32[8] kCustom",
+                                    "jit_kernel:all-reduce.1 u32[8] all-reduce"]
+    assert [s for _n, s in ops] == pytest.approx([200e-9, 150e-9, 100e-9])
+
+
+def test_gap_attribution(written):
+    gaps = dict(tracered.reduce_file(written)["idle_gaps"])
+    # gaps [0,100] [400,600] [700,1000]; spans [50,500] [550,800]
+    assert gaps["inside_q1:_total"] == pytest.approx(300e-9)
+    assert gaps["inside_q1:_longest"] == pytest.approx(100e-9)
+    assert gaps["between_statements:_total"] == pytest.approx(300e-9)
+    assert gaps["between_statements:_longest"] == pytest.approx(200e-9)
+
+
+def test_host_records_replace_cut_annotations(written):
+    # the load generator's records on the host clock (seconds), anchored
+    # at trace_begin: a statement open at both edges covers every gap
+    planes = tracered.read_planes(written)
+    r = tracered.reduce(planes, [("inside_q9", 9.0, 11.0)], 10.0)
+    gaps = dict(r["idle_gaps"])
+    assert gaps["inside_q9:_total"] == pytest.approx(600e-9)
+    assert "between_statements:_total" not in gaps
+
+
+def test_no_device_plane_reads_nothing(tmp_path):
+    path = tmp_path / "host_only.xplane.pb"
+    path.write_bytes(encode(_PLANES[1:]))
+    assert tracered.reduce_file(str(path)) is None
+
+
+def test_recorded_trace_against_brute_force():
+    """1.5 s of tpch1.q1_warm on a TPU v5 lite (chip run, PR 23), cut to
+    the device's op and module lines and the benchmark's spans."""
+    planes = tracered.read_planes(
+        os.path.join(DATA, "recorded_q1_warm.xplane.pb"))
+    r = tracered.reduce(planes)
+    lo, hi = r["window_ns"]
+    ops = tracered.device_planes(planes)[0]["ops"]
+    assert len(ops) > 1000
+    # brute force on a 1 us grid: a cell is busy if any op covers its middle
+    import numpy as np
+    grid = np.zeros(int((hi - lo) // 1000) + 1, dtype=bool)
+    for _n, s, e in ops:
+        a, b = int((max(s, lo) - lo) // 1000), int((min(e, hi) - lo) // 1000)
+        grid[a:b + 1] = True
+    assert r["busy_s"] == pytest.approx(grid.sum() * 1e-6, rel=0.01)
+    assert 0.9 < r["busy_s"] / r["window_s"] < 1.0     # device-bound Q1
+    top = [n for n, _s in r["device_ops"][:4]]
+    assert all("kCustom" in n and "u32[4096" in n for n in top)
+    gaps = dict(r["idle_gaps"])
+    assert sum(v for k, v in gaps.items() if k.endswith(":_total")) == \
+        pytest.approx(r["window_s"] - r["busy_s"], rel=1e-6)
