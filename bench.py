@@ -649,46 +649,55 @@ def _trace_mark() -> int:
 
 
 def _trace_attribution(mark: int, class_digests: dict) -> dict:
-    """Per-phase latency attribution from the statement traces retained
-    since `mark` (tidb_tpu/trace.py phases_of): for each query class,
-    p50/p99 per lifecycle phase — admission wait, scheduler stall,
-    device dispatch, finalize, host-fallback, parse/plan/commit and the
-    remainder — plus the traced statement total. The direct input
-    ROADMAP item 2 needs: WHERE a p99 regression's microseconds went.
-    `class_digests` maps normalized-SQL digest -> class name; traces
-    whose digest matches no class land under "other_sql"."""
+    """Latency attribution from the statement traces retained since
+    `mark`: for each query class, p50/p99 of the SELF time of every
+    span name (tidb_tpu/trace.py self_times: a span's duration less
+    what its same-thread children cover, so worker spans keep their own
+    time and nothing is counted twice on a thread) — sched.slot,
+    dispatch, finalize, host.fallback, parse/plan/commit, the scan's
+    copr.kv_scan/copr.decode/copr.exec, ... — plus the traced statement
+    total. The root's own self time (key "statement.self") is what no
+    span below explains. The direct input ROADMAP item 2 needs: WHERE a
+    p99 regression's microseconds went. `class_digests` maps
+    normalized-SQL digest -> class name; traces whose digest matches no
+    class land under "other_sql"."""
     from tidb_tpu import trace
     by_cls: dict = {}
     for rec in trace.ring_records(mark):
         cls = class_digests.get(rec["digest"], "other_sql")
-        by_cls.setdefault(cls, []).append(trace.phases_of(rec["root"]))
+        selfs = {n: v[0] for n, v in
+                 trace.self_times(rec["root"]).items()}
+        selfs["statement.self"] = selfs.pop("statement", 0)
+        by_cls.setdefault(cls, []).append(
+            (selfs, rec["root"].duration_ns))
     out: dict = {}
-    for cls, phs in sorted(by_cls.items()):
-        block: dict = {"traces": len(phs)}
-        phase_keys = [k for k in phs[0] if k != "total"]
-        for key in phase_keys:
-            xs = [p[key] / 1e9 for p in phs]
+    for cls, recs in sorted(by_cls.items()):
+        block: dict = {"traces": len(recs)}
+        span_keys = sorted({k for selfs, _t in recs for k in selfs})
+        for key in span_keys:
+            xs = [selfs.get(key, 0) / 1e9 for selfs, _t in recs]
             block[key] = {
                 "p50_ms": round(_percentile(xs, 50) * 1e3, 3),
                 "p99_ms": round(_percentile(xs, 99) * 1e3, 3)}
-        totals = [p["total"] / 1e9 for p in phs]
+        totals = [t / 1e9 for _selfs, t in recs]
         block["statement"] = {
             "p50_ms": round(_percentile(totals, 50) * 1e3, 3),
             "p99_ms": round(_percentile(totals, 99) * 1e3, 3)}
         # two consistency views of the tail. p99_coverage sums EVERY
-        # phase incl. the "other" remainder, so it reads ~1.0 whenever
-        # the trees are balanced (per-trace phases sum to the
-        # statement total; worker overlap pushes it above 1).
-        # p99_attributed excludes "other": it is the gap detector —
-        # how much of the tail the NAMED phases explain; a low value
-        # means the time went somewhere no span covers yet.
+        # span name incl. the root's own remainder, so it reads ~1.0
+        # for a single-threaded tree (self times partition the wall;
+        # worker threads add their own thread-seconds on top).
+        # p99_attributed excludes the root's remainder: it is the gap
+        # detector — how much of the tail the spans BELOW the root
+        # explain; a low value means the time went somewhere no span
+        # covers yet.
         p99 = block["statement"]["p99_ms"]
         if p99 > 0:
             block["p99_coverage"] = round(
-                sum(block[k]["p99_ms"] for k in phase_keys) / p99, 3)
+                sum(block[k]["p99_ms"] for k in span_keys) / p99, 3)
             block["p99_attributed"] = round(
-                sum(block[k]["p99_ms"] for k in phase_keys
-                    if k != "other") / p99, 3)
+                sum(block[k]["p99_ms"] for k in span_keys
+                    if k != "statement.self") / p99, 3)
         out[cls] = block
     return out
 
@@ -1424,10 +1433,9 @@ def _trace_bench(progress) -> dict:
         if not q1a or q1a["traces"] < iters:
             raise RuntimeError(
                 f"latency_attribution unpopulated: {attribution}")
-        if q1a["statement"]["p99_ms"] <= 0 or \
-                q1a["device_dispatch"]["p99_ms"] + \
-                q1a["finalize"]["p99_ms"] + \
-                q1a["host_fallback"]["p99_ms"] <= 0:
+        if q1a["statement"]["p99_ms"] <= 0 or sum(
+                q1a.get(k, {}).get("p99_ms", 0) for k in
+                ("dispatch", "finalize", "host.fallback")) <= 0:
             raise RuntimeError(
                 f"no device/host execution phase attributed: {q1a}")
 

@@ -246,8 +246,8 @@ class Probe:
                 families[key[0]] = families.get(key[0], 0) + disp - disp0
         obs = {
             "wall_s": round(wall, 4),
-            # kernel_profile has no join family: a device HashJoin
-            # shows in the executors' superchunk counter instead
+            # by kernel_profile family (`join` rows since PR 24; the
+            # executors' superchunk counter shows a device HashJoin too)
             "device_dispatches": families,
             "device_superchunks": by_op(
                 'tidb_tpu_superchunks_total{op="'),

@@ -383,6 +383,9 @@ class TestChromeExport:
         assert instants and instants[0]["name"] == "device.fault"
 
     def test_phases_of_sums_to_total(self):
+        # ported from trace.phases_of (inclusive sums by name) to the
+        # self-time walk: on one thread the self times PARTITION the
+        # statement, so they sum to its total exactly
         root = trace.begin("statement")
         with trace.span("plan"):
             time.sleep(0.002)
@@ -390,10 +393,13 @@ class TestChromeExport:
             with trace.span("dispatch"):
                 time.sleep(0.002)
         trace.end(root)
-        ph = trace.phases_of(root)
-        assert ph["plan"] > 0 and ph["device_dispatch"] > 0
-        assert ph["total"] >= ph["plan"] + ph["device_dispatch"]
-        assert ph["other"] >= 0
+        st = trace.self_times(root)
+        assert st["plan"][0] > 0 and st["dispatch"][0] > 0
+        assert set(st) == {"statement", "plan", "execute", "dispatch"}
+        assert all(n == 1 for _ns, n in st.values())
+        assert sum(ns for ns, _n in st.values()) == root.duration_ns
+        # execute's self time excludes the dispatch under it
+        assert st["execute"][0] < st["dispatch"][0]
 
 
 # -- overhead ----------------------------------------------------------------

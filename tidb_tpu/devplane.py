@@ -31,6 +31,7 @@ needed — the generation counter is the coherence protocol.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import numpy as np
@@ -43,7 +44,7 @@ __all__ = [
     "active_mesh", "mesh_generation", "on_topology_change", "ndev",
     "batch_spec", "replicated_spec", "batch_sharding", "replicated",
     "chip_device", "chip_scope", "mesh_fingerprint", "shard_map",
-    "pmax", "plane_jit",
+    "pmax", "named", "plane_jit",
 ]
 
 #: the one data-parallel axis name of the device plane
@@ -195,7 +196,22 @@ def pmax(x, axes=(AXIS,)):
     return jax.numpy.max(jax.lax.all_gather(x, axes), axis=0)
 
 
-def plane_jit(fn, **kwargs):
+def named(fn, family: str):
+    """`fn` under the name of its kernel family, for `jax.jit`: the XLA
+    module is called `jit_<fn.__name__>`, and every kernel class stages
+    a method called `_kernel`, so without this a device trace shows
+    `jit__kernel` for every family. The name is also what the compile
+    caches see. A wrapper, not a rename: bound methods refuse a new
+    `__name__`; it runs at trace time only (the jitted call's fast path
+    never re-enters Python)."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = family
+    return program
+
+
+def plane_jit(fn, name: str, **kwargs):
     """jit for plane kernels. Modern jax's jit IS pjit — NamedSharding
     inputs drive partitioned compilation directly, and on cpu a
     virtual-device mesh lowers the same way — so this is a plain jit
@@ -204,8 +220,9 @@ def plane_jit(fn, **kwargs):
     kernel. Each wrap registers one `plane`-family compile unit with
     the kernel-profile registry (keyed by the staged function's name +
     the process mesh): plane-stage re-jitting that the executable
-    caches should have absorbed shows up as compile churn on one row."""
+    caches should have absorbed shows up as compile churn on one row.
+    `name` is the program's family name on the device trace (`named`)."""
     from tidb_tpu import profiler
     prof = profiler.profile("plane", getattr(fn, "__name__", "shard"))
     profiler.note_construct(prof, reuse=False)
-    return jax.jit(fn, **kwargs)
+    return jax.jit(named(fn, name), **kwargs)

@@ -57,7 +57,8 @@ __all__ = ["DeviceFlow", "device_flow_of", "TracedSite", "DispatchSite"]
 # are the pow2 superchunk bucketing seams (ops/runtime.py) and the
 # per-kernel shard/pad entry points built on them
 SHAPERS = frozenset({
-    "bucket_size", "pad_column", "device_put_chunk", "prepare_build",
+    "bucket_size", "pad_column", "put_lanes", "device_put_chunk",
+    "prepare_build",
     "_shard_probe", "_put_side", "superchunk_batches", "_bucket",
 })
 
@@ -250,7 +251,9 @@ class DeviceFlow:
         nested def (the `_stage2_fn(bucket)` shape)."""
         if isinstance(expr, ast.Call):
             name = _call_name(expr)
-            if name == "shard_map" and expr.args:
+            if name in ("shard_map", "named") and expr.args:
+                # shard_map(fn, ...) / devplane.named(fn, family): the
+                # traced callable is the wrapped one
                 return self._unwrap_traced(expr.args[0], rel, owner)
             hits = self._resolve_callable(expr.func, rel, owner)
             # a factory that returns one of its nested defs: trace the

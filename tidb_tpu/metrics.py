@@ -15,7 +15,7 @@ from __future__ import annotations
 import threading
 
 __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
-           "gauges_snapshot",
+           "gauges_snapshot", "span_totals", "span_one",
            "QUERY_DURATIONS", "QUERIES_TOTAL", "SLOW_QUERIES",
            "CONNECTIONS", "COP_TASKS", "QUERY_ERRORS",
            "COP_STREAM_FRAMES", "COP_STREAM_BYTES",
@@ -37,12 +37,19 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "CLUSTER_SCRAPES", "MEMBER_START_TIME",
            "DEVICE_UTILIZATION", "HBM_OCCUPANCY", "CHIP_UTILIZATION",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
-           "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES"]
+           "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES",
+           "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
+           "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES"]
 
 _lock = threading.Lock()
 _counters: dict[tuple[str, tuple], float] = {}       # guarded-by: _lock
 _histograms: dict[tuple[str, tuple], "_Hist"] = {}   # guarded-by: _lock
 _gauges: dict[tuple[str, tuple], float] = {}         # guarded-by: _lock
+
+# span name -> [self nanoseconds, spans] (span_totals; rendered as the
+# SPAN_SELF_SECONDS / SPAN_COUNT counter families): the vocabulary is
+# closed, trace.SPAN_NAMES, so this stays a few dozen entries
+_span_acc: dict[str, list] = {}                      # guarded-by: _lock
 
 _BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0)
 
@@ -85,6 +92,45 @@ def counter(name: str, labels: dict | None = None, inc: float = 1) -> None:
         _counters[key] = _counters.get(key, 0) + inc
 
 
+def span_totals(totals: dict) -> None:
+    """Fold one statement's span tree into the two per-span-name
+    families: `totals` is trace.self_times()'s {name: [self_ns, n]}.
+    This runs at the end of every statement (trace.finish_statement),
+    so it is one lock hold and two in-place adds a name, on integer
+    nanoseconds; snapshot()/expose() render the series."""
+    with _lock:
+        for span, (self_ns, n) in totals.items():
+            acc = _span_acc.get(span)
+            if acc is None:
+                _span_acc[span] = [self_ns, n]
+            else:
+                acc[0] += self_ns
+                acc[1] += n
+
+
+def span_one(span: str, self_ns: int) -> None:
+    """span_totals for a tree that is its root alone (a statement with
+    no span under it): no dict to build, none to walk."""
+    with _lock:
+        acc = _span_acc.get(span)
+        if acc is None:
+            _span_acc[span] = [self_ns, 1]
+        else:
+            acc[0] += self_ns
+            acc[1] += 1
+
+
+def _span_series() -> list:
+    """[(family, label tuple, value)] of the span accumulators; caller
+    holds _lock."""
+    out = []
+    for span, (self_ns, n) in _span_acc.items():
+        labels = (("span", span),)
+        out.append((SPAN_SELF_SECONDS, labels, self_ns / 1e9))
+        out.append((SPAN_COUNT, labels, n))
+    return out
+
+
 def histogram(name: str, value: float, labels: dict | None = None) -> None:
     key = (name, _label_key(labels))
     with _lock:
@@ -118,6 +164,8 @@ def snapshot() -> dict:
         out = {}
         for (name, labels), v in _counters.items():
             out[name + _label_str(labels)] = v
+        for name, labels, v in _span_series():
+            out[name + _label_str(labels)] = v
         for (name, labels), v in _gauges.items():
             out[name + _label_str(labels)] = v
         for (name, labels), h in _histograms.items():
@@ -141,7 +189,10 @@ def expose() -> str:
             lines.append(f"# HELP {name} {_HELP.get(name, name)}")
             lines.append(f"# TYPE {name} {tp}")
 
-        for (name, labels), v in sorted(_counters.items()):
+        for (name, labels), v in sorted(
+                list(_counters.items()) +
+                [((name, labels), v)
+                 for name, labels, v in _span_series()]):
             meta(name, "counter")
             lines.append(f"{name}{_label_str(labels)} {v}")
         for (name, labels), v in sorted(_gauges.items()):
@@ -286,11 +337,32 @@ CHIP_UTILIZATION = "tidb_tpu_chip_utilization_ratio"
 # wall time (trace+compile+load, attributed hit|miss|cached by diffing
 # the persistent-cache counters around it), and per-family dispatch
 # counts. Labeled {family} only (hashagg|scalaragg|streamagg|fragment|
-# mesh|plane — a bounded vocabulary, per the cardinality rule)
+# mesh|plane|join|sort — profiler.FAMILIES, a bounded vocabulary, per
+# the cardinality rule)
 COMPILE_CACHE_HITS = "tidb_tpu_compile_cache_hits_total"
 COMPILE_CACHE_MISSES = "tidb_tpu_compile_cache_misses_total"
 KERNEL_COMPILE_SECONDS = "tidb_tpu_kernel_compile_seconds"
 KERNEL_DISPATCHES = "tidb_tpu_kernel_dispatch_total"
+# the statement span trees as counters (trace.py folds every ended
+# root's tree here, span_totals above): self time — a span's duration
+# less what its same-thread children cover, so thread-seconds that
+# never count an interval twice — and spans, labeled {span} from the
+# closed trace.SPAN_NAMES vocabulary. Diffed over a window they say
+# where the statements' host time went, sampled or not
+SPAN_SELF_SECONDS = "tidb_tpu_span_self_seconds_total"
+SPAN_COUNT = "tidb_tpu_span_count_total"
+# bytes handed to jax.device_put at the one-chip transfer seam
+# (ops/runtime.device_put_chunk, padding included — a one-chip HBM-cache
+# fill uploads through it too, under its hbm.fill span) and as the key
+# lanes the join matcher and the fused probe take as host arrays
+# (ops/join.py, ops/fragment.py). A chunk-memo or HBM-cache hit moves
+# nothing
+H2D_BYTES = "tidb_tpu_h2d_bytes_total"
+# result encoding + socket write (server._write_resultset): it runs
+# after session.execute has returned, outside the statement's root
+# span and outside sum_latency_ns
+WIRE_WRITE_SECONDS = "tidb_tpu_wire_write_seconds_total"
+WIRE_WRITE_BYTES = "tidb_tpu_wire_write_bytes_total"
 
 _HELP = {
     QUERY_DURATIONS: "Statement wall time through Session.execute.",
@@ -407,4 +479,11 @@ _HELP = {
         "by kernel family.",
     KERNEL_DISPATCHES:
         "Device kernel dispatches, by kernel family.",
+    SPAN_SELF_SECONDS:
+        "Statement span self time (thread-seconds), by span name.",
+    SPAN_COUNT: "Statement spans ended, by span name.",
+    H2D_BYTES: "Bytes handed to host->device transfers.",
+    WIRE_WRITE_SECONDS:
+        "Result-set encoding + socket write time on the wire.",
+    WIRE_WRITE_BYTES: "Result-set bytes written to client sockets.",
 }

@@ -42,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from tidb_tpu import devplane
 from tidb_tpu.chunk import Chunk
 from tidb_tpu.expression import AggDesc, AggFunc, Expression
 from tidb_tpu.ops import runtime
@@ -110,7 +111,7 @@ class ProbeAggKernel:
             raise DeviceRejectError("agg reads past the joined schema")
         self.probe_used = sorted(j for j in used if j < probe_width)
         self.build_used = sorted(j for j in used if j >= probe_width)
-        self._jit = jax.jit(self._kernel,
+        self._jit = jax.jit(devplane.named(self._kernel, "fragment"),
                             static_argnames=("out_cap",))
 
     # -- traced program ------------------------------------------------------
@@ -195,8 +196,7 @@ class ProbeAggKernel:
         lanes + the USED build columns (dict-encoded, padded). ->
         (bkeys_dev, bcols_dev), reused by every dispatch."""
         bb = runtime.bucket_size(max(nb, 1))
-        bkeys = [tuple(map(jnp.asarray, runtime.pad_column(d, v, bb)))
-                 for d, v in build_keys]
+        bkeys = runtime.put_lanes(build_keys, bb)
         bcols, _dicts = runtime.device_put_chunk(
             self._build_sub(build), bb, memo=False) \
             if self.build_used else ([], {})
@@ -211,8 +211,7 @@ class ProbeAggKernel:
         bkeys, bcols = build_dev
         pb = runtime.bucket_size(max(np_, 1))
         cap = out_cap or runtime.bucket_size(max(np_ * 2, 1024))
-        pk = [tuple(map(jnp.asarray, runtime.pad_column(d, v, pb)))
-              for d, v in probe_keys]
+        pk = runtime.put_lanes(probe_keys, pb)
         # only the USED probe columns ship — the kernel reads nothing
         # else, and the key lanes already ride pk
         pcols, _dicts = runtime.device_put_chunk(

@@ -551,7 +551,7 @@ class HashAggKernel:
         self.force_hash = force_hash
         self.direct_limit = direct_limit
         _validate_device_exprs(filter_expr, self.group_exprs, self.aggs)
-        self._jit = jax.jit(self._kernel)
+        self._jit = jax.jit(devplane.named(self._kernel, "hashagg"))
         self._jitd = None   # donating variant, built on first dispatch
 
     def _kernel(self, cols, nrows):
@@ -601,7 +601,9 @@ class HashAggKernel:
         cols, _dicts = runtime.device_put_chunk(chunk, memo=not donate)
         if donate:
             if self._jitd is None:
-                self._jitd = jax.jit(self._kernel, donate_argnums=(0,))
+                self._jitd = jax.jit(
+                    devplane.named(self._kernel, "hashagg"),
+                    donate_argnums=(0,))
             return self._jitd(cols, chunk.num_rows)
         return self._jit(cols, chunk.num_rows)
 
@@ -638,7 +640,7 @@ class ScalarAggKernel:
         self.filter_expr = filter_expr
         self.aggs = list(aggs)
         _validate_device_exprs(filter_expr, [], self.aggs)
-        self._jit = jax.jit(self._kernel)
+        self._jit = jax.jit(devplane.named(self._kernel, "scalaragg"))
         self._jitd = None
 
     # lint: exempt[dtype-discipline] int64 COUNT lane: exact even past 2^53 rows, matches the agg-state stacking dtype
@@ -673,7 +675,9 @@ class ScalarAggKernel:
         cols, _ = runtime.device_put_chunk(chunk, memo=not donate)
         if donate:
             if self._jitd is None:
-                self._jitd = jax.jit(self._kernel, donate_argnums=(0,))
+                self._jitd = jax.jit(
+                    devplane.named(self._kernel, "scalaragg"),
+                    donate_argnums=(0,))
             return self._jitd(cols, chunk.num_rows)
         return self._jit(cols, chunk.num_rows)
 

@@ -28,6 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from tidb_tpu import devplane, profiler
 from tidb_tpu.ops import runtime
 from tidb_tpu.ops.hashagg import _FILL, _SENTINEL_MASKED, _hash_keys
 
@@ -251,9 +252,16 @@ def _matcher_program(out_cap: int):
         return match_pairs(xp, hb, hp, [d for d, _v in bkeys],
                            [d for d, _v in pkeys], out_cap)
 
-    prog = jax.jit(kernel)
+    prog = jax.jit(devplane.named(kernel, "join"))
     _PROGRAMS[out_cap] = prog
+    profiler.note_construct(_profile(out_cap), reuse=False)
     return prog
+
+
+def _profile(out_cap: int):
+    """The `join` kernel_profile row of one capacity bucket's program
+    (the program memo's own key)."""
+    return profiler.profile("join", f"cap{out_cap}")
 
 
 class _PendingJoin:
@@ -296,9 +304,8 @@ class JoinKernel:
         """Pad + transfer the build-side key lanes once; the returned
         device lanes feed every probe superchunk's dispatch (per-probe
         build re-uploads were pure waste)."""
-        bb = runtime.bucket_size(max(nb, 1))
-        return [tuple(map(jnp.asarray, runtime.pad_column(d, v, bb)))
-                for d, v in build_keys]
+        return runtime.put_lanes(build_keys,
+                                 runtime.bucket_size(max(nb, 1)))
 
     def dispatch(self, build_keys, probe_keys, nb: int, np_: int,
                  out_cap: int | None = None, build_dev=None) -> _PendingJoin:
@@ -309,11 +316,15 @@ class JoinKernel:
             else self.prepare_build(build_keys, nb)
         pb = runtime.bucket_size(max(np_, 1))
         cap = out_cap or runtime.bucket_size(max(np_ * 2, 1024))
-        pk = [tuple(map(jnp.asarray, runtime.pad_column(d, v, pb)))
-              for d, v in probe_keys]
+        pk = runtime.put_lanes(probe_keys, pb)
         prog = _matcher_program(cap)
-        return _PendingJoin(bk, pk, nb, np_, cap,
-                            prog(bk, pk, nb, np_))
+        # the enqueue interval lands on the bucket's `join` profile
+        # row: a fresh program's first call is where jax traces +
+        # compiles, so that interval is its compile
+        with profiler.dispatch_section(
+                _profile(cap), nbytes=self.dispatch_nbytes(np_, cap)):
+            res = prog(bk, pk, nb, np_)
+        return _PendingJoin(bk, pk, nb, np_, cap, res)
 
     def finalize(self, p: _PendingJoin):
         """Blocking half: read back the pair list, growing the output
@@ -342,7 +353,11 @@ class JoinKernel:
                     extra += grow    # before consume: it may raise
                     root.consume(device=grow)
                 p.cap = new_cap
-                p.res = _matcher_program(p.cap)(p.bk, p.pk, p.nb, p.np_)
+                with profiler.dispatch_section(
+                        _profile(p.cap),
+                        nbytes=self.dispatch_nbytes(p.np_, p.cap)):
+                    p.res = _matcher_program(p.cap)(p.bk, p.pk, p.nb,
+                                                    p.np_)
             li, ri, ok = jax.device_get((li, ri, ok))
         finally:
             if root is not None and extra:

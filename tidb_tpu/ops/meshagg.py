@@ -203,7 +203,7 @@ class MeshKernelBase:
         shard = devplane.shard_map(
             self._kernel, mesh, in_specs=in_specs,
             out_specs=(P(), P(), P(), P(), P(), P(), P()))
-        self._jit = devplane.plane_jit(shard)
+        self._jit = devplane.plane_jit(shard, name="meshagg")
 
     def _shard_probe(self, chunk: Chunk, bucket: bool = False):
         """-> (sharded device cols, padded shard length). The sharded

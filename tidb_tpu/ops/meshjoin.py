@@ -400,7 +400,7 @@ class MeshLookupAggKernel(MeshKernelBase):
                 in_specs=(self._row_spec, P(), P()),
                 out_specs=(self._row_spec, self._row_spec,
                            self._row_spec, P()))
-            self._stage1_jit = devplane.plane_jit(sm)
+            self._stage1_jit = devplane.plane_jit(sm, name="meshjoin")
         return self._stage1_jit
 
     def _get_stage2(self, bucket: int):
@@ -412,7 +412,8 @@ class MeshLookupAggKernel(MeshKernelBase):
                           self._row_spec, P()),
                 out_specs=(self._row_spec, self._row_spec,
                            self._row_spec, P()))
-            j = self._stage2_jits[bucket] = devplane.plane_jit(sm)
+            j = self._stage2_jits[bucket] = devplane.plane_jit(
+                sm, name="meshjoin")
         return j
 
     def _get_stage3(self, bucket: int):
@@ -423,7 +424,8 @@ class MeshLookupAggKernel(MeshKernelBase):
                 in_specs=(self._row_spec, self._row_spec,
                           self._row_spec),
                 out_specs=(P(), P(), P(), P(), P(), P(), P()))
-            j = self._stage3_jits[bucket] = devplane.plane_jit(sm)
+            j = self._stage3_jits[bucket] = devplane.plane_jit(
+                sm, name="meshjoin")
         return j
 
     @staticmethod

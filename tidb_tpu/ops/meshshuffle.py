@@ -141,7 +141,7 @@ class MeshShuffleJoinKernel:
                      spec_row, spec_row, spec_row)
         sm = devplane.shard_map(kernel, self.mesh, in_specs=in_specs,
                                 out_specs=out_specs)
-        return devplane.plane_jit(sm)
+        return devplane.plane_jit(sm, name="meshshuffle")
 
     # -- host driver ---------------------------------------------------------
 

@@ -17,10 +17,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from tidb_tpu import metrics
 from tidb_tpu.chunk import Chunk, dict_encode
 from tidb_tpu.expression import Expression
 
-__all__ = ["bucket_size", "pad_column", "device_put_chunk",
+__all__ = ["bucket_size", "pad_column", "put_lanes", "device_put_chunk",
            "eval_filter_host", "super_batches", "MIN_BUCKET",
            "Superchunk", "superchunk_batches", "pipeline_map",
            "donation_supported", "plan_fingerprint"]
@@ -335,6 +336,24 @@ def pad_column(data: np.ndarray, valid: np.ndarray, size: int):
     return pd, pv
 
 
+def _count_h2d(cols) -> None:
+    """Bytes of [(data, valid)] host arrays about to be handed to the
+    device, padding included (metrics.H2D_BYTES)."""
+    metrics.counter(metrics.H2D_BYTES,
+                    inc=sum(d.nbytes + v.nbytes for d, v in cols))
+
+
+def put_lanes(keys, size: int):
+    """Pad [(data, valid)] key lanes to `size` and hand them to the
+    device as the operands of a join-shaped program (ops/join.py,
+    ops/fragment.py): those programs take host arrays directly, so
+    their host->device bytes are counted here and not at
+    device_put_chunk."""
+    lanes = [pad_column(d, v, size) for d, v in keys]
+    _count_h2d(lanes)
+    return [tuple(map(jnp.asarray, lane)) for lane in lanes]
+
+
 def device_put_chunk(chunk: Chunk, size: int | None = None,
                      to_device: bool = True, memo: bool = True):
     """-> (cols, dicts): cols is a list of (data, valid) per column, padded
@@ -368,6 +387,9 @@ def device_put_chunk(chunk: Chunk, size: int | None = None,
         data, valid = pad_column(np.ascontiguousarray(data), valid, size)
         cols.append((data, valid))
     if to_device:
+        # the one-chip transfer seam (a memo hit above, or an
+        # HBM-cache hit upstream, never reaches here)
+        _count_h2d(cols)
         cols = jax.device_put(cols)   # one batched transfer
         if memo:
             dev_cache_put(chunk, size, (cols, dicts))

@@ -21,9 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tidb_tpu import profiler
 from tidb_tpu.ops import runtime
 
-_jit_sort = jax.jit(jnp.sort)
+_jit_sort = jax.jit(jnp.sort)     # the XLA module is `jit_sort` as is
+
+# (dtype, bucket) pairs already dispatched: jit holds one executable
+# per pair, so a pair's first sight is one `sort` compile unit
+_SEEN: set = set()
 
 
 def device_sort(data: np.ndarray) -> np.ndarray:
@@ -40,4 +45,10 @@ def device_sort(data: np.ndarray) -> np.ndarray:
         padded[:n] = data
         padded[n:] = fill
         data = padded
-    return np.asarray(_jit_sort(data))[:n]
+    key = (data.dtype.str, cap)
+    prof = profiler.profile("sort", f"{key[0]}|{cap}")
+    if key not in _SEEN:
+        _SEEN.add(key)
+        profiler.note_construct(prof, reuse=False)
+    with profiler.dispatch_section(prof, nbytes=2 * data.nbytes):
+        return np.asarray(_jit_sort(data))[:n]

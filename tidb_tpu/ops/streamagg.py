@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from tidb_tpu import devplane
 from tidb_tpu.chunk import Chunk
 from tidb_tpu.expression import AggDesc, Expression
 from tidb_tpu.ops import runtime
@@ -51,7 +52,7 @@ class SegmentAggKernel:
         self.group_exprs = list(group_exprs)
         self.aggs = list(aggs)
         _validate_device_exprs(None, self.group_exprs, self.aggs)
-        self._jit = jax.jit(self._kernel)
+        self._jit = jax.jit(devplane.named(self._kernel, "streamagg"))
         self._jitd = None   # donating variant, built on first dispatch
 
     # lint: exempt[dtype-discipline] int64 segment counts/ids: exact lane semantics shared with hashagg's agg-state stacking
@@ -106,7 +107,9 @@ class SegmentAggKernel:
         cols, _dicts = runtime.device_put_chunk(chunk, memo=not donate)
         if donate:
             if self._jitd is None:
-                self._jitd = jax.jit(self._kernel, donate_argnums=(0,))
+                self._jitd = jax.jit(
+                    devplane.named(self._kernel, "streamagg"),
+                    donate_argnums=(0,))
             return self._jitd(cols, chunk.num_rows)
         return self._jit(cols, chunk.num_rows)
 

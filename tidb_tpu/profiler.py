@@ -61,7 +61,7 @@ __all__ = ["KernelProfile", "KernelProfileRegistry", "enabled",
 # the plane-size-invariance contract bench.py profile pins): every
 # executable-cache construction site declares exactly one of these
 FAMILIES = ("hashagg", "scalaragg", "streamagg", "fragment", "mesh",
-            "plane")
+            "plane", "join", "sort")
 
 # fixed per-entry billing against the kernel-profile SERVER node: a
 # KernelProfile is ~15 ints + 3 short strings + a small fallback dict;
@@ -362,6 +362,9 @@ def note_dispatch(prof: KernelProfile | None, busy_ns: int,
     if compiled:
         metrics.histogram(metrics.KERNEL_COMPILE_SECONDS, busy_ns / 1e9,
                           {"family": prof.family})
+        # a (re)compile inside a statement, on the span it interrupted
+        from tidb_tpu import trace
+        trace.event("kernel.compile", family=prof.family)
     if plan is not None:
         from tidb_tpu import runtime_stats
         runtime_stats.note_kernel(plan, prof.family, prof.compile_src,

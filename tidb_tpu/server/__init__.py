@@ -16,6 +16,7 @@ import os
 import socket
 import struct
 import threading
+import time
 from decimal import Decimal
 
 from tidb_tpu.server.packet import (PacketIO, lenenc_bytes, lenenc_int,
@@ -517,22 +518,34 @@ class ClientConn:
         self.pkt.write_packet(pkt)
 
     def _write_resultset(self, rs: ResultSet) -> None:
+        from tidb_tpu import metrics
         from tidb_tpu.util import failpoint
-        self.pkt.write_packet(lenenc_int(len(rs.columns)))
-        fts = getattr(rs, "field_types", None)
-        for i, name in enumerate(rs.columns):
-            self.pkt.write_packet(self._column_def(
-                name, fts[i] if fts else None))
-        self._write_eof()
-        for n, row in enumerate(rs.rows):
-            # injectable connection teardown MID-resultset (after the
-            # header, between rows): a callable action can close the
-            # socket / raise, proving a half-shipped resultset tears
-            # the connection down without wedging the session's slots
-            # or ledgers
-            failpoint.eval("wire/resultset", self, n)
-            self.pkt.write_packet(self._encode_row(row))
-        self._write_eof()
+        # session.execute has returned: the statement's root span and
+        # sum_latency_ns are closed, so row encoding + the socket write
+        # are timed here, as counters (there is no root to hang a span on)
+        t0 = time.perf_counter()
+        sent0 = self.pkt.sent
+        try:
+            self.pkt.write_packet(lenenc_int(len(rs.columns)))
+            fts = getattr(rs, "field_types", None)
+            for i, name in enumerate(rs.columns):
+                self.pkt.write_packet(self._column_def(
+                    name, fts[i] if fts else None))
+            self._write_eof()
+            for n, row in enumerate(rs.rows):
+                # injectable connection teardown MID-resultset (after
+                # the header, between rows): a callable action can close
+                # the socket / raise, proving a half-shipped resultset
+                # tears the connection down without wedging the
+                # session's slots or ledgers
+                failpoint.eval("wire/resultset", self, n)
+                self.pkt.write_packet(self._encode_row(row))
+            self._write_eof()
+        finally:
+            metrics.counter(metrics.WIRE_WRITE_SECONDS,
+                            inc=time.perf_counter() - t0)
+            metrics.counter(metrics.WIRE_WRITE_BYTES,
+                            inc=self.pkt.sent - sent0)
 
     @staticmethod
     def _column_def(name: str, ft) -> bytes:

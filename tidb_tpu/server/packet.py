@@ -19,6 +19,7 @@ class PacketIO:
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.seq = 0
+        self.sent = 0       # bytes handed to the socket, headers included
 
     def _recv_exact(self, n: int) -> bytes:
         buf = b""
@@ -45,6 +46,7 @@ class PacketIO:
             chunk = payload[off:off + MAX_PAYLOAD]
             header = struct.pack("<I", len(chunk))[:3] + bytes([self.seq])
             self.sock.sendall(header + chunk)
+            self.sent += 4 + len(chunk)
             self.seq = (self.seq + 1) & 0xFF
             off += len(chunk)
             if len(chunk) < MAX_PAYLOAD:

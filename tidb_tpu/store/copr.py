@@ -34,7 +34,7 @@ from tidb_tpu.table import index_kvrows_to_chunk, kvrows_to_chunk
 from tidb_tpu.util import failpoint
 from tidb_tpu.util.failpoint import DeviceFaultError
 
-__all__ = ["CopClient", "cop_handler", "decode_cop_batch",
+__all__ = ["CopClient", "cop_handler", "scan_batch", "decode_cop_batch",
            "exec_cop_plan", "exec_cached_cop", "use_cached_path"]
 
 # fan-out width lives in the tidb_tpu_cop_concurrency sysvar (config.py;
@@ -85,15 +85,32 @@ def _agg_kernels(plan: CopPlan):
     return k
 
 
+def scan_batch(storage, cur: bytes, e: bytes, limit: int,
+               req: CopRequest):
+    """One `storage.engine.scan` call (MVCC iteration over at most
+    `limit` rows from `cur`) under a `copr.kv_scan` span: the scan's
+    first step, by the same name on the streamed path (store/stream.py)
+    and in the materialized readers below."""
+    with trace.span("copr.kv_scan") as sp:
+        batch = storage.engine.scan(cur, e, limit, req.start_ts,
+                                    req.isolation, desc=False)
+        sp.tags["rows"] = len(batch)
+    return batch
+
+
 def decode_cop_batch(plan: CopPlan, batch):
     """Raw (key, value) rows -> decoded chunk for `plan` (row or index
-    encoding). Shared by the materialized handler below and the framed
-    producer in store/stream.py."""
-    if plan.index is not None:
-        return index_kvrows_to_chunk(plan.table, plan.index, plan.cols,
-                                     batch, handle_col=plan.handle_col)
-    return kvrows_to_chunk(plan.table, plan.cols, batch,
-                           with_handle_col=plan.handle_col)
+    encoding), under a `copr.decode` span: the scan's second step.
+    Shared by the materialized handler below and the framed producer in
+    store/stream.py. `native` says whether native/codec.cc took the
+    layout (kvrows_to_chunk stamps it; an index layout never does)."""
+    with trace.span("copr.decode", rows=len(batch), native=0):
+        if plan.index is not None:
+            return index_kvrows_to_chunk(plan.table, plan.index,
+                                         plan.cols, batch,
+                                         handle_col=plan.handle_col)
+        return kvrows_to_chunk(plan.table, plan.cols, batch,
+                               with_handle_col=plan.handle_col)
 
 
 def _resolve_block(plan: CopPlan, chunk, dev_ref):
@@ -218,6 +235,15 @@ def _encoded_agg(plan: CopPlan, chunk, sources: int,
 
 def exec_cop_plan(plan: CopPlan, chunk, sources: int = 1,
                   dev_ref=None) -> CopResponse:
+    """`_exec_cop_plan` under a `copr.exec` span: the scan's third
+    step, on the streamed and the materialized path alike. The slot
+    wait, dispatch and finalize of a device agg are its children."""
+    with trace.span("copr.exec", rows=chunk.num_rows):
+        return _exec_cop_plan(plan, chunk, sources, dev_ref)
+
+
+def _exec_cop_plan(plan: CopPlan, chunk, sources: int,
+                   dev_ref) -> CopResponse:
     """Run the pushed subplan over one region's decoded chunk.
     `sources` is how many storage scan batches were coalesced into
     `chunk` (superchunk accounting for EXPLAIN ANALYZE / metrics).
@@ -500,9 +526,7 @@ def _cached_range_chunk(storage, region: Region, plan: CopPlan, s: bytes,
         want_handles = dstore is not None and plan.index is None
         cur = s
         while True:
-            batch = storage.engine.scan(cur, e, COP_SCAN_BATCH,
-                                        req.start_ts, req.isolation,
-                                        desc=False)
+            batch = scan_batch(storage, cur, e, COP_SCAN_BATCH, req)
             if not batch:
                 break
             parts.append(decode_cop_batch(plan, batch))
@@ -653,9 +677,7 @@ def cop_handler(storage):
 
         try:
             while True:
-                batch = storage.engine.scan(cur, e, COP_SCAN_BATCH,
-                                            req.start_ts,
-                                            req.isolation, desc=False)
+                batch = scan_batch(storage, cur, e, COP_SCAN_BATCH, req)
                 if not batch:
                     break
                 if sc_limit:

@@ -196,7 +196,8 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
     cap bounds buffering, it cannot split a row. Cache-eligible ranges
     consult and fill the columnar caches (module docstring)."""
     from tidb_tpu.store.copr import (clamp_range, decode_cop_batch,
-                                     exec_cop_plan, use_cached_path)
+                                     exec_cop_plan, scan_batch,
+                                     use_cached_path)
 
     plan = req.plan
     # ONE clamp shared with the materialized handler: cache keys embed
@@ -311,9 +312,10 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
 
     try:
         while not done:
-            batch = storage.engine.scan(cur, e, SCAN_SUB_BATCH,
-                                        req.start_ts, req.isolation,
-                                        desc=False)
+            # the three steps are spans around CALLS (scan_batch,
+            # decode_cop_batch and exec_cop_plan open them), never
+            # around a yield: see trace.annotate
+            batch = scan_batch(storage, cur, e, SCAN_SUB_BATCH, req)
             if not batch:
                 break
             for k, v in batch:

@@ -525,6 +525,10 @@ def kvrows_to_chunk(info: TableInfo, col_infos, kvrows,
     if not any(c.ft.is_wide_decimal for c in info.columns):
         ch = _kvrows_to_chunk_native(col_infos, kvrows, with_handle_col)
     if ch is not None:
+        # whether the C++ decoder took the layout, on the span that
+        # times the decode (store/copr.decode_cop_batch's copr.decode)
+        from tidb_tpu import trace
+        trace.annotate(native=1)
         return ch
     ncols = len(col_infos) + (1 if with_handle_col is not None else 0)
     rows = []

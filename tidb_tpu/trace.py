@@ -30,6 +30,16 @@ The ring is billed to a `trace-ring` memtrack SERVER node with a
 registered shed action, so admission shedding and `GET /shed` reclaim
 retained trees like any other server-scope residency.
 
+Two more readers of the same trees, neither needing retention: when a
+root ends, `finish_statement` folds the tree's self time per span name
+into counters (`self_times` -> metrics.span_totals:
+tidb_tpu_span_self_seconds_total{span} / tidb_tpu_span_count_total
+{span}), so a window of statements can be diffed whether sampled or
+not; and while a `jax.profiler` session is live every span is also a
+`jax.profiler.TraceAnnotation` carrying the root's trace id, so the
+program's spans sit on the device trace's clock (decided once per root,
+`_profiling`; nothing per span outside a session).
+
 Cross-thread propagation follows the house pattern (the runtime_stats
 collector and the memtrack tracker): the coprocessor fan-out captures
 the dispatching span with `propagate()` and re-installs it inside every
@@ -42,6 +52,7 @@ failpoints already follow."""
 from __future__ import annotations
 
 import contextlib
+import itertools
 import logging
 import threading
 import time
@@ -50,7 +61,7 @@ __all__ = ["Span", "SPAN_NAMES", "begin", "end", "span", "event",
            "annotate", "current_root", "active", "detach", "restore",
            "attached", "propagate", "attach_remote", "origin",
            "phase_ns", "log_tree", "ensure_id", "finish_statement",
-           "tree", "validate", "phases_of", "ring_snapshot",
+           "tree", "validate", "self_times", "ring_snapshot",
            "ring_records", "ring_get", "to_chrome", "reset_for_tests"]
 
 log = logging.getLogger("tidb_tpu.trace")
@@ -78,6 +89,12 @@ SPAN_NAMES = {
     # coprocessor fan-out (store/copr.py)
     "copr.task": "one region task on a coprocessor pool worker",
     "copr.stream": "one streaming fan-out worker's frame production",
+    # the scan's three steps, the same names on the streamed
+    # (store/stream.py) and the materialized (store/copr.py) path; they
+    # wrap calls (one KV batch, one frame or batch), never a row
+    "copr.kv_scan": "one storage.engine.scan call (MVCC iteration)",
+    "copr.decode": "raw KV rows -> decoded chunk (decode_cop_batch)",
+    "copr.exec": "the pushed filter/projection/partial agg over a chunk",
     # storage-side caches and deltas (store/device_cache.py, delta.py)
     "hbm.fill": "HBM region-block cache upload",
     "hbm.patch": "in-place delta patch of a resident HBM block",
@@ -101,12 +118,14 @@ _SPAN_EST_BYTES = 256          # rough per-span record cost estimate
 
 
 class Span:
-    # the last three slots are ROOT-ONLY retention state (sampling
-    # decided at begin(), TRACE forces, ids assigned on first need):
-    # begin() writes them; child spans leave them unset — the hot
-    # constructor must not pay three dead writes per span
+    # the last five slots are ROOT-ONLY state: retention (sampling
+    # decided at begin(), TRACE forces, ids assigned on first need) and
+    # the profiler-session decision (`live`, with the root's own
+    # annotation in `ann`). begin() writes them; child spans leave them
+    # unset — the hot constructor must not pay dead writes per span
     __slots__ = ("name", "tags", "start_ns", "end_ns", "children",
-                 "events", "tid", "sampled", "forced", "trace_id")
+                 "events", "tid", "sampled", "forced", "trace_id",
+                 "live", "ann")
 
     def __init__(self, name: str, tags: dict | None = None):
         self.name = name
@@ -153,6 +172,12 @@ def begin(name: str, **tags) -> Span:
     root.sampled = _sample_next() if name == "statement" else False
     root.forced = False
     root.trace_id = None
+    # one profiler-session check per root: the whole tree below either
+    # mirrors itself onto the device trace's clock or pays nothing
+    global _session
+    live = root.live = _session = \
+        _TraceMe.is_enabled() if _TraceMe is not None else _profiling()
+    root.ann = _annotation(name, root) if live else None
     _tl.cur = root
     # the ROOT is tracked separately from the current span: origin()
     # must name the enclosing statement from arbitrarily deep inside
@@ -164,6 +189,12 @@ def begin(name: str, **tags) -> Span:
 
 def end(root: Span) -> Span:
     root.end_ns = time.perf_counter_ns()
+    try:
+        if root.ann is not None:
+            root.ann.__exit__(None, None, None)
+            root.ann = None
+    except AttributeError:      # a hand-built Span, never begun
+        pass
     if getattr(_tl, "cur", None) is root:
         _tl.cur = None
     if getattr(_tl, "root", None) is root:
@@ -228,25 +259,81 @@ class span:
     __enter__ immediately after evaluating the expression, with no
     user code in between; use only as `with trace.span(...)`."""
 
-    __slots__ = ("_span", "_parent")
+    __slots__ = ("_span", "_parent", "_ann")
 
     def __init__(self, name: str, **tags):
         parent = getattr(_tl, "cur", None)
         s = Span(name, tags)
         self._span = s
         self._parent = parent
+        self._ann = None
         if parent is not None:
             parent.children.append(s)
             _tl.cur = s
+            if _session or name in _RECHECK_SPANS:
+                self._ann = _mirror(name)
 
     def __enter__(self) -> Span:
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
         self._span.end_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if self._parent is not None:
             _tl.cur = self._parent
         return False
+
+
+# -- the same spans on the device trace's clock ------------------------------
+
+# where a long statement re-takes the profiler-session decision: a
+# streamed join runs for seconds and a session may open (or close) under
+# it, so these coarse spans (a region task, a stream worker, a device
+# slot wait, one decoded frame or scan batch — never a per-row span)
+# refresh `root.live`. The frame is in the set because a join starts all
+# its stream workers at once: on the chip a 15 s statement crossed no
+# other boundary for its first 4 s (PERF.md §6, PR 24)
+_RECHECK_SPANS = frozenset({"copr.task", "copr.stream", "sched.slot",
+                            "copr.decode"})
+
+_TraceMe = None
+# what the last _profiling() call saw, process-wide: the one word a
+# span reads outside a session (the decision itself rides on each root)
+_session = False
+
+
+def _profiling() -> bool:
+    """True while a jax.profiler session is recording host events: the
+    TraceMe recorder's own flag, one C call (~30ns)."""
+    global _TraceMe, _session
+    if _TraceMe is None:
+        from jax.profiler import TraceAnnotation
+        _TraceMe = TraceAnnotation
+    _session = _TraceMe.is_enabled()
+    return _session
+
+
+def _mirror(name: str):
+    """span()'s slow path: the annotation for a span of the thread's
+    statement when its root is live, re-taking the root's decision at a
+    coarse boundary. None where there is nothing to mirror."""
+    root = getattr(_tl, "root", None)
+    if root is None:
+        return None
+    if name in _RECHECK_SPANS:
+        root.live = _profiling()
+    return _annotation(name, root) if root.live else None
+
+
+def _annotation(name: str, root: Span):
+    """An entered jax.profiler.TraceAnnotation named like the span, on
+    the calling thread, carrying the statement's trace id: the span as
+    the device trace sees it. The caller exits it where the span ends
+    (same thread: a span never migrates)."""
+    ann = _TraceMe(name, trace_id=ensure_id(root))
+    ann.__enter__()
+    return ann
 
 
 def active() -> bool:
@@ -337,21 +424,28 @@ def log_tree(root: Span, sql: str) -> None:
 # -- sampling ----------------------------------------------------------------
 
 _seq_lock = threading.Lock()
-_stmt_seq = 0
+# statements since process start (or reset): itertools.count's next()
+# is one atomic C call, so the per-statement sampling decision takes no
+# lock
+_stmt_seq = itertools.count(1)
 _id_seq = 0
 
 # lazy config binding: trace.py keeps zero package imports at module
 # level (it loads before most of the package), and a per-statement
-# `from tidb_tpu import config` would dominate the disarmed cost
-_config = None
+# `from tidb_tpu import config` would dominate the disarmed cost. The
+# two sysvars a statement reads (sampling at begin, the slow threshold
+# at finish) go through config's own overlay-aware reader directly:
+# the named accessors' two extra calls were a fifth of the disarmed
+# cost, which the statement-end fold now needs (TestOverhead)
+_sysvar = None
 
 
-def _cfg():
-    global _config
-    if _config is None:
-        from tidb_tpu import config
-        _config = config
-    return _config
+def _bind_sysvar():
+    global _sysvar
+    if _sysvar is None:
+        from tidb_tpu.config import _read
+        _sysvar = _read
+    return _sysvar
 
 
 _member_mod = None
@@ -365,18 +459,28 @@ def _member():
     return _member_mod
 
 
+_metrics_mod = None
+
+
+def _metrics():
+    global _metrics_mod
+    if _metrics_mod is None:
+        from tidb_tpu import metrics
+        _metrics_mod = metrics
+    return _metrics_mod
+
+
 def _sample_next() -> bool:
     """Deterministic 1-in-N: the N-th, 2N-th, ... statement since
-    process start (or reset) is sampled. One lock'd int increment per
-    statement — the whole disarmed cost besides the skeleton spans the
-    phase breakdown needs anyway."""
-    n = _cfg().trace_sample()
+    process start (or reset) is sampled. One atomic counter step per
+    statement — with the profiler-session check and the statement-end
+    fold of the span tree into the self-time counters, the whole
+    disarmed cost besides the skeleton spans the phase breakdown needs
+    anyway."""
+    n = (_sysvar or _bind_sysvar())("tidb_tpu_trace_sample")
     if n <= 0:
         return False
-    global _stmt_seq
-    with _seq_lock:
-        _stmt_seq += 1
-        return _stmt_seq % n == 0
+    return next(_stmt_seq) % n == 0
 
 
 def ensure_id(root: Span) -> int:
@@ -489,17 +593,24 @@ def finish_statement(root: Span, sql: str, error: str | None = None,
     statement that caused this store-plane root, instead of defaulting
     to the local identity — the join key cluster_statement_traces and
     /fleet/trace search on."""
+    # self time per span name, once a statement (never in
+    # span.__exit__): what the window's counters are diffed over
+    dur_ns = root.duration_ns
+    if root.children:
+        (_metrics_mod or _metrics()).span_totals(self_times(root))
+    else:
+        (_metrics_mod or _metrics()).span_one(root.name, dur_ns)
     if root.forced:
         reason = "forced"
     elif root.sampled:
         reason = "sampled"
     else:
         if slow_ms is None:
-            slow_ms = _cfg().slow_trace_ms()
-        if slow_ms <= 0 or root.duration_ns < slow_ms * 1_000_000:
+            slow_ms = (_sysvar or _bind_sysvar())(
+                "tidb_tpu_slow_trace_ms")
+        if slow_ms <= 0 or dur_ns < slow_ms * 1_000_000:
             return None
         reason = "slow"
-    dur_ns = root.duration_ns
     from tidb_tpu import metrics, perfschema
     tid = ensure_id(root)
     rec = {
@@ -552,7 +663,7 @@ def reset_for_tests() -> None:
     global _stmt_seq, _id_seq
     _RING.shed()
     with _seq_lock:
-        _stmt_seq = 0
+        _stmt_seq = itertools.count(1)
         _id_seq = 0
 
 
@@ -603,42 +714,59 @@ def validate(root: Span) -> list[str]:
     return problems
 
 
-# the bench attribution's phase buckets: span names summed per trace.
-# "other" is the statement remainder — with no cross-thread overlap the
-# per-trace phase sum equals the statement duration exactly.
-_PHASE_SPANS = {
-    "parse": ("parse",),
-    "plan": ("plan",),
-    "admission_wait": ("admission",),
-    "sched_stall": ("sched.slot",),
-    "device_dispatch": ("dispatch",),
-    "finalize": ("finalize",),
-    "host_fallback": ("host.fallback",),
-    "commit": ("commit",),
-}
-
-
-def phases_of(root: Span) -> dict:
-    """Per-phase nanosecond sums for one finished statement tree — the
-    latency-attribution input (bench serve/chaos blocks, ROADMAP item
-    2's p99 breakdown). Spans sum BY NAME across the whole tree (pool
-    workers included), so concurrent workers can push a phase past the
-    wall-clock statement time; "other" floors at zero."""
-    sums: dict[str, int] = {}
-
-    def walk(s: Span) -> None:
-        sums[s.name] = sums.get(s.name, 0) + s.duration_ns
+def self_times(root: Span) -> dict:
+    """name -> [self nanoseconds, spans] over one span tree, the root
+    included. A span's self time is its duration minus what its
+    children ON THE SAME THREAD cover: a child on a pool or stream
+    worker runs beside its parent and keeps its own time, so the sums
+    are thread-seconds and no interval is counted twice on one thread.
+    A span still open reads as closed now. Same-thread children are
+    sequential by construction; grafted remote trees (attach_remote)
+    may overlap, so each child only covers what the one before did not,
+    clipped to the parent. Runs once a statement (finish_statement), so
+    leaves are folded where their parent meets them, without a visit of
+    their own."""
+    now = 0
+    end = root.end_ns
+    if not end:
+        end = now = time.perf_counter_ns()
+    elif not root.children:
+        return {root.name: [end - root.start_ns, 1]}    # no walk to make
+    out: dict = {}
+    stack = [(root, end)]
+    while stack:
+        s, end = stack.pop()
+        at = s.start_ns
+        self_ns = end - at
+        tid = s.tid
         for c in s.children:
-            walk(c)
-
-    for c in root.children:
-        walk(c)
-    out = {phase: sum(sums.get(n, 0) for n in names)
-           for phase, names in _PHASE_SPANS.items()}
-    total = root.duration_ns
-    out["total"] = total
-    out["other"] = max(0, total - sum(
-        v for k, v in out.items() if k != "total"))
+            c_end = c.end_ns
+            if not c_end:
+                c_end = now = now or time.perf_counter_ns()
+            if c.tid == tid:
+                lo = c.start_ns if c.start_ns > at else at
+                hi = c_end if c_end < end else end
+                if hi > lo:
+                    self_ns -= hi - lo
+                    at = hi
+            if c.children:
+                stack.append((c, c_end))
+                continue
+            c_ns = c_end - c.start_ns
+            acc = out.get(c.name)
+            if acc is None:
+                out[c.name] = [c_ns if c_ns > 0 else 0, 1]
+            else:
+                if c_ns > 0:
+                    acc[0] += c_ns
+                acc[1] += 1
+        acc = out.get(s.name)
+        if acc is None:
+            out[s.name] = [self_ns if self_ns > 0 else 0, 1]
+        else:
+            if self_ns > 0:
+                acc[0] += self_ns
+            acc[1] += 1
     return out
 
 
