@@ -165,3 +165,84 @@ class TestIneligibleShapes:
             assert fused == plain
         finally:
             s.execute("SET tidb_tpu_superchunk_rows = 262144")
+
+
+class TestDenseBranch:
+    """The fused program shares group_partial with the group-by kernel
+    (PR 25): a Q5-shaped fragment (an inner join grouped by the build
+    side's name, SUM of a decimal product) takes the dense masked
+    reductions, a group-by as wide as the probe takes the scatters,
+    both give what the program with the dense branch shut gives and
+    what plain Python gives, and each dispatch read back moves
+    tidb_tpu_agg_dispatch_total by exactly one."""
+
+    N_PROBE, N_BUILD, NATIONS = 3000, 120, 25
+
+    def _sides(self):
+        from tidb_tpu.chunk import Chunk
+        from tidb_tpu.sqltypes import new_decimal_field
+        dec = new_decimal_field(frac=2)
+        rng = np.random.default_rng(25)
+        probe = [(i, None if i % 53 == 0 else int(rng.integers(0, 150)),
+                  int(rng.integers(0, 10**6)), int(rng.integers(0, 11)))
+                 for i in range(self.N_PROBE)]
+        build = [(i, f"NATION{i % self.NATIONS:02d}")
+                 for i in range(self.N_BUILD)]
+        import decimal
+        pch = Chunk.from_rows(
+            [FT_I, FT_I, dec, FT_I],
+            [(i, k, decimal.Decimal(a) / 100, q) for i, k, a, q in probe])
+        bch = Chunk.from_rows([FT_I, FT_S], build)
+        return probe, build, pch, bch, dec
+
+    def _run(self, k, pch, bch):
+        pk = [(pch.columns[1].data, pch.columns[1].valid)]
+        bk = [(bch.columns[0].data, bch.columns[0].valid)]
+        nb, n = bch.num_rows, pch.num_rows
+        dev = k.prepare_build(bch, bk, nb)
+        before = {p: _metric('tidb_tpu_agg_dispatch_total{path="%s"}' % p)
+                  for p in ("dense", "scatter")}
+        gr = k.finalize(pch, bch, nb, k.dispatch(dev, nb, pk, pch, n))
+        moved = {p: _metric('tidb_tpu_agg_dispatch_total{path="%s"}' % p)
+                 - before[p] for p in before}
+        return gr, moved
+
+    @pytest.mark.parametrize("shape,path", [("q5", "dense"),
+                                            ("wide", "scatter")])
+    def test_dense_scatter_and_plain_python_agree(self, monkeypatch,
+                                                  shape, path):
+        from tidb_tpu.expression import AggDesc, AggFunc, Op, func
+        from tidb_tpu.ops import hashagg
+        probe, build, pch, bch, dec = self._sides()
+        # joined schema: probe (id, k, amt, q) then build (id, name)
+        group = [ColumnRef(5, FT_S, "name")] if shape == "q5" else \
+            [ColumnRef(0, FT_I, "id")]
+        revenue = func(Op.MUL, ColumnRef(2, dec, "amt"),
+                       ColumnRef(3, FT_I, "q"))
+        aggs = [AggDesc(fn=AggFunc.SUM, arg=revenue),
+                AggDesc(fn=AggFunc.COUNT, arg=None)]
+        limit = hashagg._DENSE_SLOTS
+        assert self.NATIONS + 2 <= limit < self.N_PROBE // 2
+        got = []
+        for slots in (limit, 0):       # as it is; the dense branch shut
+            monkeypatch.setattr(hashagg, "_DENSE_SLOTS", slots)
+            k = op_fragment.ProbeAggKernel(1, 4, 6, group, aggs)
+            gr, moved = self._run(k, pch, bch)
+            want = path if slots else "scatter"
+            assert moved == {"dense": int(want == "dense"),
+                             "scatter": int(want == "scatter")}
+            got.append({key: (int(gr.partials[0][0][i]),
+                              int(gr.partials[1][0][i]))
+                        for i, key in enumerate(gr.keys)})
+        names = dict(build)
+        want = {}
+        for i, k_, amt, q in probe:
+            if k_ is None or k_ not in names:
+                continue
+            key = (names[k_],) if shape == "q5" else (i,)
+            s, c = want.get(key, (0, 0))
+            want[key] = (s + amt * q, c + 1)
+        assert got[0] == got[1] == want
+        assert (len(want) > limit) == (path == "scatter")
+        if shape == "q5":
+            assert len(want) == self.NATIONS

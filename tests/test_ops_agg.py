@@ -251,3 +251,173 @@ def test_cond_direct_wide_span_takes_hash_branch():
                       capacity=64)
     gr = k(ch)          # must not raise CollisionError
     assert sorted(int(c) for c in gr.counts) == [32, 32]
+
+
+# -- dense against scatter against the host executor (PR 25) -----------------
+
+import jax  # noqa: E402
+
+from tidb_tpu import metrics  # noqa: E402
+from tidb_tpu.ops import hashagg  # noqa: E402
+from tidb_tpu.ops.hostagg import host_hash_agg  # noqa: E402
+
+D = hashagg._DENSE_SLOTS
+BIG = 1 << 55                      # on every 20th row: 205 a bucket fit int64
+_ROWS = 4096                       # one bucket for every counted case
+_VAL_FT = [INT, DEC2, INT, INT]   # SUM, AVG, MIN/MAX/COUNT, FIRST_ROW
+
+
+def _dense_aggs():
+    return [AggDesc(AggFunc.SUM, col(1, INT)),
+            AggDesc(AggFunc.AVG, col(2, DEC2)),
+            AggDesc(AggFunc.MIN, col(3, INT)),
+            AggDesc(AggFunc.MAX, col(3, INT)),
+            AggDesc(AggFunc.COUNT, col(3, INT)),
+            AggDesc(AggFunc.COUNT, None),
+            AggDesc(AggFunc.FIRST_ROW, col(4, INT))]
+
+
+def _dense_parts(mode):
+    """(key type, group exprs, force_hash) of one group-table mode."""
+    if mode == "direct":           # dictionary-coded string keys
+        return STR, [col(0, STR)], False
+    if mode == "cond":             # bare int keys: direct at run time
+        return INT, [col(0, INT)], False
+    return INT, [col(0, INT)], True   # the packed sort over the hash
+
+
+def _dense_chunk(mode, groups, rows, null_keys):
+    """`rows` rows over `groups` distinct keys (every key used), group
+    g's MIN/MAX argument all NULL when g % 5 == 0 (an empty slot for
+    those lanes), sums past 2^53 with odd low bits; with null_keys, a
+    NULL group too."""
+    rng = np.random.default_rng(groups * 7 + rows)
+    nnull = rows // 11 if null_keys else 0
+    g = np.concatenate([np.arange(groups),
+                        rng.integers(0, groups, rows - groups - nnull),
+                        np.full(nnull, -1)]) if rows else np.zeros(0, int)
+    rng.shuffle(g)
+    out = []
+    for i, gi in enumerate(g.tolist()):
+        key = None if gi < 0 else gi if mode != "direct" else f"k{gi:05d}"
+        out.append((key, (BIG if i % 20 == 0 else 0) + int(rng.integers(1000)),
+                    decimal.Decimal(int(rng.integers(-10**6, 10**6))) / 100,
+                    None if gi % 5 == 0 else int(rng.integers(-50, 50)),
+                    i))
+    return Chunk.from_rows([_dense_parts(mode)[0]] + _VAL_FT, out)
+
+
+def _dispatches():
+    snap = metrics.snapshot()
+    return {p: snap.get('tidb_tpu_agg_dispatch_total{path="%s"}' % p, 0)
+            for p in ("dense", "scatter")}
+
+
+def _run_both(monkeypatch, mode, ch, filt=None):
+    """One block through the program as it is and through the program
+    with the dense branch shut (a table of 0 slots never fits): ->
+    the two raw result pytrees, and the two finalized GroupResults."""
+    _kt, groups, force = _dense_parts(mode)
+    raws, results, moved = [], [], []
+    for limit in (D, 0):
+        monkeypatch.setattr(hashagg, "_DENSE_SLOTS", limit)
+        k = HashAggKernel(filt, groups, _dense_aggs(), force_hash=force)
+        pending = k.dispatch(ch)
+        raws.append(jax.device_get(pending))
+        before = _dispatches()
+        results.append(k.finalize(ch, pending))
+        after = _dispatches()
+        moved.append({p: after[p] - before[p] for p in after})
+    return raws, results, moved
+
+
+def _merged(aggs, res):
+    agg = HashAggregator(aggs)
+    agg.update(res)
+    return agg.results()
+
+
+def _assert_same_block(raws, results, moved, ch, mode, filt=None):
+    (uniq, nuniq, coll, counts, rep, lanes, dense), \
+        (uniq_s, nuniq_s, coll_s, counts_s, rep_s, lanes_s, dense_s) = raws
+    # the choice follows the count, and the counter follows the choice
+    assert bool(dense) == (int(nuniq) <= D) and not bool(dense_s)
+    assert moved[0] == {"dense": int(bool(dense)),
+                        "scatter": int(not bool(dense))}
+    assert moved[1] == {"dense": 0, "scatter": 1}
+    assert int(nuniq) == int(nuniq_s) and not coll and not coll_s
+    # every lane, every slot but the masked one (capacity-1, never
+    # live: its FIRST_ROW / representative fill is the row count under
+    # a scatter and the empty segment's under a reduction)
+    np.testing.assert_array_equal(uniq, uniq_s)
+    np.testing.assert_array_equal(counts, counts_s)
+    np.testing.assert_array_equal(rep[:-1], rep_s[:-1])
+    for ls, ls_s in zip(lanes, lanes_s):
+        for lane, lane_s in zip(ls, ls_s):
+            assert lane.dtype == lane_s.dtype
+            np.testing.assert_array_equal(lane[:-1], lane_s[:-1])
+    aggs = _dense_aggs()
+    _kt, groups, _force = _dense_parts(mode)
+    host = _merged(aggs, host_hash_agg(ch, filt, groups, aggs))
+    assert _merged(aggs, results[0]) == host
+    assert _merged(aggs, results[1]) == host
+    return int(nuniq), bool(dense)
+
+
+@pytest.mark.parametrize("mode", ["direct", "cond", "hash"])
+@pytest.mark.parametrize("null_keys,rows", [(True, _ROWS - 37),
+                                            (False, _ROWS)],
+                         ids=["nullkeys-padded", "exact-bucket"])
+@pytest.mark.parametrize("slots", [D - 1, D, D + 1, None],
+                         ids=["below", "at", "above", "one-group"])
+def test_dense_equals_scatter_equals_host(monkeypatch, mode, null_keys,
+                                          rows, slots):
+    """`slots` is the count the table reports (the predicate's operand):
+    the direct modes count the code domain (groups + NULL's code + the
+    masked slot), the packed sort the distinct hashes plus the masked
+    sentinel when a padding row exists."""
+    if slots is None:
+        groups = 1
+    elif mode == "hash":
+        groups = slots - int(null_keys) - int(rows < _ROWS)
+    else:
+        groups = slots - 2
+    ch = _dense_chunk(mode, groups, rows, null_keys)
+    raws, results, moved = _run_both(monkeypatch, mode, ch)
+    nuniq, dense = _assert_same_block(raws, results, moved, ch, mode)
+    if slots is not None:
+        assert nuniq == slots and dense == (slots <= D)
+    assert sum(int(c) for c in results[0].counts) == rows
+    # the sums did pass 2^53, exactly
+    assert max(int(v) for v in results[0].partials[0][0]) > 1 << 53
+
+
+@pytest.mark.parametrize("mode", ["direct", "cond", "hash"])
+@pytest.mark.parametrize("shape", ["all-masked", "empty-chunk"])
+def test_dense_with_no_live_row(monkeypatch, mode, shape):
+    ch = _dense_chunk(mode, 9, 300 if shape == "all-masked" else 0, True)
+    filt = col(1, INT).lt(0) if shape == "all-masked" else None
+    raws, results, moved = _run_both(monkeypatch, mode, ch, filt)
+    nuniq, dense = _assert_same_block(raws, results, moved, ch, mode, filt)
+    assert nuniq == 1 and dense        # the masked slot alone
+    assert results[0].keys == [] and results[1].keys == []
+
+
+@pytest.mark.parametrize("mode", ["direct", "cond", "hash"])
+@pytest.mark.parametrize("groups,capacity,path", [
+    (200, 64, "dense"), (D + 300, 512, "scatter")])
+def test_capacity_error_on_both_branches(mode, groups, capacity, path):
+    """More groups than the table holds: the count (what the choice
+    reads too) still overshoots the capacity and finalize raises, with
+    the dispatch counted under the branch that ran."""
+    ch = _dense_chunk(mode, groups, 2048, False)
+    _kt, gexprs, force = _dense_parts(mode)
+    k = HashAggKernel(None, gexprs, _dense_aggs(), capacity=capacity,
+                      force_hash=force)
+    before = _dispatches()
+    with pytest.raises(CapacityError) as e:
+        k(ch)
+    assert e.value.needed >= groups
+    after = _dispatches()
+    assert {p: after[p] - before[p] for p in after} == \
+        {"dense": int(path == "dense"), "scatter": int(path == "scatter")}

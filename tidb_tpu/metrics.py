@@ -37,7 +37,7 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "CLUSTER_SCRAPES", "MEMBER_START_TIME",
            "DEVICE_UTILIZATION", "HBM_OCCUPANCY", "CHIP_UTILIZATION",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
-           "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES",
+           "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
            "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES"]
 
@@ -343,6 +343,14 @@ COMPILE_CACHE_HITS = "tidb_tpu_compile_cache_hits_total"
 COMPILE_CACHE_MISSES = "tidb_tpu_compile_cache_misses_total"
 KERNEL_COMPILE_SECONDS = "tidb_tpu_kernel_compile_seconds"
 KERNEL_DISPATCHES = "tidb_tpu_kernel_dispatch_total"
+# which implementation a group-by block's per-slot reductions took,
+# counted once per dispatch when its result is read back
+# (ops/hashagg.count_dispatch: the one-chip group-by kernel and the
+# fused join fragment): {path="dense"} masked reductions over the rows
+# while the block's slots in use are at most ops/hashagg._DENSE_SLOTS,
+# {path="scatter"} the segment scatters otherwise. The program decides
+# on the device from the count it already computes
+AGG_DISPATCHES = "tidb_tpu_agg_dispatch_total"
 # the statement span trees as counters (trace.py folds every ended
 # root's tree here, span_totals above): self time — a span's duration
 # less what its same-thread children cover, so thread-seconds that
@@ -479,6 +487,9 @@ _HELP = {
         "by kernel family.",
     KERNEL_DISPATCHES:
         "Device kernel dispatches, by kernel family.",
+    AGG_DISPATCHES:
+        "Group-by dispatches read back, by the per-slot reduction "
+        "their block took (dense|scatter).",
     SPAN_SELF_SECONDS:
         "Statement span self time (thread-seconds), by span name.",
     SPAN_COUNT: "Statement spans ended, by span name.",

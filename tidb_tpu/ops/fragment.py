@@ -145,7 +145,7 @@ class ProbeAggKernel:
         for lane, j in enumerate(self.build_used):
             d, v = bcols[lane]
             joined[j] = (d[ri], v[ri] & ok)
-        uniq, nuniq, collided, counts, rep, lanes = group_partial(
+        uniq, nuniq, collided, counts, rep, lanes, dense = group_partial(
             xp, self.group_exprs, self.aggs, joined, out_cap, ok,
             self.capacity, force_hash=self.force_hash,
             direct_limit=self.direct_limit)
@@ -154,7 +154,7 @@ class ProbeAggKernel:
         # chunks without ever reading the full li/ri buffers back
         repc = xp.clip(rep, 0, out_cap - 1)
         return (uniq, nuniq, collided, counts, li[repc], ri[repc],
-                lanes, total)
+                lanes, total, dense)
 
     # -- sizing (device-ledger billing, from shapes alone) -------------------
 
@@ -228,7 +228,8 @@ class ProbeAggKernel:
         device->host read of the group tables, then the host
         late-materialize tail."""
         from tidb_tpu import memtrack
-        from tidb_tpu.ops.hashagg import CapacityError, CollisionError
+        from tidb_tpu.ops.hashagg import (CapacityError, CollisionError,
+                                          count_dispatch)
         root = memtrack.current()
         extra = 0
         try:
@@ -246,10 +247,11 @@ class ProbeAggKernel:
                 p.res = self._jit(bkeys, p.pk, p.pcols, bcols, p.nb,
                                   p.np_, out_cap=p.cap)
             (uniq, nuniq, collided, counts, rep_li, rep_ri, lanes,
-             _total) = jax.device_get(p.res)
+             _total, dense) = jax.device_get(p.res)
         finally:
             if root is not None and extra:
                 root.release(device=extra)
+        count_dispatch(dense)
         if int(nuniq) > self.capacity:
             err = CapacityError(f"distinct groups {int(nuniq)} > "
                                 f"capacity {self.capacity}")

@@ -6,12 +6,17 @@ mvmap hash table, row-at-a-time aggCtx updates) and the storage-side agg of
 mocktikv/aggregate.go. The dynamic hash table becomes a TPU-friendly
 sort-based group-by (SURVEY.md §7 "Device hash tables", Plan A):
 
-    1. mix group-key lanes into a 64-bit hash per row (masked rows get a
-       sentinel bucket)
-    2. jnp.unique(size=capacity) -> sorted group hashes + inverse ids
-       (static shapes; capacity overflow detected and surfaced)
-    3. jax.ops.segment_* reduces produce fixed-width partial states
-    4. a second independent hash verifies per-group key agreement, so a
+    1. give every row a slot: dictionary / small-range keys index slots
+       directly by their codes; anything else mixes the key lanes into a
+       64-bit hash and ONE packed sort (_group_table) yields the group
+       table, the rows' slots and the true distinct count (static
+       shapes; capacity overflow detected and surfaced). Masked rows
+       land in a sentinel slot
+    2. reduce every partial-state lane per slot (_SegBatch): a masked
+       reduction over the row axis per slot while the block's slots in
+       use are few, jax.ops.segment_* scatters otherwise, chosen at run
+       time from the count step 1 already has
+    3. a second independent hash verifies per-group key agreement, so a
        64-bit collision is *detected* (collision -> caller falls back to
        the host path) rather than silently merging groups
 
@@ -31,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tidb_tpu import devplane
+from tidb_tpu import devplane, metrics
 from tidb_tpu.chunk import Chunk
 from tidb_tpu.expression import AggDesc, AggFunc, Expression
 from tidb_tpu.ops import runtime
@@ -40,7 +45,7 @@ from tidb_tpu.sqltypes import EvalType
 __all__ = ["AggSpec", "HashAggKernel", "ScalarAggKernel", "HashAggregator",
            "CapacityError", "CollisionError", "DeviceRejectError",
            "GroupResult", "finalize_group_result", "kernel_for",
-           "group_partial"]
+           "group_partial", "count_dispatch"]
 
 AggSpec = AggDesc  # the planner's descriptor doubles as the kernel spec
 
@@ -143,12 +148,13 @@ def _direct_group_mode(group_exprs) -> bool:
 
 # lint: exempt[dtype-discipline] group codes carry exact int64 key values (scaled decimals / epoch-micros exceed float range)
 def _direct_group_table(xp, group_exprs, cols, n, mask, C, pmax_axes=None):
-    """Direct-indexed group table -> (uniq[C], inv[n] i32, tot).
+    """Direct-indexed group slots -> (inv[n] i32, tot).
     Strides come from data maxima (pmax over the mesh axes so every
     shard agrees on the slot space). Slot C-1 is the masked-rows slot;
     combined codes clamp to C-2 and `tot` overshoots _C when clamping
     occurred, so the capacity-escalation path re-plans exactly as in
-    the hash mode. uniq holds the combined code per live slot."""
+    the hash mode. A live slot's identity is its own index, so the
+    table follows from the per-slot row counts (_slot_uniq)."""
     combined = None
     for g in group_exprs:
         d, v = g.eval_xp(xp, cols, n)
@@ -164,9 +170,15 @@ def _direct_group_table(xp, group_exprs, cols, n, mask, C, pmax_axes=None):
     tot = xp.max(xp.where(mask, combined, -1)) + 2
     slot = xp.minimum(combined, C - 2).astype(jnp.int32)
     inv = xp.where(mask, slot, C - 1).astype(jnp.int32)
-    uniq = xp.full(C, _FILL, dtype=jnp.int64).at[inv].set(
-        xp.where(mask, xp.minimum(combined, C - 2), _SENTINEL_MASKED))
-    return uniq, inv, tot
+    return inv, tot
+
+
+def _slot_uniq(xp, ids, mask, C):
+    """uniq[C] of a direct-indexed table from `ids` (each live slot's
+    identity, _FILL in an empty one) with no pass over the rows: slot
+    C-1 holds the masked sentinel as soon as one row is masked."""
+    return ids.at[C - 1].set(
+        xp.where(xp.all(mask), _FILL, _SENTINEL_MASKED))
 
 
 def _cond_direct_mode(group_exprs) -> bool:
@@ -202,7 +214,10 @@ def _cond_group_table(xp, group_exprs, cols, n, mask, h, C,
     below the table capacity (tidb_tpu_direct_agg_slots): a
     capacity-escalated retry keeps a bounded direct domain and degrades
     wide spans to the hash branch instead of ballooning the
-    direct-indexed table."""
+    direct-indexed table. -> (uniq, inv, tot, small): on the direct
+    branch (`small`) a slot's rows share one hash, so its table is one
+    min lane of the caller's batch (_group_slots) and `uniq` here is
+    all _FILL."""
     codes = []
     spans = []
     span_fs = []
@@ -241,19 +256,14 @@ def _cond_group_table(xp, group_exprs, cols, n, mask, h, C,
         tot = xp.max(xp.where(mask, combined, -1)) + 2
         slot = xp.minimum(combined, C - 2).astype(jnp.int32)
         inv = xp.where(mask, slot, C - 1).astype(jnp.int32)
-        # slot IDENTITY is the key-tuple hash, not the dense code:
-        # the cross-shard re-unique merge quantizes top bits, which
-        # would collapse small codes into one group (hash values keep
-        # the hash mode's merge contract exactly)
-        uniq = xp.full(C, _FILL, dtype=jnp.int64).at[inv].set(
-            xp.where(mask, h, _SENTINEL_MASKED))
-        return uniq, inv, tot.astype(jnp.int64)
+        return (xp.full(C, _FILL, dtype=jnp.int64), inv,
+                tot.astype(jnp.int64))
 
     def hashed(_):
         uniq, inv, tot = _group_table(xp, h, n, C, mask=mask)
         return uniq, inv, jnp.asarray(tot, jnp.int64)
 
-    return lax.cond(small, direct, hashed, None)
+    return (*lax.cond(small, direct, hashed, None), small)
 
 
 # lint: exempt[dtype-discipline] packed sort rides the int64 hash lanes (row index bit-packed into the low hash bits)
@@ -303,20 +313,58 @@ def _group_table(xp, x, m, C, mask=None):
 _SEG_FNS = {"sum": jax.ops.segment_sum,
             "min": jax.ops.segment_min,
             "max": jax.ops.segment_max}
+_RED_FNS = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+
+# A block whose slots in use number at most _DENSE_SLOTS reduces each
+# slot by a masked pass over the rows instead of scattering the rows
+# into the table: the largest power of two at which that still costs
+# half the scatters on a 524,288-row block with TPC-H Q1's lanes (TPU
+# v5e; the table is in PERF.md section 6, PR 25). One pass over the rows
+# reduces _DENSE_PASS slots, and only the passes below the count run.
+_DENSE_SLOTS = 1024
+_DENSE_PASS = 16
+
+
+def _empty_segment(op: str, dtype):
+    """What jax.ops.segment_<op> leaves in a segment no row landed in:
+    the identity the masked reduction starts from."""
+    if op == "sum":
+        return np.zeros((), dtype)
+    floating = jnp.issubdtype(dtype, jnp.floating)
+    if op == "min":
+        return np.array(np.inf if floating else np.iinfo(dtype).max, dtype)
+    return np.array(-np.inf if floating else np.iinfo(dtype).min, dtype)
 
 
 class _SegBatch:
-    """Batches segment reductions: every requested lane with the same
-    (merge-op, dtype) reduces in ONE wide segment op over stacked [n, k]
-    data instead of k separate scatters. Scatter passes dominate the
-    group-by program (CPU XLA scatters are serial; on TPU each scatter
-    is a full HBM pass), so Q1's ~16 per-lane scatters collapse to ~4.
-    dtype-separated stacking keeps int64 lanes exact (decimal sums can
-    exceed 2^53 — promoting through float64 would corrupt them)."""
+    """Batches the per-slot reductions: every requested lane with the
+    same (merge-op, dtype) reduces together, so Q1's ~20 lanes are three
+    reductions and not twenty. dtype-separated stacking keeps int64
+    lanes exact (decimal sums can exceed 2^53 - promoting through
+    float64 would corrupt them).
 
-    def __init__(self, inv, capacity: int):
+    Two implementations, chosen at run time from `nuniq` (the table's
+    count of slots in use, which every slot a row can land in is below,
+    bar the masked slot capacity-1 whose rows carry only identities):
+
+    * scatter: one jax.ops.segment_* over stacked [n, k] lanes. On the
+      TPU that is a row-at-a-time update, ~78 ns a row whether 1 lane
+      or 12 and whatever the table's size (emulated int64; 122 ms a
+      524,288-row block for Q1's three), and on the CPU a serial loop.
+    * dense (nuniq <= _DENSE_SLOTS): slot s is
+      reduce_rows(where(inv == s, lanes, identity)), _DENSE_PASS slots a
+      pass, on the vector unit in the lanes' own dtype; slots at or
+      past the count hold what a scatter leaves in an empty segment.
+
+    With nuniq None (a table as long as the rows: ops/streamagg, the
+    one-slot scalar kernel) the scatter alone is traced. `dense` is the
+    traced predicate (None then)."""
+
+    def __init__(self, inv, capacity: int, nuniq=None):
         self.inv = inv
         self.capacity = capacity
+        self.nuniq = nuniq
+        self.dense = None
         self._reqs: list = []     # (op, array[n])
         self._out: list | None = None
 
@@ -324,12 +372,15 @@ class _SegBatch:
         self._reqs.append((op, x))
         return len(self._reqs) - 1
 
-    def run(self) -> None:
-        out: list = [None] * len(self._reqs)
+    def _groups(self) -> dict:
         groups: dict = {}
         for i, (op, x) in enumerate(self._reqs):
             groups.setdefault((op, x.dtype), []).append((i, x))
-        for (op, _dt), reqs in groups.items():
+        return groups
+
+    def _scatter(self) -> list:
+        out: list = [None] * len(self._reqs)
+        for (op, _dt), reqs in self._groups().items():
             fn = _SEG_FNS[op]
             if len(reqs) == 1:
                 i, x = reqs[0]
@@ -339,7 +390,40 @@ class _SegBatch:
                 r = fn(stk, self.inv, num_segments=self.capacity)
                 for j, (i, _x) in enumerate(reqs):
                     out[i] = r[:, j]
-        self._out = out
+        return out
+
+    def _dense(self) -> list:
+        C, P = self.capacity, _DENSE_PASS
+        rows = -(-C // P) * P           # the table, in whole passes
+        todo = (jnp.minimum(self.nuniq, C).astype(jnp.int32) + (P - 1)) // P
+        inv = self.inv[None, None, :]
+        slot_ids = jnp.arange(P, dtype=jnp.int32)[:, None, None]
+        out: list = [None] * len(self._reqs)
+        for (op, dt), reqs in self._groups().items():
+            ident = _empty_segment(op, dt)
+            red = _RED_FNS[op]
+            # rows minor: [k, n] tiles the row axis over the vector
+            # lanes, and the [P, k, n] select fuses into the reduce
+            stk = jnp.stack([x for _i, x in reqs])[None]
+
+            def one_pass(p, acc):
+                hit = inv == slot_ids + p * P
+                part = red(jnp.where(hit, stk, ident), axis=2)
+                return lax.dynamic_update_slice(acc, part,
+                                                (p * P, jnp.int32(0)))
+
+            acc = lax.fori_loop(0, todo, one_pass,
+                                jnp.full((rows, len(reqs)), ident, dt))
+            for j, (i, _x) in enumerate(reqs):
+                out[i] = acc[:C, j]
+        return out
+
+    def run(self) -> None:
+        if self.nuniq is None:
+            self._out = self._scatter()
+            return
+        self.dense = self.nuniq <= _DENSE_SLOTS
+        self._out = lax.cond(self.dense, self._dense, self._scatter)
 
     def get(self, i: int):
         return self._out[i]
@@ -478,38 +562,61 @@ def finalize_group_result(chunk: Chunk, group_exprs, aggs, gidx: np.ndarray,
     return GroupResult(keys=keys, partials=partials, counts=counts)
 
 
-# lint: exempt[dtype-discipline] int64 slot init: group slots hold exact key codes and decimal sums
+# lint: exempt[dtype-discipline] int64 slot identities: exact key codes and key hashes (splitmix64 bit patterns)
+def _group_slots(xp, group_exprs, cols, n, mask, C, force_hash=False,
+                 direct_limit=None, pmax_axes=None):
+    """Give every row its slot, by the group keys' shape: direct-indexed
+    (dictionary strings), runtime-selected (bare int / dict keys) or the
+    packed sort over the key hash. -> (batch, h2, uniq_of): a _SegBatch
+    over the rows' slots that knows the table's count (batch.nuniq, the
+    caller's overflow check) with the table's own lane enqueued where
+    it has one; the check hash per row; and uniq_of(counts) -> uniq[C]
+    for after batch.run(). pmax_axes: the mesh axes a sharded caller's
+    slot space must agree over (ops/meshagg.py)."""
+    if not force_hash and _direct_group_mode(group_exprs):
+        inv, nuniq = _direct_group_table(
+            xp, group_exprs, cols, n, mask, C, pmax_axes=pmax_axes)
+        # no hash, no collisions: the check trivially passes
+        h2 = xp.zeros(n, dtype=jnp.int64)
+        return _SegBatch(inv, C, nuniq), h2, lambda counts: _slot_uniq(
+            xp, xp.where(counts > 0, xp.arange(C), _FILL), mask, C)
+    key_cols = [g.eval_xp(xp, cols, n) for g in group_exprs]
+    h = _hash_keys(xp, key_cols, n, seed=0x517CC1B727220A95)
+    h2 = _hash_keys(xp, key_cols, n, seed=0x2545F4914F6CDD1D)
+    if not force_hash and _cond_direct_mode(group_exprs):
+        uniq, inv, nuniq, small = _cond_group_table(
+            xp, group_exprs, cols, n, mask, h, C, pmax_axes=pmax_axes,
+            direct_limit=direct_limit)
+        b = _SegBatch(inv, C, nuniq)
+        # slot IDENTITY on the direct branch is the key-tuple hash, not
+        # the dense code: the cross-shard re-unique merge quantizes top
+        # bits, which would collapse small codes into one group (hash
+        # values keep the hash mode's merge contract exactly). _FILL
+        # is no hash (_hash_keys), so an empty slot reads _FILL
+        i_id = b.add(xp.where(mask, h, _FILL), "min")
+        return b, h2, lambda _counts: xp.where(
+            small, _slot_uniq(xp, b.get(i_id), mask, C), uniq)
+    # one packed sort -> group table + inverse + true distinct count
+    # (incl. masked sentinel) for overflow detection
+    uniq, inv, nuniq = _group_table(xp, h, n, C, mask=mask)
+    return _SegBatch(inv, C, nuniq), h2, lambda _counts: uniq
+
+
+# lint: exempt[dtype-discipline] int64 header lanes: exact row counts and the int64 check hash
 def group_partial(xp, group_exprs, aggs, cols, n, mask, capacity,
                   force_hash: bool = False, direct_limit=None):
     """The traced group+partial-agg phase shared by HashAggKernel and
-    the fused pipeline-fragment kernel (ops/fragment.py): group table
-    (direct-indexed / runtime-selected / packed-sort per the group-key
-    shape), one batched scatter pass per (merge-op, dtype), dual-hash
+    the fused pipeline-fragment kernel (ops/fragment.py): the rows'
+    slots (_group_slots), one batched reduction per (merge-op, dtype)
+    for the header lanes + every aggregate (_SegBatch), dual-hash
     collision check. `cols` entries may be None for columns no
     group/agg expression reads (the fragment kernel gathers only used
-    lanes). -> (uniq, nuniq, collided, counts, rep, lanes)."""
-    if not force_hash and _direct_group_mode(group_exprs):
-        uniq, inv, nuniq = _direct_group_table(
-            xp, group_exprs, cols, n, mask, capacity)
-        h2 = xp.zeros(n, dtype=jnp.int64)
-    elif not force_hash and _cond_direct_mode(group_exprs):
-        key_cols = [g.eval_xp(xp, cols, n) for g in group_exprs]
-        h = _hash_keys(xp, key_cols, n, seed=0x517CC1B727220A95)
-        h2 = _hash_keys(xp, key_cols, n, seed=0x2545F4914F6CDD1D)
-        uniq, inv, nuniq = _cond_group_table(
-            xp, group_exprs, cols, n, mask, h, capacity,
-            direct_limit=direct_limit)
-    else:
-        key_cols = [g.eval_xp(xp, cols, n) for g in group_exprs]
-        h = _hash_keys(xp, key_cols, n, seed=0x517CC1B727220A95)
-        h2 = _hash_keys(xp, key_cols, n, seed=0x2545F4914F6CDD1D)
-        # one packed sort -> group table + inverse + true distinct
-        # count (incl. masked sentinel) for overflow detection
-        uniq, inv, nuniq = _group_table(xp, h, n, capacity, mask=mask)
-    # one batched scatter pass per (merge-op, dtype) for the header
-    # lanes + every aggregate (see _SegBatch)
+    lanes). -> (uniq, nuniq, collided, counts, rep, lanes, dense);
+    `dense` says which of _SegBatch's two implementations ran."""
+    b, h2, uniq_of = _group_slots(xp, group_exprs, cols, n, mask, capacity,
+                                  force_hash=force_hash,
+                                  direct_limit=direct_limit)
     mask_i = mask.astype(jnp.int64)
-    b = _SegBatch(inv, capacity)
     i_cmin = b.add(xp.where(mask, h2, _I64_MAX), "min")
     i_cmax = b.add(xp.where(mask, h2, _I64_MIN), "max")
     i_live = b.add(mask_i, "max")
@@ -523,7 +630,14 @@ def group_partial(xp, group_exprs, aggs, cols, n, mask, capacity,
     counts = b.get(i_cnt)
     rep = b.get(i_rep)
     lanes = [[l for l, _op in assemble(b.get)] for assemble in assembles]
-    return uniq, nuniq, collided, counts, rep, lanes
+    return uniq_of(counts), b.nuniq, collided, counts, rep, lanes, b.dense
+
+
+def count_dispatch(dense) -> None:
+    """One group-by dispatch read back: which of _SegBatch's two
+    implementations its block took."""
+    metrics.counter(metrics.AGG_DISPATCHES,
+                    {"path": "dense" if bool(dense) else "scatter"})
 
 
 class HashAggKernel:
@@ -611,7 +725,9 @@ class HashAggKernel:
         """Blocking half: one batched device->host transfer for the whole
         result pytree (per-array reads each pay a full device round
         trip), then the host tail."""
-        uniq, nuniq, collided, counts, rep, lanes = jax.device_get(pending)
+        uniq, nuniq, collided, counts, rep, lanes, dense = \
+            jax.device_get(pending)
+        count_dispatch(dense)
         # capacity before collision: overflow groups clamp into the last
         # slot, which then trips the collision check spuriously
         if int(nuniq) > self.capacity:

@@ -38,10 +38,8 @@ from tidb_tpu.expression import AggDesc, AggFunc, Expression
 from tidb_tpu.ops import runtime
 from tidb_tpu.ops.hashagg import (CapacityError, CollisionError, GroupResult,
                                   _FILL, _SENTINEL_MASKED, _I64_MAX, _I64_MIN,
-                                  _SegBatch, _agg_requests,
-                                  _cond_direct_mode, _cond_group_table,
-                                  _direct_group_mode, _direct_group_table,
-                                  _group_table, _hash_keys,
+                                  _agg_requests, _direct_group_mode,
+                                  _group_slots, _group_table,
                                   _validate_device_exprs,
                                   finalize_group_result)
 
@@ -64,33 +62,14 @@ def group_merge_program(xp, cols, mask, ln, offs, group_exprs, aggs,
     (global original probe row index per row) replaces offs+arange for
     the representative/FIRST_ROW lanes when rows were compacted."""
     direct = _direct_group_mode(group_exprs)
-    axes = (AXIS,) if ndev > 1 else None
-    if direct:
-        # dense dict codes index slots directly: no sort, no hash, no
-        # collisions (h2 lanes are zeros so the check trivially passes)
-        uniq, inv, local_tot = _direct_group_table(
-            xp, group_exprs, cols, ln, mask, C, pmax_axes=axes)
-        # lint: exempt[dtype-discipline] h2 lanes ride the int64 hash dtype (splitmix64 bit patterns)
-        h2 = xp.zeros(ln, dtype=jnp.int64)
-    elif _cond_direct_mode(group_exprs):
-        # bare int/dict keys: RUNTIME range check picks direct slots
-        # when the span fits capacity, packed-sort hash table otherwise
-        key_cols = [g.eval_xp(xp, cols, ln) for g in group_exprs]
-        h = _hash_keys(xp, key_cols, ln, seed=0x517CC1B727220A95)
-        h2 = _hash_keys(xp, key_cols, ln, seed=0x2545F4914F6CDD1D)
-        uniq, inv, local_tot = _cond_group_table(
-            xp, group_exprs, cols, ln, mask, h, C, pmax_axes=axes)
-    else:
-        key_cols = [g.eval_xp(xp, cols, ln) for g in group_exprs]
-        h = _hash_keys(xp, key_cols, ln, seed=0x517CC1B727220A95)
-        h2 = _hash_keys(xp, key_cols, ln, seed=0x2545F4914F6CDD1D)
-        uniq, inv, local_tot = _group_table(xp, h, ln, C, mask=mask)
-
-    # one _SegBatch for the header lanes + every aggregate: all lanes
-    # with the same (merge-op, dtype) reduce in one wide scatter pass
+    # local slots by the keys' shape, exactly as on one chip; the slot
+    # space of the direct modes is agreed over the batch axis. The
+    # header lanes + every aggregate share the one _SegBatch, which
+    # picks dense or scatter from this shard's own count
+    b, h2, uniq_of = _group_slots(xp, group_exprs, cols, ln, mask, C,
+                                  pmax_axes=(AXIS,) if ndev > 1 else None)
     # lint: exempt[dtype-discipline] int64 COUNT lane: exact past 2^53 rows, matches the agg-state stacking dtype
     mask_i = mask.astype(jnp.int64)
-    b = _SegBatch(inv, C)
     i_cnt = b.add(mask_i, "sum")
     i_h2min = b.add(xp.where(mask, h2, _I64_MAX), "min")
     i_h2max = b.add(xp.where(mask, h2, _I64_MIN), "max")
@@ -103,6 +82,7 @@ def group_merge_program(xp, cols, mask, ln, offs, group_exprs, aggs,
                                row_ids=row_ids)
                  for a in aggs]
     b.run()
+    uniq, local_tot = uniq_of(b.get(i_cnt)), b.nuniq
 
     lanes: list[tuple] = []  # (array[C], merge_op)
     lanes.append((b.get(i_cnt), "sum"))                            # cnt
