@@ -220,6 +220,7 @@ class MiniClient:
     def stmt_close(self, sid: int) -> None:
         self.pkt.reset_seq()
         self.pkt.write_packet(bytes([0x19]) + struct.pack("<I", sid))
+        self.pkt.flush()                          # no reply to read
 
     @staticmethod
     def _parse_binary_row(pkt: bytes, cols) -> tuple:
@@ -273,6 +274,7 @@ class MiniClient:
         try:
             self.pkt.reset_seq()
             self.pkt.write_packet(b"\x01")       # COM_QUIT
+            self.pkt.flush()
         except OSError:
             pass
         self.sock.close()

@@ -161,3 +161,131 @@ class TestConcurrency:
         _cols, rows = check.query("SELECT COUNT(*) FROM p")
         assert rows == [("8",)]
         check.close()
+
+
+class _RecordingSock:
+    """A socket that keeps each `sendall` apart."""
+
+    def __init__(self):
+        self.sends = []
+
+    def sendall(self, b):
+        self.sends.append(bytes(b))
+
+
+def _framed(payloads):
+    """What the per-packet writer put on the wire: header + payload each,
+    sequence numbers from 0."""
+    return b"".join(
+        len(p).to_bytes(3, "little") + bytes([i & 0xFF]) + p
+        for i, p in enumerate(payloads))
+
+
+class TestOneWriteAResponse:
+    """The packet writer buffers a command's packets and flushes before
+    it next reads: many small writes met Nagle's algorithm and the
+    client's delayed ACK, 40 ms a statement."""
+
+    def test_q1_shaped_resultset_leaves_in_one_sendall(self):
+        from tidb_tpu import metrics
+        from tidb_tpu.server import ClientConn
+        from tidb_tpu.server.packet import lenenc_int
+        from tidb_tpu.session import ResultSet
+
+        sock = _RecordingSock()
+        conn = ClientConn(server=None, sock=sock, conn_id=1)
+        cols = [f"c{i}" for i in range(10)]
+        rows = [tuple(f"{r}.{c}" for c in range(10)) for r in range(4)]
+        calls = metrics.snapshot().get(metrics.WIRE_WRITE_CALLS, 0)
+        conn._write_resultset(ResultSet(cols, rows))
+        assert len(sock.sends) == 1
+        assert metrics.snapshot()[metrics.WIRE_WRITE_CALLS] - calls == 1
+        eof = b"\xfe\x00\x00\x02\x00"
+        packets = [lenenc_int(10)]
+        packets += [ClientConn._column_def(c, None) for c in cols]
+        packets += [eof] + [ClientConn._encode_row(r) for r in rows] + [eof]
+        assert len(packets) == 17
+        assert sock.sends[0] == _framed(packets)
+
+    def test_write_packet_alone_writes_nothing(self):
+        from tidb_tpu.server.packet import PacketIO
+        sock = _RecordingSock()
+        pkt = PacketIO(sock)
+        pkt.write_packet(b"abc")
+        pkt.write_packet(b"")
+        assert sock.sends == [] and pkt.sent == 11 and pkt.seq == 2
+        pkt.flush()
+        pkt.flush()                               # nothing left: no write
+        assert sock.sends == [_framed([b"abc", b""])]
+
+    def test_read_flushes_first(self):
+        import socket
+
+        from tidb_tpu.server.packet import PacketIO
+        a, b = socket.socketpair()
+        try:
+            b.settimeout(10)
+            server = PacketIO(a)
+            server.write_packet(b"\x00reply")
+            b.sendall(_framed([b"\x0e"]))
+            # the read hands over what the writer held: the server never
+            # blocks on a peer that is waiting for bytes in its buffer
+            assert server.read_packet() == b"\x0e"
+            assert b.recv(64) == _framed([b"\x00reply"])
+        finally:
+            a.close()
+            b.close()
+
+    def test_large_resultset_streams_in_bounded_pieces(self, cli,
+                                                       monkeypatch):
+        from tidb_tpu.server import packet
+        n, width = 6000, 96            # ~ 600 KiB: nine times the bound
+        cli.query("CREATE TABLE big (a BIGINT PRIMARY KEY, s VARCHAR(120))")
+        for lo in range(0, n, 1000):
+            cli.query("INSERT INTO big VALUES " + ",".join(
+                f"({i}, '{str(i).rjust(width, 'x')}')"
+                for i in range(lo, lo + 1000)))
+        held = []                      # the buffer's size at each flush
+        flush = packet.PacketIO.flush
+
+        def watched(self):
+            held.append(len(self._out))
+            flush(self)
+
+        monkeypatch.setattr(packet.PacketIO, "flush", watched)
+        _cols, rows = cli.query("SELECT a, s FROM big ORDER BY a")
+        assert rows == [(str(i), str(i).rjust(width, "x"))
+                        for i in range(n)]
+        assert n * width > 8 * packet.FLUSH_BYTES
+        assert max(held) <= packet.FLUSH_BYTES
+        assert sum(1 for h in held if h) >= 9
+
+    def test_failed_login_err_arrives_before_the_close(self, srv):
+        with pytest.raises(MySQLError) as e:
+            MiniClient("127.0.0.1", srv.port, user="nobody",
+                       password="wrong")
+        assert e.value.code == 1045 and "Access denied" in str(e.value)
+
+    def test_accepted_socket_has_nodelay(self, srv, cli):
+        import socket
+        with srv._mu:
+            socks = [c.sock for c in srv._conns]
+        assert socks
+        for s in socks:
+            assert s.getsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY) != 0
+
+    def test_back_to_back_selects_do_not_wait_for_a_delayed_ack(self, cli):
+        import statistics
+        import time
+        cli.query("CREATE TABLE q (a BIGINT PRIMARY KEY, b INT, c INT)")
+        cli.query("INSERT INTO q VALUES " + ",".join(
+            f"({i}, {i * 2}, {i * 3})" for i in range(4)))
+        took = []
+        for _ in range(20):
+            t0 = time.perf_counter()
+            _cols, rows = cli.query("SELECT a, b, c FROM q ORDER BY a")
+            took.append(time.perf_counter() - t0)
+            assert len(rows) == 4
+        # 3 column definitions + 4 rows + the rest: ten packets a reply.
+        # Sent one by one, every reply after the first waits 40 ms
+        assert statistics.median(took) < 0.025, took

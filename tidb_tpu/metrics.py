@@ -39,7 +39,7 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
            "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
-           "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES"]
+           "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS"]
 
 _lock = threading.Lock()
 _counters: dict[tuple[str, tuple], float] = {}       # guarded-by: _lock
@@ -371,6 +371,9 @@ H2D_BYTES = "tidb_tpu_h2d_bytes_total"
 # span and outside sum_latency_ns
 WIRE_WRITE_SECONDS = "tidb_tpu_wire_write_seconds_total"
 WIRE_WRITE_BYTES = "tidb_tpu_wire_write_bytes_total"
+# one per socket write the packet writer makes (server/packet.py
+# PacketIO.flush), whatever the reply: a response should cost one
+WIRE_WRITE_CALLS = "tidb_tpu_wire_write_calls_total"
 
 _HELP = {
     QUERY_DURATIONS: "Statement wall time through Session.execute.",
@@ -497,4 +500,5 @@ _HELP = {
     WIRE_WRITE_SECONDS:
         "Result-set encoding + socket write time on the wire.",
     WIRE_WRITE_BYTES: "Result-set bytes written to client sockets.",
+    WIRE_WRITE_CALLS: "Socket writes made by the MySQL packet writer.",
 }

@@ -131,6 +131,9 @@ class Server:
             metrics.gauge(metrics.CONNECTIONS_CURRENT, len(self._conns))
         metrics.counter(metrics.CONNECTIONS)
         try:
+            # a reply's last segment never waits for the ACK of the one
+            # before it (Go's net sets this on the reference's listener)
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             conn.run()
         except (ConnectionError, OSError):
             pass   # peer went away; engine errors surface via ERR packets
@@ -239,6 +242,10 @@ class ClientConn:
     def close(self) -> None:
         with self._close_mu:
             session, self.session = self.session, None
+        try:
+            self.pkt.flush()   # an ERR after a failed login still arrives
+        except OSError:
+            pass
         if session is not None:
             session.close()
         try:
@@ -541,6 +548,7 @@ class ClientConn:
                 failpoint.eval("wire/resultset", self, n)
                 self.pkt.write_packet(self._encode_row(row))
             self._write_eof()
+            self.pkt.flush()   # inside the timed region: the socket write
         finally:
             metrics.counter(metrics.WIRE_WRITE_SECONDS,
                             inc=time.perf_counter() - t0)

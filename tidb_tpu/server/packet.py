@@ -10,16 +10,28 @@ from __future__ import annotations
 import socket
 import struct
 
+from tidb_tpu import metrics
+
 MAX_PAYLOAD = 0xFFFFFF
+# the output buffer is handed to the socket before it would pass this:
+# a large result set streams in bounded pieces and is never held whole
+FLUSH_BYTES = 64 << 10
 
 
 class PacketIO:
-    """Framed packet reader/writer over a socket with sequence tracking."""
+    """Framed packet reader/writer over a socket with sequence tracking.
+
+    Writes are buffered (ref: packetio.go's bufio.Writer, which conn.go
+    flushes once a response): `write_packet` only frames, `flush` is the
+    one socket write. A response of many small packets sent one by one
+    meets Nagle's algorithm and the peer's delayed ACK, 40 ms a reply.
+    """
 
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.seq = 0
-        self.sent = 0       # bytes handed to the socket, headers included
+        self.sent = 0       # bytes framed for the socket, headers included
+        self._out = bytearray()
 
     def _recv_exact(self, n: int) -> bytes:
         buf = b""
@@ -31,6 +43,8 @@ class PacketIO:
         return buf
 
     def read_packet(self) -> bytes:
+        # never block on the peer while holding bytes it is waiting for
+        self.flush()
         payload = b""
         while True:
             header = self._recv_exact(4)
@@ -45,12 +59,23 @@ class PacketIO:
         while True:
             chunk = payload[off:off + MAX_PAYLOAD]
             header = struct.pack("<I", len(chunk))[:3] + bytes([self.seq])
-            self.sock.sendall(header + chunk)
+            if len(self._out) + 4 + len(chunk) > FLUSH_BYTES:
+                self.flush()
+            self._out += header
+            self._out += chunk
             self.sent += 4 + len(chunk)
             self.seq = (self.seq + 1) & 0xFF
             off += len(chunk)
             if len(chunk) < MAX_PAYLOAD:
                 return
+
+    def flush(self) -> None:
+        """Hand everything framed so far to the socket in one write."""
+        if not self._out:
+            return
+        out, self._out = self._out, bytearray()
+        metrics.counter(metrics.WIRE_WRITE_CALLS)
+        self.sock.sendall(out)
 
     def reset_seq(self) -> None:
         self.seq = 0
