@@ -7,7 +7,10 @@ executed operations ("XLA Ops"); host spans are the benchmark's own
 
   busy        union of the device-op intervals inside the window
   idle share  1 - busy / window
-  op ranking  total duration by op name, nested ops included as traced
+  op ranking  self time by op name: an op's interval less the ops
+              nested inside it on its device's line, so a `conditional`
+              or `while` counts what it does itself and the ranking
+              sums to busy
   collectives union of the collective ops' intervals (sync and async)
   gaps        the complement of busy, attributed to what the host was
               doing: each `inside_<statement>` span by name, and
@@ -125,6 +128,23 @@ def host_spans(planes) -> tuple[list, float | None, float | None]:
     return spans, begin, end
 
 
+def self_times(ops) -> list[tuple[str, float, float]]:
+    """[(name, start, self time)] of one device line's operations: each
+    one's duration less those of the operations nested directly inside
+    it. A device line runs one thing at a time, so two of its operations
+    are disjoint or one holds the other; an overlap that is not nesting
+    leaves both whole."""
+    own, holding = [], []        # holding: (end, index into own), outermost first
+    for name, s, e in sorted(ops, key=lambda o: (o[1], -o[2])):
+        while holding and holding[-1][0] < e:
+            holding.pop()
+        if holding:
+            own[holding[-1][1]][2] -= e - s
+        holding.append((e, len(own)))
+        own.append([name, s, e - s])
+    return [tuple(o) for o in own]
+
+
 def _module_of(modules, at: float) -> str:
     for name, s, e in modules:
         if s <= at < e:
@@ -177,12 +197,11 @@ def reduce(planes, spans=None, begin_host_s=None) -> dict | None:
         busy.append(union((s, e) for _n, s, e in inside))
         coll = [(max(s, lo), min(e, hi)) for n, s, e in d["async"]
                 if _COLLECTIVE.search(n) and min(e, hi) > max(s, lo)]
-        for n, s, e in inside:
-            if _COLLECTIVE.search(n):
-                coll.append((s, e))
+        coll += [(s, e) for n, s, e in inside if _COLLECTIVE.search(n)]
+        for n, s, own in self_times(inside):
             mod = _module_of(d["modules"], s)
             key = f"{mod}:{short_name(n)}" if mod else short_name(n)
-            op_s[key] = op_s.get(key, 0.0) + (e - s)
+            op_s[key] = op_s.get(key, 0.0) + own
         coll_s.append(total(union(coll)))
     busy_s = [total(b) for b in busy]
     fullest = max(range(len(devs)), key=lambda i: busy_s[i])

@@ -1,7 +1,9 @@
 """Minimal MySQL text-protocol client: the benchmark's own copy.
 
 Handshake response 41, COM_INIT_DB, COM_QUERY and text resultsets, over
-a framed socket (3-byte little-endian length + 1-byte sequence). It
+a framed socket (3-byte little-endian length + 1-byte sequence), read
+through one receive buffer: the client shares the server's process, so
+every `recv` it makes is a GIL hand-over inside the measured rate. It
 imports nothing of the program, so a change to the program's packet
 layer or test helper cannot move what the benchmark measures. Copied
 from tests/mysql_client.py and tidb_tpu/server/packet.py (PERF.md lists
@@ -14,6 +16,7 @@ import socket
 import struct
 
 _MAX_PAYLOAD = 0xFFFFFF
+_RECV_BYTES = 1 << 16
 _CLIENT_CONNECT_WITH_DB = 8
 _CLIENT_PROTOCOL_41 = 0x200
 _CLIENT_SECURE_CONNECTION = 0x8000
@@ -53,28 +56,36 @@ class Client:
         self.sock = socket.create_connection((host, port), timeout=10)
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self._seq = 0
+        self._buf = bytearray()          # received, not yet handed out ...
+        self._at = 0                     # ... from this offset on
         self._handshake(db)
         self.sock.settimeout(timeout_s)
 
     # -- framing ------------------------------------------------------------
 
-    def _recv_exact(self, n: int) -> bytes:
-        parts = []
-        while n:
-            chunk = self.sock.recv(n)
+    def _take(self, n: int) -> bytes:
+        """The stream's next `n` bytes, through the one receive buffer:
+        whatever a `recv` brings stays there until a packet is sliced
+        out of it, so a reply of many small packets costs one call."""
+        buf = self._buf
+        while len(buf) - self._at < n:
+            del buf[:self._at]               # drop what was handed out
+            self._at = 0
+            chunk = self.sock.recv(_RECV_BYTES)
             if not chunk:
                 raise ConnectionError("server closed the connection")
-            parts.append(chunk)
-            n -= len(chunk)
-        return b"".join(parts)
+            buf += chunk
+        out = bytes(buf[self._at:self._at + n])
+        self._at += n
+        return out
 
     def _read(self) -> bytes:
         payload = b""
         while True:
-            header = self._recv_exact(4)
+            header = self._take(4)
             length = header[0] | (header[1] << 8) | (header[2] << 16)
             self._seq = (header[3] + 1) & 0xFF
-            payload += self._recv_exact(length)
+            payload += self._take(length)
             if length < _MAX_PAYLOAD:
                 return payload
 

@@ -139,6 +139,17 @@ def _find_xplane(trace_dir: str) -> str | None:
     return None
 
 
+def _top_ops(ranked: list, n: int = 10) -> list:
+    """The first `n` places of the device-op ranking for the result's
+    line; where it is longer, the last place holds the rest together, so
+    the entries still sum to the device's busy time."""
+    if len(ranked) <= n:
+        return [[k, v] for k, v in ranked]
+    rest = ranked[n - 1:]
+    return [[k, v] for k, v in ranked[:n - 1]] + [
+        [f"{len(rest)} other ops", sum(v for _k, v in rest)]]
+
+
 def _sweep(args, ctx: Ctx, sut, loadgen) -> None:
     """Several fixed rates in one process on one set-up: for each, one
     window of --seconds with every open loop at that rate. Prints a row
@@ -332,7 +343,7 @@ def run(args) -> dict:
         result["rehearsal_metrics"] = values
     if args.trace and ctx.trace:
         result["breakdown"] = {
-            "device_ops": [[k, v] for k, v in ctx.trace["device_ops"][:10]],
+            "device_ops": _top_ops(ctx.trace["device_ops"]),
             "idle_gaps": [[k, v] for k, v in ctx.trace["idle_gaps"][:10]]}
     result["setup_phases_s"] = dict(ctx.setup)
     result["window_wall_s"] = ctx.window.wall_s

@@ -42,7 +42,8 @@ def test_nothing_to_read_is_none(before, after):
 
 
 def test_manifest_entry():
-    assert MANIFEST["per_layer"][-1] == {
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    assert by_name["agg_dense_dispatch_pct"] == {
         "name": "agg_dense_dispatch_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "analytic_rows_per_s", "workloads": ["tpch1.q1_warm"]}
