@@ -8,10 +8,8 @@ heartbeats NOT invalidating the columnar caches, the connection gauges,
 the status-port /shed hook returning the hbm-cache ledger to zero, and
 — under a pinched `tidb_tpu_server_mem_quota` — statements queueing or
 shedding with the RETRYABLE 9008, never a mid-query
-ER_MEM_EXCEED_QUOTA. The heavy bench leg (`python bench.py serve`)
-rides behind the `slow` marker."""
+ER_MEM_EXCEED_QUOTA."""
 
-import json
 import threading
 import time
 
@@ -379,27 +377,3 @@ class TestResourceMetering:
         finally:
             status.close()
 
-
-@pytest.mark.slow
-class TestServeBenchHeavy:
-    def test_bench_serve_small_leg(self):
-        """The load harness end to end in a subprocess (the heavy leg):
-        concurrent rows/sec beats the serialized replay and the pinched
-        leg completes with zero OOM cancels."""
-        import os
-        import subprocess
-        import sys
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   BENCH_SERVE_CLIENTS="8", BENCH_SERVE_ROUNDS="1",
-                   BENCH_SERVE_LOOKUPS="4", BENCH_SERVE_SF="0.01")
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r = subprocess.run([sys.executable, "bench.py", "serve"],
-                           cwd=root, env=env, capture_output=True,
-                           text=True, timeout=560)
-        assert r.returncode == 0, r.stderr[-2000:]
-        rep = json.loads(r.stdout.strip().splitlines()[-1])
-        d = rep["detail"]
-        assert rep["value"] > 0
-        assert d["pinched"]["completed"], d["pinched"]
-        assert d["pinched"]["oom_cancels"] == 0
-        assert d["concurrent"]["rows_per_sec"] > 0

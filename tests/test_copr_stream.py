@@ -281,12 +281,12 @@ class TestMeshFeed:
         (executor/mesh.py _stream_groups) with NO intermediate full
         materialization: both streaming layers engage and the result
         matches the host path."""
-        from tidb_tpu import parallel
+        from tidb_tpu import devplane
         from tidb_tpu.executor import mesh as mesh_exec
 
         sql = "SELECT s, COUNT(*), SUM(v) FROM t GROUP BY s ORDER BY s"
         want = _materialized(sess, sql)
-        parallel.enable_mesh(8)
+        devplane.enable_mesh(8)
         old = config.get_var("tidb_tpu_stream_rows")
         config.set_var("tidb_tpu_stream_rows", 256)
         mesh_exec.reset_stream_stats()
@@ -294,7 +294,7 @@ class TestMeshFeed:
             got = q(sess, sql)
         finally:
             config.set_var("tidb_tpu_stream_rows", old)
-            parallel.disable_mesh()
+            devplane.disable_mesh()
         mstats = mesh_exec.stream_stats()
         assert mstats["streams"] >= 1 and mstats["batches"] >= 2, mstats
         cstats = costream.stream_stats()

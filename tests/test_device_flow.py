@@ -1,7 +1,7 @@
 """The device-plane dataflow pass (tidb_tpu/lint/flow/device.py):
 discovery of every traced-program construction site across its four
-forms, dispatch resolution, the static compile-prediction contract the
-`bench.py lintcheck` leg cross-checks against the profiler plane, and
+forms, dispatch resolution, the static compile-prediction contract and
+its cross-check against the profiler plane on a warm TPC-H run, and
 the runtime pin for the audited `donate_argnums` sites in ops/hashagg
 and ops/streamagg (ISSUE 20's donation audit: the donating branch
 returns at the dispatch, the donated transfer skips the chunk memo,
@@ -109,6 +109,57 @@ def test_compile_predictions_cover_every_profiler_family(df):
             assert p["per_row_bound"] == 1
     assert preds["plane"]["sites"] == sum(
         1 for s in df.sites if s.form == "plane_jit")
+
+
+def test_warm_run_stays_inside_the_compile_predictions(df):
+    """The static model against the profiler plane, both directions:
+    every family that compiled on warm TPC-H Q1/Q3 has a prediction
+    (else the device pass fell behind the runtime), no family compiles
+    on a warm iteration and no fingerprinted row compiles past its
+    per-row bound (else the runtime fell behind the contract the lint
+    rules enforce)."""
+    import tpch
+    from tidb_tpu import config, profiler
+    from tidb_tpu.session import Session
+    from tidb_tpu.store.storage import new_mock_storage
+
+    preds = df.compile_predictions()
+    s = Session(new_mock_storage())
+    s.execute("CREATE DATABASE tpch_flow")
+    s.execute("USE tpch_flow")
+    tpch.load(s, tpch.TpchData(seed=5))
+
+    def family_compiles() -> dict:
+        fams: dict = {}
+        for p in profiler.snapshot():
+            fams[p["family"]] = fams.get(p["family"], 0) + p["compiles"]
+        return fams
+
+    profiler.reset_for_tests()
+    try:
+        with config.session_overlay({"tidb_tpu_device": 1,
+                                     "tidb_tpu_device_min_rows": 1}):
+            for sql in (tpch.Q1, tpch.Q3):
+                s.query(sql)            # cold: compile + cache fill
+            cold = family_compiles()
+            for _ in range(2):
+                for sql in (tpch.Q1, tpch.Q3):
+                    s.query(sql)
+        warm = family_compiles()
+        assert warm, "no family compiled anything: nothing was checked"
+        assert set(warm) <= set(preds)
+        for fam, n in warm.items():
+            assert n - cold.get(fam, 0) <= preds[fam]["warm_growth"], \
+                (fam, cold, warm)
+        over = [(p["family"], p["fingerprint"][:16], p["compiles"])
+                for p in profiler.snapshot()
+                if preds[p["family"]]["per_row_bound"] is not None
+                and not p["fingerprint"].startswith("~")
+                and p["compiles"] > preds[p["family"]]["per_row_bound"]]
+        assert not over, over
+    finally:
+        s.close()
+        profiler.reset_for_tests()
 
 
 # -- donation audit (ISSUE 20 satellite): runtime pin -----------------------

@@ -114,6 +114,8 @@ class TestTraceSpans:
             f"plane size {plane}: missing device/scan spans "
             f"{(DEVICE_SPANS | SCAN_EXEC_SPANS) - names}")
         assert names <= set(trace.SPAN_NAMES)
+        # one plane: no fallback class of the mesh's own at any size
+        assert _fallbacks("mesh") == 0
         _assert_same_across_sizes(
             self._spans, plane,
             tuple(sorted(names - TRANSPORT_SPANS - COLD_SCAN_SPANS)))

@@ -9,6 +9,7 @@ dictionary-code stability across delta patches."""
 import numpy as np
 import pytest
 
+import tpch
 from tidb_tpu import config, metrics
 from tidb_tpu.chunk import Chunk, Column, dict_encode
 from tidb_tpu.expression.core import ColumnRef, Constant, Op, func
@@ -397,3 +398,45 @@ class TestDeltaCodeStability:
         finally:
             s.execute("SET tidb_tpu_device = 1")
         assert s.query(q).rows == want
+
+
+# -- the stock TPC-H schema stays encoded ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tpch_sess():
+    s = Session(new_mock_storage())
+    s.execute("CREATE DATABASE tpch_enc")
+    s.execute("USE tpch_enc")
+    tpch.load(s, tpch.TpchData(seed=11))
+    s.execute("SET tidb_tpu_device_min_rows = 1")
+    yield s
+    s.close()
+
+
+class TestStockTpchStaysEncoded:
+    """Q1 (dict group keys + direct-indexed agg) and Q3 (string-filtered
+    join chain: encoded join-key lanes + fragment fusion): the feature
+    pair on against BOTH off — identical answers, no fallback with
+    reason="encoding" (one would mean the vocabulary regressed and warm
+    scans silently re-decode), and both bytes-touched counters move."""
+
+    @pytest.mark.parametrize("q", ["Q1", "Q3"])
+    def test_no_encoding_fallback_and_bytes_counted(self, tpch_sess, q):
+        s, sql = tpch_sess, getattr(tpch, q)
+        s.query(sql)                    # compile + cache fill
+        fb0 = _enc_fallbacks()
+        enc0 = _metric(metrics.BYTES_ENCODED)
+        dec0 = _metric(metrics.BYTES_DECODED_EQUIV)
+        enc_rows = s.query(sql).rows
+        assert _enc_fallbacks() == fb0
+        assert _metric(metrics.BYTES_ENCODED) > enc0
+        assert _metric(metrics.BYTES_DECODED_EQUIV) > dec0
+        s.execute("SET tidb_tpu_encoded_exec = 0")
+        s.execute("SET tidb_tpu_fuse_fragments = 0")
+        try:
+            dec_rows = s.query(sql).rows
+        finally:
+            s.execute("SET tidb_tpu_encoded_exec = 1")
+            s.execute("SET tidb_tpu_fuse_fragments = 1")
+        assert enc_rows == dec_rows

@@ -34,7 +34,7 @@ def fleet():
 
 
 def _client(fleet, index, db=""):
-    c = fleet.client(index=index, db=db)
+    c = MiniClient(fleet.host, fleet.members[index].port, db=db)
     c.sock.settimeout(120)
     return c
 
@@ -140,6 +140,31 @@ class TestClusterObservability:
         assert {m.status_port for m in fleet.members} <= ports
         assert fleet.store_status_port in ports
 
+    def test_cluster_resource_usage_attributes_every_member(self, fleet):
+        """Per-member utilization through the cluster fan-out: every
+        live member has a server-scope row, and the member that served
+        statements shows them attributed."""
+        _query_until(fleet, 0, "SELECT 1")
+        mrows, _ = _query_until(
+            fleet, 1, "SELECT member_id FROM "
+                      "information_schema.cluster_members")
+        deadline = time.monotonic() + CONVERGE_S
+        while True:
+            urows, _ = _query_until(
+                fleet, 1,
+                "SELECT member, statements FROM "
+                "information_schema.cluster_resource_usage "
+                "WHERE scope = 'server'")
+            util = {r[0]: int(r[1]) for r in urows}
+            if set(util) >= {r[0] for r in mrows} and \
+                    any(n > 0 for n in util.values()):
+                break
+            if time.monotonic() > deadline:
+                raise AssertionError(
+                    f"live={sorted(r[0] for r in mrows)} "
+                    f"attributed={util}")
+            time.sleep(0.25)
+
     def test_cross_member_trace_correlation(self, fleet):
         """The ISSUE 17 acceptance bar: a statement TRACEd on member 0
         mints a fleet-unique trace id; one SELECT over
@@ -200,7 +225,7 @@ class TestFleetChaos:
         _query_until(fleet, 0, "SELECT v FROM chaos.t WHERE id = 3",
                      db="chaos")
         # the seeded fault schedule on the victim: retryable-classed
-        # device and RPC faults with small budgets (bench.py chaos
+        # device and RPC faults with small budgets (tests/chaos_harness.py
         # vocabulary), so statements are mid-flight through fault
         # handling when the SIGKILL lands
         _arm_failpoint(fleet, 0, "device/dispatch",

@@ -1,9 +1,9 @@
 """HTAP through the real wire protocol (ISSUE 11): a TPC-C-style
 new-order/payment write mix on live connections while analytic readers
 hammer the same table — the workload the MVCC delta store
-(store/delta.py) exists for. The fast tests pin the wire-level
-consistency contract; the full sweep (`python bench.py htap`, CI:
-scripts/htap_bench.sh) rides behind the `slow` marker."""
+(store/delta.py) exists for. The fast test pins the wire-level
+consistency contract; the write mix under analytic load rides behind
+the `slow` marker."""
 
 import threading
 import time

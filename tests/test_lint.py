@@ -49,9 +49,9 @@ def report():
 
 
 def test_catalog_is_complete():
-    """4 ported + 12 project-specific + 3 whole-program flow rules
+    """4 ported + 11 project-specific + 3 whole-program flow rules
     + 3 device-plane dataflow rules."""
-    assert len(RULE_NAMES) == 22, RULE_NAMES
+    assert len(RULE_NAMES) == 21, RULE_NAMES
     for ported in ("wire-discipline", "hot-path-sync", "metric-names",
                    "memtrack-alloc"):
         assert ported in RULE_NAMES
@@ -59,7 +59,7 @@ def test_catalog_is_complete():
                 "errcode-discipline", "device-sync", "dtype-discipline",
                 "bare-except", "device-cache", "decode-discipline",
                 "failpoint-discipline", "trace-names",
-                "no-parallel-import", "metric-cardinality"):
+                "metric-cardinality"):
         assert new in RULE_NAMES
     for flow in ("lock-order", "guarded-by", "paired-resource"):
         assert flow in RULE_NAMES

@@ -292,9 +292,9 @@ class TestIsolation:
     def test_mesh_path_is_tracked(self, store):
         """The mesh-routed aggregation path must bill the trackers too —
         quota and the mem column cannot have a blind spot on the mesh."""
-        from tidb_tpu import parallel
+        from tidb_tpu import devplane
         s = Session(store, db="d")
-        parallel.enable_mesh(8)
+        devplane.enable_mesh(8)
         try:
             rs = s.query(
                 "EXPLAIN ANALYZE SELECT a, SUM(v) FROM t GROUP BY a")
@@ -304,7 +304,7 @@ class TestIsolation:
                 assert _parse_mem(mesh_rows[0][mem_i]) > 0, mesh_rows
             assert s.mem_tracker.total() == 0
         finally:
-            parallel.disable_mesh()
+            devplane.disable_mesh()
             s.close()
 
     def test_processlist_mem_column(self, sess):

@@ -10,7 +10,7 @@ shape (EXPLAIN) and result equality with the mesh disabled.
 import pytest
 
 import tpch
-from tidb_tpu import parallel
+from tidb_tpu import devplane
 from tidb_tpu.executor import mesh as mesh_exec
 from tidb_tpu.session import Session
 from tidb_tpu.store.storage import new_mock_storage
@@ -31,9 +31,9 @@ def sess():
 
 @pytest.fixture
 def mesh():
-    parallel.enable_mesh(8)
-    yield parallel.active_mesh()
-    parallel.disable_mesh()
+    devplane.enable_mesh(8)
+    yield devplane.active_mesh()
+    devplane.disable_mesh()
 
 
 def _explain(sess, sql):
@@ -55,7 +55,7 @@ class TestRouting:
         assert "dims:[" in e5
 
     def test_no_mesh_no_routing(self, sess):
-        assert parallel.active_mesh() is None
+        assert devplane.active_mesh() is None
         assert "MeshAgg" not in _explain(sess, tpch.Q1)
         assert "MeshLookupAgg" not in _explain(sess, tpch.Q3)
 
@@ -65,12 +65,12 @@ class TestRouting:
         columnar caches — the copTask path serves them fused from the
         HBM device cache (store/device_cache.py), measured 1.2-2.6x
         faster warm on Q1/Q3/Q5 (plan/mesh_route.route_mesh)."""
-        parallel.enable_mesh(1)
+        devplane.enable_mesh(1)
         try:
             assert "MeshAgg" not in _explain(sess, tpch.Q1)
             assert "MeshLookupAgg" not in _explain(sess, tpch.Q3)
         finally:
-            parallel.disable_mesh()
+            devplane.disable_mesh()
 
 
 class TestResults:
@@ -78,11 +78,11 @@ class TestResults:
     def test_matches_host(self, sess, mesh, q):
         sql = getattr(tpch, q)
         got = sess.query(sql).rows
-        parallel.disable_mesh()
+        devplane.disable_mesh()
         try:
             want = sess.query(sql).rows
         finally:
-            parallel.enable_mesh(8)
+            devplane.enable_mesh(8)
         assert want, "vacuous comparison: host result is empty"
         assert len(got) == len(want)
         for g, w in zip(got, want):
@@ -126,7 +126,7 @@ class TestShuffleJoinSQL:
         chain; with a mesh active HashJoinExec repartitions both sides
         via the all_to_all shuffle kernel instead."""
         from tidb_tpu import executor as ex
-        from tidb_tpu.parallel import shuffle_join as sj
+        from tidb_tpu.ops import meshshuffle as sj
 
         sql = ("SELECT o_custkey, COUNT(*) FROM orders, lineitem "
                "WHERE o_custkey = l_suppkey GROUP BY o_custkey "
@@ -134,11 +134,11 @@ class TestShuffleJoinSQL:
         e = _explain(sess, sql)
         assert "MeshLookupAgg" not in e and "HashJoin" in e
 
-        parallel.disable_mesh()
+        devplane.disable_mesh()
         try:
             want = sess.query(sql).rows
         finally:
-            parallel.enable_mesh(8)
+            devplane.enable_mesh(8)
         assert want
 
         monkeypatch.setattr(ex.HashJoinExec, "_DEVICE_MIN_BUILD", 64)
@@ -160,7 +160,7 @@ class TestShuffleJoinSQL:
         the build side qualifies (advisor r2): the join falls through to
         the per-chunk single-chip paths."""
         from tidb_tpu import executor as ex
-        from tidb_tpu.parallel import shuffle_join as sj
+        from tidb_tpu.ops import meshshuffle as sj
 
         # n_regionkey is NOT unique-keyed, so this cannot become a
         # MeshLookupAgg chain — it must stay a HashJoin
@@ -179,11 +179,11 @@ class TestShuffleJoinSQL:
             return orig(self, *a, **kw)
 
         monkeypatch.setattr(sj.MeshShuffleJoinKernel, "__call__", spy)
-        parallel.disable_mesh()
+        devplane.disable_mesh()
         try:
             want = sess.query(sql).rows
         finally:
-            parallel.enable_mesh(8)
+            devplane.enable_mesh(8)
         got = sess.query(sql).rows
         assert got == want and want
         assert not used, "small probe still paid the mesh shuffle"

@@ -15,7 +15,7 @@ bound is host-side super-batches sized for a TPU dispatch.
 import pytest
 
 import tpch
-from tidb_tpu import config, parallel
+from tidb_tpu import config, devplane
 from tidb_tpu.executor import mesh as mesh_exec
 from tidb_tpu.session import Session
 from tidb_tpu.store.storage import new_mock_storage
@@ -36,9 +36,9 @@ def sess():
 
 @pytest.fixture
 def mesh():
-    parallel.enable_mesh(8)
-    yield parallel.active_mesh()
-    parallel.disable_mesh()
+    devplane.enable_mesh(8)
+    yield devplane.active_mesh()
+    devplane.disable_mesh()
 
 
 @pytest.fixture
@@ -51,11 +51,11 @@ def small_stream():
 
 
 def _host_rows(sess, sql):
-    parallel.disable_mesh()
+    devplane.disable_mesh()
     try:
         return sess.query(sql).rows
     finally:
-        parallel.enable_mesh(8)
+        devplane.enable_mesh(8)
 
 
 def _check(got, want):

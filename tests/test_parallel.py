@@ -11,11 +11,12 @@ import pytest
 import jax
 
 from tidb_tpu.chunk import Chunk, Column
+from tidb_tpu.devplane import build_mesh
 from tidb_tpu.expression import AggDesc, AggFunc
 from tidb_tpu.expression.core import Op, col, const, func
 from tidb_tpu.ops.hashagg import HashAggregator
 from tidb_tpu.ops.hostagg import host_hash_agg
-from tidb_tpu.parallel import MeshAggKernel, build_mesh
+from tidb_tpu.ops.meshagg import MeshAggKernel
 from tidb_tpu.sqltypes import new_double_field, new_int_field, new_string_field
 
 

@@ -9,13 +9,12 @@ import numpy as np
 import pytest
 
 from tidb_tpu.chunk import Chunk, Column
+from tidb_tpu.devplane import build_mesh
 from tidb_tpu.expression import AggDesc, AggFunc
 from tidb_tpu.expression.core import Op, col, const, func
 from tidb_tpu.ops.hashagg import HashAggregator
-from tidb_tpu.parallel import build_mesh
-from tidb_tpu.parallel.dist_join import (BuildError, LookupSpec,
-                                         MeshLookupAggKernel,
-                                         host_lookup_agg)
+from tidb_tpu.ops.meshjoin import (BuildError, LookupSpec,
+                                   MeshLookupAggKernel, host_lookup_agg)
 from tidb_tpu.sqltypes import (new_double_field, new_int_field,
                                new_string_field)
 

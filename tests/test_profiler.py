@@ -273,6 +273,37 @@ class TestEndToEnd:
         # a warm second run must not recompile
         assert compiles <= 1
 
+    def test_warm_sweep_compiles_flat_and_every_row_has_a_roofline(
+            self, sess):
+        """Warm Q1/Q3/Q5 under the profiler: compile counts stay flat
+        across warm iterations, every kernel_profile row that moved
+        bytes carries a roofline_fraction, and every statement_profile
+        memo row carries the mode that ran."""
+        profiler.reset_for_tests()
+        perfschema.memo_reset()
+
+        def total_compiles() -> int:
+            return sum(p["compiles"] for p in profiler.snapshot())
+
+        with config.session_overlay({"tidb_tpu_device": 1}):
+            for sql in (tpch.Q1, tpch.Q3, tpch.Q5):
+                sess.query(sql)         # cold: compile + cache fill
+            track = []
+            for _ in range(2):
+                for sql in (tpch.Q1, tpch.Q3, tpch.Q5):
+                    sess.query(sql)
+                track.append(total_compiles())
+        assert track[-1] == track[0], track
+        rows = sess.query(
+            "SELECT family, dispatches, bytes_in, roofline_fraction "
+            "FROM information_schema.kernel_profile").rows
+        assert any(r[1] for r in rows), rows
+        assert not [r[0] for r in rows
+                    if r[1] and r[2] and r[3] is None], rows
+        memo = sess.query("SELECT digest, op, mode FROM "
+                          "information_schema.statement_profile").rows
+        assert memo and all(m[2] for m in memo), memo
+
     def test_mode_memo_after_cardinality_sweep(self, sess):
         perfschema.memo_reset()
         with config.session_overlay({"tidb_tpu_device": 1}):

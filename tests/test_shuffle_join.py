@@ -10,9 +10,9 @@ over mocktikv, here against the 8-device virtual mesh.
 import numpy as np
 import pytest
 
+from tidb_tpu.devplane import build_mesh
 from tidb_tpu.ops.join import JoinKeyEncoder
-from tidb_tpu.parallel import build_mesh
-from tidb_tpu.parallel.shuffle_join import MeshShuffleJoinKernel
+from tidb_tpu.ops.meshshuffle import MeshShuffleJoinKernel
 
 
 @pytest.fixture(scope="module")

@@ -8,6 +8,7 @@ overhead pin."""
 
 import json
 import logging
+import sys
 import time
 import urllib.error
 
@@ -74,6 +75,19 @@ class TestSampling:
         # statements 3 and 6 of the window retain, deterministically
         assert len(recs) == 2, recs
         assert all(r["reason"] == "sampled" for r in recs)
+
+    def test_every_retained_tree_of_a_mix_is_balanced(self, sess):
+        """Aggregates and point reads, every statement retained: no
+        tree in the ring holds a begin without an end."""
+        config.set_var("tidb_tpu_trace_sample", 1)
+        for i in range(3):
+            sess.query("SELECT v, COUNT(*) FROM t GROUP BY v")
+            for j in range(4):
+                sess.query(f"SELECT v FROM t WHERE id = {i * 7 + j}")
+        records = trace.ring_records()
+        assert len(records) == 15
+        assert [(r["trace_id"], p) for r in records
+                for p in trace.validate(r["root"])] == []
 
     def test_sampling_off_retains_nothing(self, sess):
         for _ in range(5):
@@ -405,32 +419,53 @@ class TestChromeExport:
 # -- overhead ----------------------------------------------------------------
 
 
+def _calls_per_iteration(body, n=300):
+    """Python and C calls `body()` makes per iteration on this thread,
+    under `sys.setprofile` (per-thread: other workers and the machine's
+    load cannot move the count; `body` itself is not counted)."""
+    calls = [0]
+
+    def profiler(_frame, event, _arg):
+        if event in ("call", "c_call"):
+            calls[0] += 1
+
+    for _ in range(20):             # past first-use imports and caches
+        body()
+    sys.setprofile(profiler)
+    try:
+        for _ in range(n):
+            body()
+    finally:
+        sys.setprofile(None)
+    # less the profiler's own removal and `body`'s frame each round
+    return (calls[0] - 1 - n) / n
+
+
 class TestOverhead:
     def test_disarmed_per_statement_overhead_is_tiny(self):
         """Sampling disarmed (the N-1 of N statements): what the
         tracing subsystem adds per statement beyond the phase-skeleton
         spans perfschema always needed is the root lifecycle — begin
         (sampling decision) + end + finish_statement (retention
-        check). Budget <5us per untraced statement (measured ~3us on
-        the CI container)."""
-        n = 20_000
-        t0 = time.perf_counter()
-        for _ in range(n):
+        check). Counted in calls, not seconds: the tree makes 18 per
+        untraced statement, so one more retention check or sysvar read
+        fails the budget of 19."""
+        def statement():
             root = trace.begin("statement")
             trace.end(root)
             trace.finish_statement(root, "SELECT 1")
-        per_stmt = (time.perf_counter() - t0) / n
+
+        per_stmt = _calls_per_iteration(statement)
         assert trace.ring_snapshot() == []     # truly disarmed
-        assert per_stmt < 5e-6, f"{per_stmt * 1e6:.2f}us per statement"
+        assert per_stmt <= 19, f"{per_stmt} calls per statement"
 
     def test_span_skeleton_stays_cheap(self):
         """Regression guard on span() itself (it runs per dispatch and
-        per phase): the full 2-phase-span statement skeleton stays
-        under a loose 15us — the slotted context manager must never
-        regress back to generator-based @contextmanager cost."""
-        n = 10_000
-        t0 = time.perf_counter()
-        for _ in range(n):
+        per phase): the full 2-phase-span statement skeleton makes 44
+        calls, 13 a span — the slotted context manager must never
+        regress back to a generator-based @contextmanager (six or more
+        calls a span on top)."""
+        def statement():
             root = trace.begin("statement")
             with trace.span("plan"):
                 pass
@@ -438,5 +473,6 @@ class TestOverhead:
                 pass
             trace.end(root)
             trace.finish_statement(root, "SELECT 1")
-        per_stmt = (time.perf_counter() - t0) / n
-        assert per_stmt < 15e-6, f"{per_stmt * 1e6:.2f}us per statement"
+
+        per_stmt = _calls_per_iteration(statement)
+        assert per_stmt <= 46, f"{per_stmt} calls per statement"

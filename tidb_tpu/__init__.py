@@ -20,7 +20,7 @@ Layer map (cf. SURVEY.md §1):
     executor/   volcano-over-chunks executors                (ref: executor/)
     expression/ expr trees, numpy + jax evaluation           (ref: expression/)
     ops/        TPU kernels: filter/agg/join/sort            (ref: executor/ hot ops)
-    parallel/   device mesh, sharded kernels                 (new, TPU-native)
+    devplane    device mesh + layout; ops/mesh* sharded kernels (new, TPU-native)
     kv/         engine-neutral txn KV contract               (ref: kv/)
     store/      distributed client: regions, 2PC, cop fanout (ref: store/tikv/)
     mockstore/  in-process MVCC cluster + coprocessor        (ref: store/tikv/mocktikv/)
@@ -47,7 +47,7 @@ _jax.config.update("jax_enable_x64", True)
 # cost tens of seconds of XLA compile; this turns them into disk hits.
 # util/compile_cache owns the wiring (JAX_COMPILATION_CACHE_DIR where
 # set, else <checkout>/.jax_cache) and counts hits/misses for
-# chip_smoke.py / bench.py / the server log.
+# chip_smoke.py / GET /profile / the server log.
 from tidb_tpu.util import compile_cache as _compile_cache
 
 _compile_cache.enable()

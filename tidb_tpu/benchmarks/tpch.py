@@ -1,4 +1,4 @@
-"""Scaled TPC-H generator + offline loader for the end-to-end benchmark.
+"""Scaled TPC-H generator + offline loader for chip_smoke.py and the tests.
 
 Reference: BASELINE.md configs 2-4 (TPC-H Q1/Q3/Q5 through the server) and
 /root/reference/cmd/benchdb (the SQL workload driver role). Row counts
@@ -210,11 +210,4 @@ GROUP BY n_name
 ORDER BY revenue DESC
 """
 
-# per-query input-row accounting (tables each query scans)
-QUERY_TABLES = {
-    "q1": ["lineitem"],
-    "q3": ["lineitem", "orders", "customer"],
-    "q5": ["lineitem", "orders", "customer", "supplier", "nation",
-           "region"],
-}
 QUERIES = {"q1": Q1, "q3": Q3, "q5": Q5}

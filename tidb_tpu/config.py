@@ -55,7 +55,8 @@ _BOOL, _INT, _STR = "bool", "int", "str"
 # the rare _STR vars (failpoint arming) store their string verbatim.
 _DEFS: dict[str, tuple[str, int]] = {
     # master switch for single-chip device kernels; 0 = pure host numpy
-    # execution everywhere (the measured CPU baseline mode of bench.py)
+    # execution everywhere (the plain reference every device result is
+    # compared with)
     "tidb_tpu_device": (_BOOL, 1),
     # columnar region-chunk cache on the storage side (store/chunk_cache)
     "tidb_tpu_chunk_cache": (_BOOL, 1),
@@ -284,7 +285,7 @@ _DEFS: dict[str, tuple[str, int]] = {
     # a registered shed action) every this-many milliseconds, and rolls
     # the resource meter's per-tenant interval baselines. Served on
     # GET /metrics/history. 0 = sampler idle (manual sample_now() — the
-    # bench/test door — still records).
+    # tests' door — still records).
     "tidb_tpu_metrics_history_interval_ms": (_INT, 1000),
     # metrics-history ring capacity in points (one point per sampler
     # tick); the oldest points evict past it

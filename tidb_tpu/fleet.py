@@ -10,9 +10,9 @@ horizontally over one shared MVCC store): this module spawns
   * N SQL-server processes (`python -m tidb_tpu --store HOST:PORT`),
     each a full wire server with its own coherent chunk/HBM caches,
 
-health-checks members over their status ports, hands out round-robin
-client connections, and supports killing/restarting a member — the
-chaos surface the fleet tests and `bench.py fleet` drive. Every fleet
+health-checks members over their status ports (a client connects to
+`fleet.host`, `fleet.members[i].port`), and supports killing/restarting
+a member — the chaos surface tests/test_fleet_process.py drives. Every fleet
 fault degrades to a slower correct mode: killing a SQL server yields
 retryable errors on ITS clients only (errcode.ER_STORE_UNAVAILABLE
 class), survivors keep serving, and the DDL owner lease fails over
@@ -95,8 +95,8 @@ class Fleet:
     Usage::
 
         with Fleet(n_sql=4) as f:
-            c = f.client()          # round-robin MiniClient
-            c.query("SELECT 1")
+            f.wait_healthy()
+            port = f.members[0].port    # MySQL wire, on f.host
             f.kill(0)               # SIGKILL one SQL server
             f.restart(0)
     """
@@ -112,7 +112,6 @@ class Fleet:
         self.store_port: int | None = None
         self.store_status_port: int | None = None
         self.members: list[SQLMember] = []
-        self._rr = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -210,20 +209,3 @@ class Fleet:
                         raise TimeoutError(
                             f"member {i} not healthy in {timeout}s")
                     time.sleep(0.1)
-
-    def client(self, index: int | None = None, db: str = "",
-               **kw):
-        """MiniClient to one member — round-robin over live members
-        when `index` is None."""
-        if index is None:
-            live = [m for m in self.members if m.alive()]
-            if not live:
-                raise RuntimeError("no live SQL members")
-            m = live[self._rr % len(live)]
-            self._rr += 1
-        else:
-            m = self.members[index]
-        if _REPO_ROOT not in sys.path:
-            sys.path.insert(0, _REPO_ROOT)
-        from tests.mysql_client import MiniClient
-        return MiniClient(self.host, m.port, db=db, **kw)

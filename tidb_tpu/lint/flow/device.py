@@ -34,8 +34,8 @@ statically, BEFORE dispatch:
   coercions inside traced bodies are findings;
 * **compile prediction** — a static per-kernel-family compile-count
   model (every construction site sits behind a cache/memo, so warm
-  runs compile nothing) that `bench.py lintcheck` cross-checks against
-  `information_schema.kernel_profile`'s observed counters — static
+  runs compile nothing) that tests/test_device_flow.py cross-checks
+  against the profiler registry's observed counters — static
   analysis the profiler plane can falsify, and vice versa.
 
 Zero extra parses: the pass walks the shared forest and reuses the
@@ -496,7 +496,8 @@ class DeviceFlow:
     # -- compile prediction --------------------------------------------------
 
     def compile_predictions(self) -> dict:
-        """Static per-family compile model for `bench.py lintcheck`:
+        """Static per-family compile model (tests/test_device_flow.py
+        holds a warm run to it):
         every construction site sits behind a fingerprint cache or a
         bounded program memo, so (a) warm re-runs compile nothing and
         (b) fingerprint-cached families construct at most once per
@@ -542,8 +543,8 @@ class DeviceFlow:
 
 def device_flow_of(forest) -> DeviceFlow:
     """The forest's device-plane analysis, computed once and memoized
-    on the forest instance (all three device rules and the bench
-    cross-check share the same facts)."""
+    on the forest instance (all three device rules and the compile
+    predictions share the same facts)."""
     df = getattr(forest, "_device_flow", None)
     if df is None:
         df = DeviceFlow(forest)

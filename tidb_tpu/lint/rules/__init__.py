@@ -1,6 +1,6 @@
 """Rule catalog: importing this package registers every rule, in the
 order CI reports them. Four ported from the original standalone test
-walkers, ten project-specific additions, three whole-program flow
+walkers, nine project-specific additions, three whole-program flow
 rules built on tidb_tpu/lint/flow (call graph + lock registry over
 the same shared parse), and three device-plane dataflow rules built
 on tidb_tpu/lint/flow/device (traced-program discovery over that
@@ -19,7 +19,6 @@ from tidb_tpu.lint.rules import (  # noqa: F401  (import == register)
     devcache,    # device-cache
     decode,      # decode-discipline (encoded execution stays encoded)
     failpoints,  # failpoint-discipline (fault-injection registry)
-    planeimports,  # no-parallel-import (unified device plane only)
     tracenames,  # trace-names       (statement-trace span vocabulary)
     lockorder,   # lock-order        (flow: acquisition-order cycles)
     guardedby,   # guarded-by        (flow: annotated shared state)

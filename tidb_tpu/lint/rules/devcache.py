@@ -23,8 +23,8 @@ class DeviceCacheRule(Rule):
     `upload_block`. A stray device_put in a handler or executor creates
     untracked, unbudgeted HBM residency that the eviction/OOM machinery
     can neither see nor reclaim — the exact failure mode the old
-    per-chunk transfer memos had. Kernel-internal transfers (ops/,
-    parallel/) are out of scope: they are transient dispatch staging,
+    per-chunk transfer memos had. Kernel-internal transfers (ops/)
+    are out of scope: they are transient dispatch staging,
     billed per-dispatch via dispatch_nbytes.
     """
 

@@ -56,7 +56,7 @@ class TraceNamesRule(Rule):
     declared name is opened by at least one in-tree site.
 
     The registry is the operator-facing span vocabulary (the docs, the
-    Chrome export lanes and the bench latency attribution all read
+    Chrome export lanes and the span counters all read
     these names): a span opened under an undeclared name is a timeline
     lane no attribution bucket or doc explains, and a declared name no
     site opens is catalog fiction.
@@ -105,8 +105,8 @@ class TraceNamesRule(Rule):
                         pf.rel, call.lineno, self.name,
                         f"trace.{kind}({arg.value!r}) opens a span not "
                         f"declared in trace.SPAN_NAMES — declare it "
-                        f"(one vocabulary: docs, Chrome export, bench "
-                        f"attribution)")
+                        f"(one vocabulary: docs, Chrome export, span "
+                        f"counters)")
                     continue
                 used.add(arg.value)
         for name, lineno in sorted(declared.items()):

@@ -300,7 +300,7 @@ class MemTracker:
         release-on-close is what leaves the session root at zero after
         each statement even when an abandoned generator never ran its
         finally. Peaks (and residual current counters) survive for
-        post-mortem readers (bench, slow log)."""
+        post-mortem readers (EXPLAIN ANALYZE, slow log)."""
         with self._mu:
             p = self.parent
             if p is None:

@@ -16,8 +16,8 @@ per-node lock at a time, never nested), so the SERVER node is the total
 and each tenant level is a consistent slice of it. Work metered on a
 thread with NO meter installed (internal bookkeeping sessions, library
 use) charges the SERVER node alone — the gap between the SERVER total
-and the per-session sum is the *attribution coverage* BENCH audits
-(`utilization.attribution_coverage`, pinned to [0.9, 1.1]).
+and the per-session sum is the *attribution coverage* `GET /top`
+reports (`attributed_device_ns` beside `server.device_ns`).
 
 Instrumentation sites are the chokepoints every device dispatch already
 passes through: `sched.device_slot` (sync kernel sites: copr aggs,
@@ -45,8 +45,8 @@ enforces that split).
 
 Surfaces: `information_schema.resource_usage`, SHOW PROCESSLIST's
 DeviceTime/RowsSent columns, `GET /top`, the history sampler's derived
-`tidb_tpu_device_utilization_ratio` gauge (tidb_tpu/metrics_history.py)
-and BENCH's `utilization` blocks. See docs/OBSERVABILITY.md.
+`tidb_tpu_device_utilization_ratio` gauge (tidb_tpu/metrics_history.py).
+See docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations
@@ -159,7 +159,7 @@ class Meter:
 
 
 # process root: the total of all metered work, attributed or not —
-# the denominator of BENCH's attribution_coverage
+# the denominator of the attribution coverage
 SERVER = Meter("server")
 
 _reg_mu = threading.Lock()
@@ -415,7 +415,7 @@ def top_digests(n: int = 10) -> list[dict]:
 
 
 def attributed_device_ns() -> int:
-    """Sum of per-session device busy-time — BENCH's coverage numerator
+    """Sum of per-session device busy-time — the coverage numerator
     (the SERVER node's device_ns is the denominator)."""
     with _reg_mu:
         nodes = list(_sessions.values())

@@ -292,9 +292,9 @@ FLEET_RPC_SECONDS = "tidb_tpu_fleet_remote_rpc_seconds"
 FLEET_LOCAL_COP = "tidb_tpu_fleet_local_cop_total"
 # encoded execution (ops/encoded.py): input bytes device dispatches
 # actually staged/read (dict codes + validity at the padded bucket) vs
-# the decoded-equivalent footprint of the same inputs — BENCH's
-# per-query bytes_touched column diffs these to audit the compression
-# win (ROADMAP item 4)
+# the decoded-equivalent footprint of the same inputs — their ratio is
+# the compression win (tests/test_encoded_exec.py diffs them; meter.py
+# bills the same bytes per tenant)
 BYTES_ENCODED = "tidb_tpu_device_bytes_encoded_total"
 BYTES_DECODED_EQUIV = "tidb_tpu_device_bytes_decoded_equiv_total"
 # fault injection + device-plane recovery (util/failpoint.py, sched.py,
@@ -329,11 +329,11 @@ DEVICE_UTILIZATION = "tidb_tpu_device_utilization_ratio"
 HBM_OCCUPANCY = "tidb_tpu_hbm_occupancy_ratio"
 # per-chip slot busy-time over the sampler interval, labeled {chip}
 # (bounded by the plane's device count): the scheduler's placement
-# signal surfaced as a series, and the serve bench's balance figure
+# signal surfaced as a series (GET /metrics/history)
 CHIP_UTILIZATION = "tidb_tpu_chip_utilization_ratio"
 # kernel profiling plane (tidb_tpu/profiler.py + util/compile_cache.py):
-# persistent XLA compile-cache hit/miss counts promoted from BENCH-json-
-# only to first-class families, per-family kernel first-call compile
+# persistent XLA compile-cache hit/miss counts as first-class
+# families, per-family kernel first-call compile
 # wall time (trace+compile+load, attributed hit|miss|cached by diffing
 # the persistent-cache counters around it), and per-family dispatch
 # counts. Labeled {family} only (hashagg|scalaragg|streamagg|fragment|

@@ -5,8 +5,8 @@ are last-write-wins, and the /metrics endpoint assumes an EXTERNAL
 scraper keeps the history. Nothing in-process could answer "how busy
 was the device over the last minute" or "is the HBM hit rate decaying",
 which is exactly what the adaptive-runtime items (ROADMAP 2 and 3, per
-the hash-vs-sort study arxiv 2411.13245) and the serve bench's
-utilization audit need. This module keeps that history in-process:
+the hash-vs-sort study arxiv 2411.13245) need. This module keeps that
+history in-process:
 
 * a background sampler — supervised per util/supervisor.py, so a
   crashing beat restarts counted instead of dying silently — snapshots
@@ -27,7 +27,7 @@ utilization audit need. This module keeps that history in-process:
   action — admission shedding and GET /shed reclaim retained points
   like any other server-scope residency (trace-ring discipline).
 
-`sample_now()` is the deterministic door: tests and bench call it to
+`sample_now()` is the deterministic door: tests call it to
 record a point (and roll the meter intervals) without waiting out the
 cadence. Served as JSON on `GET /metrics/history` (server/status.py).
 """
@@ -207,7 +207,7 @@ def _beat() -> None:
 
 def ensure_started() -> None:
     """Start the supervised sampler thread once per process (idempotent;
-    Server.start / StatusServer.start / the bench legs call it)."""
+    Server.start / StatusServer.start call it)."""
     global _started, _stop
     with _state_mu:
         if _started:

@@ -1,7 +1,7 @@
 """Encoded execution: operate on dictionary codes end-to-end.
 
-BENCH r05 measured roofline_fraction geomean 0.229 with most device time
-spent moving bytes the query never needed: varlen columns decoded into
+Before this module most device time went to moving bytes the query
+never needed: varlen columns decoded into
 wide host vectors at the device-cache boundary, string predicates
 evaluated over object arrays on the host (which also rewrote the chunk
 and disqualified it from the fused HBM-cache dispatch), and every join

@@ -48,10 +48,10 @@ def _lex_key(d: np.ndarray, v: np.ndarray):
 def _host_agg_vectorized(chunk: Chunk, mask, group_exprs, aggs
                          ) -> GroupResult:
     """Sort-based group-by, fully vectorized (np.lexsort + ufunc.reduceat):
-    the numpy mirror of the device segment-reduce kernel, and the measured
-    CPU baseline of bench.py — kept honest by being a real columnar
-    engine, not a per-row interpreter (the reference's chunk executor is
-    compiled Go; a Python row loop would flatter the device numbers)."""
+    the numpy mirror of the device segment-reduce kernel, and the plain
+    reference (`tidb_tpu_device=0`) every device result is compared
+    with — a real columnar engine, not a per-row interpreter (the
+    reference's chunk executor is compiled Go)."""
     live = np.flatnonzero(mask)
     nlive = len(live)
     gcols = [(d, v) for d, v in _eval_cols(group_exprs, chunk)]

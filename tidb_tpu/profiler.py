@@ -1,10 +1,8 @@
 """Kernel profiling plane: continuous compile/dispatch/roofline accounting.
 
-ROADMAP item 5's roofline target was unverifiable from inside the server
-— achieved-GB/s math and compile-cache attribution lived only in
-bench.py — and item 3's self-tuning execution needs a per-digest record
-of which mode ran and what it cost (perfschema.memo_record is the write
-side; this module is the per-kernel substrate).
+Achieved-GB/s math and compile-cache attribution from inside the
+server, and the per-kernel substrate of the per-digest record of which
+mode ran and what it cost (perfschema.memo_record is the write side).
 
 One `KernelProfileRegistry` keyed ``(family, plan fingerprint, mesh
 fingerprint)`` — the exact key discipline of the executable caches it
@@ -26,8 +24,8 @@ Feeds:
     persistent cache) or `cached` (served from jax's in-process
     executable cache — no persistent-cache event at all).
 
-Roofline: the platform-peak table and achieved-GB/s math hoisted out of
-bench.py so `roofline_fraction` is computed ONLINE per kernel family and
+Roofline: the platform-peak table and achieved-GB/s math live here, so
+`roofline_fraction` is computed ONLINE per kernel family and
 per statement (bytes / busy-ns against `platform_peak_gbps()`), surfaced
 in EXPLAIN ANALYZE's `kernel` column, the slow log,
 `information_schema.kernel_profile` / `cluster_kernel_profile` and
@@ -58,7 +56,7 @@ __all__ = ["KernelProfile", "KernelProfileRegistry", "enabled",
            "reset_for_tests"]
 
 # the closed family vocabulary (also the {family} metric label set and
-# the plane-size-invariance contract bench.py profile pins): every
+# the plane-size-invariance contract tests/test_profiler.py pins): every
 # executable-cache construction site declares exactly one of these
 FAMILIES = ("hashagg", "scalaragg", "streamagg", "fragment", "mesh",
             "plane", "join", "sort")
@@ -465,8 +463,7 @@ class dispatch_section:
         return False
 
 
-# -- roofline (hoisted from bench.py — ONE estimator for bench and the
-# continuous in-server numbers) ---------------------------------------------
+# -- roofline (ONE estimator for every in-server surface) -------------------
 
 # HBM peak per chip, keyed by jax's device_kind (public datasheet
 # figures, GB/s). An accelerator that is not in the table is an error,

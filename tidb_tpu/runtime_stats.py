@@ -259,8 +259,9 @@ class StatsCollector:
 
     def seal(self) -> None:
         """Drop the plan-object references once the statement is done:
-        the collector outlives the statement on the session (bench reads
-        it), and it must not pin the executed plan tree. ops() keeps
+        the collector outlives the statement on the session (the slow
+        log and tests/test_runtime_stats.py read it), and it must not
+        pin the executed plan tree. ops() keeps
         answering from the sealed snapshot."""
         ops = self.ops()
         with self._lock:
@@ -331,8 +332,8 @@ def note_bytes_touched(decoded_equiv: int, encoded: int) -> None:
     bytes-touched counter families: `encoded` is what the dispatch
     actually staged/read (dict codes + validity at the padded bucket),
     `decoded_equiv` is what the same input would occupy decoded into
-    wide host vectors — the auditable compression win BENCH reports as
-    the per-query bytes_touched column. Also the per-tenant bytes
+    wide host vectors — their ratio is the compression win. Also the
+    per-tenant bytes
     ledger's single chokepoint (meter.py)."""
     from tidb_tpu import meter, metrics
     metrics.counter(metrics.BYTES_DECODED_EQUIV, inc=decoded_equiv)

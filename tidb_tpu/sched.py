@@ -129,8 +129,8 @@ class DeviceScheduler:
     hour ago competes as an equal once the work drains instead of
     being penalized by its cumulative ledger forever). Releases
     attribute the slot's hold interval to both the cumulative busy
-    ledger (the metrics-history sampler and serve bench derive
-    utilization from its deltas — those must stay monotone) and the
+    ledger (the metrics-history sampler derives utilization from its
+    deltas — those must stay monotone) and the
     decayed one (the placement signal). On a 1-device plane every
     counter collapses to chip 0 and behavior is exactly the
     single-device scheduler."""
@@ -276,7 +276,7 @@ class DeviceScheduler:
             self._chip_granted[slot.chip] = max(held - 1, 0)
             # the hold interval (dispatch through finalize) IS the
             # chip's attributed busy time — cumulative for the sampler
-            # and serve bench (monotone deltas), decayed for placement
+            # (monotone deltas), decayed for placement
             held_ns = max(now - slot.t_grant, 0)
             self._chip_busy_ns[slot.chip] = \
                 self._chip_busy_ns.get(slot.chip, 0) + held_ns
@@ -370,8 +370,7 @@ class DeviceScheduler:
     def chip_busy_ns(self) -> dict:
         """{chip: cumulative attributed busy ns} — the metrics-history
         sampler derives per-chip utilization ratios from deltas of
-        this, and the serve bench reads it for the mesh-balance
-        aggregate (total rows over the busiest chip's time)."""
+        this."""
         with self._cv:
             out = {c: self._chip_busy_ns.get(c, 0)
                    for c in range(devplane.ndev())}
@@ -879,7 +878,7 @@ def shed_server(target: int = 0) -> int:
 
 
 def stats() -> dict:
-    """Scheduler + admission snapshot (status port, bench serve block)."""
+    """Scheduler + admission snapshot (GET /status `serving` block)."""
     return {"scheduler": _SCHEDULER.snapshot(),
             "admission": _ADMISSION.snapshot(),
             "watchdog": _WATCHDOG.snapshot(),

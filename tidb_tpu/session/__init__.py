@@ -476,7 +476,7 @@ class Session:
         self._stmt_start = time.perf_counter()
         self.killed = False   # a kill that landed while idle is a no-op
         self._last_plan = None    # executed physical plan (EXPLAIN
-        self._last_stats = None   # ANALYZE / slow log / bench read these)
+        self._last_stats = None   # ANALYZE / slow log read these)
         # each statement resets the diagnostics area, except the SHOWs
         # that read it (MySQL: SHOW WARNINGS does not clear warnings)
         if not (isinstance(stmt, ast.ShowStmt)
@@ -666,7 +666,7 @@ class Session:
                                                 trace_id=trace_id))
             # release the executed plan tree: an idle pooled session
             # must not pin a multi-MB INSERT's literal plan (the sealed
-            # collector keeps only name+number OpStats for bench)
+            # collector keeps only name+number OpStats)
             self._last_plan = None
             if coll is not None:
                 coll.seal()

@@ -582,7 +582,7 @@ def exec_cached_cop(storage, region: Region, plan: CopPlan, s: bytes,
         # memo (shard transfers, build tables) keeps hitting —
         # re-filtering per execution silently re-uploaded whole
         # probe tables. Agg plans stay uncached so the host and
-        # device paths both really compute (the bench contract).
+        # device paths both really compute.
         with _memo_lock:
             memo = getattr(chunk, "_cop_filter_memo", None)
             if memo is None:

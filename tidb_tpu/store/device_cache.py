@@ -6,8 +6,7 @@ decode cost of repeated analytical reads, but every execution still
 re-paid the host->device transfer unless the SAME chunk object happened
 to carry a device memo — an invisible, per-object, unbudgeted residency
 that evaporates with the host entry and never helps the streaming path.
-BENCH r05 put the device scan path at ~0.23 of the memory roofline
-largely on that re-upload. This module is the TiFlash-columnar-replica
+This module is the TiFlash-columnar-replica
 analogue one level further down (PAPER.md): the storage node keeps the
 PADDED, DICT-ENCODED device arrays per region block resident in HBM,
 keyed by (region, schema fingerprint, range) and validated by the

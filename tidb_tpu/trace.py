@@ -71,8 +71,9 @@ _tl = threading.local()
 # declared span vocabulary: every trace.begin / trace.span call site in
 # the package names one of these, as a string literal (lint rule
 # trace-names — tidb_tpu/lint/rules/tracenames.py). One table so the
-# docs (docs/OBSERVABILITY.md), the Chrome export and the bench
-# attribution all read the same names.
+# docs (docs/OBSERVABILITY.md), the Chrome export and the span counters
+# (tidb_tpu_span_*_total{span}, read by benchmark/metrics/) all read
+# the same names.
 SPAN_NAMES = {
     # statement lifecycle (session/__init__.py)
     "statement": "root of one non-internal statement execution",
@@ -646,7 +647,7 @@ def ring_snapshot() -> list[dict]:
 
 
 def ring_records(min_id: int = 0) -> list[dict]:
-    """Full retained records (bench attribution walks their trees)."""
+    """Full retained records, trees included (the tests walk them)."""
     return _RING.records(min_id)
 
 
@@ -697,8 +698,8 @@ def tree(root: Span, base_ns: int | None = None) -> dict:
 
 def validate(root: Span) -> list[str]:
     """Structural problems of a FINISHED tree: begin-without-end spans
-    and negative durations (the balance check the trace bench and the
-    TRACE tests assert empty)."""
+    and negative durations (the balance check tests/test_trace.py and
+    tests/test_span_counters.py assert empty)."""
     problems: list[str] = []
 
     def walk(s: Span) -> None:

@@ -3,7 +3,7 @@
 First-compile of the big fused query programs costs tens of seconds;
 the persistent cache turns every later process's compiles into disk
 loads. One place owns the wiring so the package import, the server
-entrypoint, chip_smoke.py and bench.py all agree on the directory and
+entrypoint, chip_smoke.py and benchmark/run.py all agree on the directory and
 the hit/miss counters (via jax.monitoring events).
 
 Directory: where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it
@@ -69,7 +69,7 @@ def enable() -> str:
 
 
 def stats() -> dict:
-    """Snapshot for BENCH json / the status API: the directory in
+    """Snapshot for the server log / chip_smoke.py: the directory in
     force, how many compiled executables it currently holds (None
     before the first one is written), and this process's hit/miss
     counts."""

@@ -1,8 +1,8 @@
 """Status-port HTTP client: one bounded-timeout JSON fetch helper.
 
 Before this module, every consumer of the status API hand-rolled its
-own `urllib.request.urlopen` — fleet.py's health probe, bench.py's
-fleet scrapes, and half a dozen test files, each with its own timeout
+own `urllib.request.urlopen` — fleet.py's health probe, member.py's
+cluster scrapes, and half a dozen test files, each with its own timeout
 (or none). One shared client keeps the contract in one place:
 
   * every request carries an explicit bounded timeout — a dead or
