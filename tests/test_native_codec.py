@@ -3,21 +3,26 @@
 Ref model: util/codec/codec_test.go + bench — the native decoder must be
 bit-identical with the Python reference implementation on every input,
 including NULLs, defaults for rows written before ALTER ADD COLUMN,
-decimal rescaling, and fallback on varlen columns.
+decimal rescaling, string columns (group edges, non-UTF-8 bytes,
+malformed groups), and fallback on JSON / DURATION / wide-decimal layouts.
 """
 
 import decimal
+import json
 import random
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from tidb_tpu import native, tablecodec
 from tidb_tpu.schema.model import ColumnInfo, TableInfo
-from tidb_tpu.sqltypes import (FieldType, TypeCode, new_decimal_field,
-                               new_double_field, new_int_field,
+from tidb_tpu.sqltypes import (FieldType, TypeCode, new_date_field,
+                               new_decimal_field, new_double_field,
+                               new_duration_field, new_int_field,
                                new_string_field)
-from tidb_tpu.table import kvrows_to_chunk
+from tidb_tpu.table import (_kvrows_to_chunk_native, decode_kvrows,
+                            kvrows_to_chunk)
 
 pytestmark = pytest.mark.skipif(native.lib() is None,
                                 reason="no C++ toolchain")
@@ -41,6 +46,24 @@ def _encode_rows(info, rows):
     return out
 
 
+# TPC-H's eight tables as the benchmark's generator declares them: the
+# schema file gives each column's declared width, from which its type
+# follows (8: BIGINT keys, DECIMAL(15,2) otherwise; 4: DATE or INT;
+# else CHAR/VARCHAR of that length)
+_TPCH = json.loads((Path(__file__).parent.parent / "benchmark" / "generators"
+                    / "tpch_dbgen.schema.json").read_text())["tables"]
+
+
+def _tpch_ft(name, width):
+    if width == 8:
+        return new_int_field() if name.endswith("key") \
+            else new_decimal_field(15, 2)
+    if width == 4:
+        return new_date_field() if name.endswith("date") \
+            else new_int_field()
+    return new_string_field(width)
+
+
 def _python_chunk(info, cols, kvrows, handle_col=None):
     """Force the pure-Python decode path."""
     import tidb_tpu.table as table_mod
@@ -54,11 +77,17 @@ def _python_chunk(info, cols, kvrows, handle_col=None):
 
 def _assert_chunks_equal(a, b):
     assert a.num_rows == b.num_rows
+    assert len(a.columns) == len(b.columns)
     for ca, cb in zip(a.columns, b.columns):
         np.testing.assert_array_equal(np.asarray(ca.valid),
                                       np.asarray(cb.valid))
         va, vb = np.asarray(ca.data), np.asarray(cb.data)
-        if va.dtype == np.float64:
+        assert va.dtype == vb.dtype
+        if va.dtype == object:
+            # every slot, the fill in NULL ones too, and str against bytes
+            assert va.tolist() == vb.tolist()
+            assert [type(x) for x in va] == [type(x) for x in vb]
+        elif va.dtype == np.float64:
             np.testing.assert_allclose(va[ca.valid], vb[cb.valid])
         else:
             np.testing.assert_array_equal(va[ca.valid], vb[cb.valid])
@@ -152,15 +181,145 @@ class TestParity:
         _assert_chunks_equal(got, want)
         assert list(got.columns[0].data) == [0, 42]
 
-    def test_string_column_falls_back(self):
+    def test_string_column_decodes_natively(self):
         info = _mk_table([(new_int_field(), None, True),
                           (new_string_field(), None, True)])
         rows = [{1: i, 2: f"s{i}"} for i in range(20)]
         kvrows = _encode_rows(info, rows)
-        from tidb_tpu.table import _kvrows_to_chunk_native
-        assert _kvrows_to_chunk_native(info.columns, kvrows, None) is None
+        got = _kvrows_to_chunk_native(info.columns, kvrows, None)
+        assert got is not None
+        _assert_chunks_equal(got, _python_chunk(info, info.columns, kvrows))
         ch = kvrows_to_chunk(info, info.columns, kvrows, None)
         assert ch.columns[1].get(5) == "s5"
+
+    @pytest.mark.parametrize("ft,val", [
+        (FieldType(TypeCode.JSON), '{"a":1}'),
+        (new_duration_field(), 3_600_000_000),
+    ], ids=["json", "duration"])
+    def test_json_and_duration_columns_fall_back(self, ft, val):
+        info = _mk_table([(new_int_field(), None, True), (ft, None, True)])
+        kvrows = _encode_rows(info, [{1: i, 2: val} for i in range(20)])
+        assert _kvrows_to_chunk_native(info.columns, kvrows, None) is None
+        ch = kvrows_to_chunk(info, info.columns, kvrows, None)
+        assert ch.columns[1].data[5] == val
+        # the fixed-width column beside it alone is the walker's again
+        assert _kvrows_to_chunk_native(info.columns[:1], kvrows,
+                                       None) is not None
+
+    def test_wide_decimal_table_keeps_the_python_path(self):
+        info = _mk_table([(new_string_field(), None, True),
+                          (new_decimal_field(30, 2), None, True)])
+        kvrows = _encode_rows(info, [{1: f"s{i}", 2: (2, 10 ** 25 + i)}
+                                     for i in range(10)])
+        ch, native_built = decode_kvrows(info, info.columns[:1], kvrows)
+        assert not native_built
+        _assert_chunks_equal(ch, _python_chunk(info, info.columns[:1],
+                                               kvrows))
+
+    @pytest.mark.parametrize("length", [0, 7, 8, 9, 16, 17, 200])
+    def test_string_lengths_at_group_edges(self, length):
+        info = _mk_table([(new_string_field(), None, True)])
+        text = ("the quick brown fox jumps over the lazy dog " * 5)[:length]
+        rows = [{1: text}, {1: text[::-1]}, {1: ""}, {1: text}]
+        kvrows = _encode_rows(info, rows)
+        got = _kvrows_to_chunk_native(info.columns, kvrows, None)
+        assert got is not None
+        _assert_chunks_equal(got, _python_chunk(info, info.columns, kvrows))
+        assert got.columns[0].data.tolist() == [r[1] for r in rows]
+
+    @pytest.mark.parametrize("default,nullable", [
+        (None, True), ("dflt", False), (b"\xff\xfe", False), ("", False),
+        ("h\u00e9", False)],
+        ids=["null", "str", "non-utf8", "empty", "multibyte"])
+    def test_string_nulls_and_missing_column_defaults(self, default,
+                                                      nullable):
+        # c1 written before ALTER ADD COLUMN in two rows of three; an
+        # explicit NULL in every fifth
+        info = _mk_table([(new_int_field(), None, True),
+                          (new_string_field(), default, nullable)])
+        rows = []
+        for i in range(60):
+            r = {1: i}
+            if i % 3 == 0:
+                r[2] = None if i % 5 == 0 else f"v{i}"
+            rows.append(r)
+        kvrows = _encode_rows(info, rows)
+        got = _kvrows_to_chunk_native(info.columns, kvrows, None)
+        assert got is not None
+        _assert_chunks_equal(got, _python_chunk(info, info.columns, kvrows))
+        col = got.columns[1]
+        assert col.valid[3] and col.data[3] == "v3"
+        assert not col.valid[15] and col.data[15] == ""
+        assert bool(col.valid[1]) == (default is not None)
+        assert col.data[1] == ("" if default is None else default)
+
+    def test_non_utf8_bytes_stay_bytes_and_multibyte_utf8_is_str(self):
+        info = _mk_table([(FieldType(TypeCode.BLOB), None, True),
+                          (new_string_field(), None, True)])
+        rows = [{1: b"\xff\x00\xfe binary", 2: "plain"},
+                {1: b"ascii bytes", 2: "gr\u00fc\u00dfe \u4e16\u754c \U0001f600"},
+                {1: "\u00e9".encode("utf8")[:1], 2: "x" * 8 + "\u00e9"},
+                {1: b"", 2: None}]
+        kvrows = _encode_rows(info, rows)
+        got = _kvrows_to_chunk_native(info.columns, kvrows, None)
+        assert got is not None
+        _assert_chunks_equal(got, _python_chunk(info, info.columns, kvrows))
+        assert got.columns[0].data.tolist() == [
+            b"\xff\x00\xfe binary", "ascii bytes", b"\xc3", ""]
+        assert got.columns[1].data[1] == "gr\u00fc\u00dfe \u4e16\u754c \U0001f600"
+
+    @pytest.mark.parametrize("handle_col", [None, 0, 2])
+    def test_string_between_a_skipped_one_and_fixed_width(self, handle_col):
+        info = _mk_table([(new_string_field(), None, True),
+                          (new_string_field(), None, True),
+                          (new_int_field(), None, True),
+                          (new_decimal_field(15, 2), None, True),
+                          (new_string_field(), None, True)])
+        rows = [{1: "skip me " * (i % 4), 2: f"keep{i}" * (i % 3), 3: i,
+                 4: (2, i * 101), 5: None if i % 4 == 0 else "t" * i}
+                for i in range(40)]
+        kvrows = _encode_rows(info, rows)
+        for cols in (info.columns[1:], [info.columns[4], info.columns[2],
+                                        info.columns[1]]):
+            got = _kvrows_to_chunk_native(cols, kvrows, handle_col)
+            assert got is not None
+            _assert_chunks_equal(
+                got, _python_chunk(info, cols, kvrows, handle_col))
+        if handle_col is not None:
+            assert got.columns[handle_col].data.tolist() == \
+                list(range(1, 41))
+
+    @pytest.mark.parametrize("fault", ["marker", "padding", "truncated"])
+    def test_malformed_group_is_pythons_error(self, fault):
+        info = _mk_table([(new_int_field(), None, True),
+                          (new_string_field(), None, True)])
+        kvrows = _encode_rows(info, [{1: i, 2: "abc"} for i in range(4)])
+        k, v = kvrows[2]
+        # the value ends [BYTES_FLAG] "abc" + 5 pad bytes + marker 0xFA
+        assert v[-9:] == b"abc\x00\x00\x00\x00\x00\xfa"
+        bad = {"marker": v[:-1] + b"\xf0",
+               "padding": v[:-2] + b"\x01\xfa",
+               "truncated": v[:-3]}[fault]
+        kvrows[2] = (k, bad)
+        assert _kvrows_to_chunk_native(info.columns, kvrows, None) is None
+        with pytest.raises(ValueError):
+            kvrows_to_chunk(info, info.columns, kvrows, None)
+        # also when the string is only walked over
+        if fault != "truncated":
+            assert _kvrows_to_chunk_native(info.columns[:1], kvrows,
+                                           None) is None
+
+    def test_datum_of_the_other_kind_falls_back(self):
+        info = _mk_table([(new_int_field(), None, True),
+                          (new_string_field(), None, True)])
+        # a number stored under the string column: python keeps the int
+        kvrows = _encode_rows(info, [{1: 1, 2: "a"}, {1: 2, 2: 7}])
+        assert _kvrows_to_chunk_native(info.columns, kvrows, None) is None
+        ch = kvrows_to_chunk(info, info.columns, kvrows, None)
+        assert ch.columns[1].data.tolist() == ["a", 7]
+        # a string stored under the int column
+        kvrows = _encode_rows(info, [{1: "x", 2: "a"}])
+        assert _kvrows_to_chunk_native(info.columns, kvrows, None) is None
 
     def test_extra_stored_columns_skipped(self):
         # rows contain a dropped column's leftovers (incl. a string)
@@ -203,6 +362,41 @@ class TestParity:
             got = kvrows_to_chunk(info, info.columns, kvrows, None)
             want = _python_chunk(info, info.columns, kvrows, None)
             _assert_chunks_equal(got, want)
+
+    @pytest.mark.parametrize("table", sorted(_TPCH))
+    def test_fuzz_tpch_layouts(self, table):
+        info = _mk_table([(_tpch_ft(name, width), None, True)
+                          for name, width in _TPCH[table].items()])
+        rng = random.Random(sum(map(ord, table)))
+        alphabet = "abcdefghij klmnop,.-ABC" + "\u00e9\u4e16"
+        for trial in range(6):
+            rows = []
+            for _ in range(rng.randint(0, 80)):
+                r = {}
+                for ci in info.columns:
+                    if rng.random() < 0.05:
+                        continue            # absent
+                    if rng.random() < 0.05:
+                        r[ci.id] = None     # explicit NULL
+                    elif ci.ft.tp == TypeCode.NEWDECIMAL:
+                        r[ci.id] = (2, rng.randint(-10**12, 10**12))
+                    elif ci.ft.tp == TypeCode.VARCHAR:
+                        # ASCII-only batches on even trials: the bulk path
+                        chars = alphabet[:-2] if trial % 2 == 0 else alphabet
+                        r[ci.id] = "".join(
+                            rng.choice(chars)
+                            for _ in range(rng.randint(0, ci.ft.flen)))
+                    else:
+                        r[ci.id] = rng.randint(0, 2**40)
+                rows.append(r)
+            kvrows = _encode_rows(info, rows)
+            cols = rng.sample(info.columns,
+                              rng.randint(1, len(info.columns)))
+            handle_col = rng.choice([None, 0, len(cols)])
+            got = _kvrows_to_chunk_native(cols, kvrows, handle_col)
+            assert got is not None
+            _assert_chunks_equal(
+                got, _python_chunk(info, cols, kvrows, handle_col))
 
 
 class TestBatchPrimitives:
@@ -247,5 +441,27 @@ class TestPerf:
         t0 = time.perf_counter()
         want = _python_chunk(info, info.columns, kvrows, None)
         t_python = time.perf_counter() - t0
+        _assert_chunks_equal(got, want)
+        assert t_native <= t_python, (t_native, t_python)
+
+    def test_native_not_slower_on_full_width_rows(self):
+        """lineitem's sixteen columns, five of them strings: the rows the
+        streamed joins decode (typically ~20-30x faster natively)."""
+        import time
+        info = _mk_table([(_tpch_ft(name, width), None, True)
+                          for name, width in _TPCH["lineitem"].items()])
+        rows = [{ci.id: ("c" * (1 + (i + ci.id) % ci.ft.flen)
+                         if ci.ft.tp == TypeCode.VARCHAR
+                         else (2, i * 7) if ci.ft.tp == TypeCode.NEWDECIMAL
+                         else i)
+                 for ci in info.columns} for i in range(5000)]
+        kvrows = _encode_rows(info, rows)
+        t0 = time.perf_counter()
+        got = _kvrows_to_chunk_native(info.columns, kvrows, None)
+        t_native = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        want = _python_chunk(info, info.columns, kvrows, None)
+        t_python = time.perf_counter() - t0
+        assert got is not None
         _assert_chunks_equal(got, want)
         assert t_native <= t_python, (t_native, t_python)

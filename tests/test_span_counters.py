@@ -5,7 +5,8 @@
   by exactly one statement's tree when its root ends;
 * the streamed and the materialized scan of one table both produce
   `copr.kv_scan` / `copr.decode` / `copr.exec`, `rows` summing to the
-  table;
+  table, and count its rows in `tidb_tpu_decode_rows_total` under the
+  decoder that built the chunks (an index layout under `python`);
 * under a live `jax.profiler` session the `.xplane.pb` host plane holds
   the statement's span names with one shared trace id, workers' spans on
   their own threads; outside a session no annotation is constructed;
@@ -27,6 +28,7 @@ from tidb_tpu.store.storage import new_mock_storage
 
 SELF = 'tidb_tpu_span_self_seconds_total{span="%s"}'
 COUNT = 'tidb_tpu_span_count_total{span="%s"}'
+DECODED = 'tidb_tpu_decode_rows_total{path="%s"}'
 
 
 # -- the self-time walk against hand-built trees ----------------------------
@@ -208,6 +210,17 @@ def _walk(span, out):
     return out
 
 
+def _native_built() -> bool:
+    from tidb_tpu import native
+    return native.decoder_kind() == "native"
+
+
+def _decoded_rows() -> dict:
+    snap = metrics.snapshot()
+    return {path: snap.get(DECODED % path, 0)
+            for path in ("native", "python")}
+
+
 @pytest.mark.parametrize("stream", [1, 0], ids=["streamed", "materialized"])
 def test_scan_steps_on_both_paths(scan_session, stream):
     s = scan_session
@@ -227,14 +240,52 @@ def test_scan_steps_on_both_paths(scan_session, stream):
     for name in ("copr.kv_scan", "copr.decode", "copr.exec"):
         assert name in by_name, sorted(by_name)
         assert sum(sp.tags["rows"] for sp in by_name[name]) == N_ROWS, name
-    # a VARCHAR column: native/codec.cc declines the layout
-    assert {sp.tags["native"] for sp in by_name["copr.decode"]} == {0}
+    # a VARCHAR column is a layout native/codec.cc takes
+    assert {sp.tags["native"] for sp in by_name["copr.decode"]} == \
+        {int(_native_built())}
     assert trace.validate(rec["root"]) == []
+
+
+@pytest.mark.parametrize("stream", [1, 0], ids=["streamed", "materialized"])
+def test_decode_rows_counted_under_the_decoder_that_ran(scan_session,
+                                                        stream):
+    s = scan_session
+    ran, other = ("native", "python") if _native_built() \
+        else ("python", "native")
+    before = _decoded_rows()
+    # untraced: the counter does not hang on the span being kept
+    with config.session_overlay({"tidb_tpu_copr_stream": stream,
+                                 "tidb_tpu_chunk_cache": 0,
+                                 "tidb_tpu_trace_sample": 0}):
+        assert len(s.query("SELECT a, b, c FROM t WHERE b < 7").rows) == \
+            N_ROWS
+    after = _decoded_rows()
+    assert after[ran] - before[ran] == N_ROWS
+    assert after[other] == before[other]
+
+
+def test_an_index_scan_counts_as_python(scan_session):
+    s = scan_session
+    s.execute("CREATE TABLE IF NOT EXISTS ix (a INT PRIMARY KEY, b INT, "
+              "KEY ib (b))")
+    s.execute("INSERT IGNORE INTO ix VALUES " + ", ".join(
+        f"({i}, {i % 5})" for i in range(200)))
+    trace.reset_for_tests()
+    before = _decoded_rows()
+    with config.session_overlay({"tidb_tpu_chunk_cache": 0,
+                                 "tidb_tpu_trace_sample": 1}):
+        rows = s.query("SELECT b FROM ix USE INDEX (ib) WHERE b = 3").rows
+    after = _decoded_rows()
+    assert len(rows) == 40
+    rec = [r for r in trace.ring_records() if "FROM ix" in r["sql"]][-1]
+    dec = [sp for sp in _walk(rec["root"], []) if sp.name == "copr.decode"]
+    assert dec and {sp.tags["native"] for sp in dec} == {0}
+    assert after["python"] - before["python"] == 40
+    assert after["native"] == before["native"]
 
 
 def test_decode_span_says_when_the_native_codec_took_the_layout(
         scan_session):
-    from tidb_tpu.native import decode_rows_native
     s = scan_session
     trace.reset_for_tests()
     with config.session_overlay({"tidb_tpu_chunk_cache": 0,
@@ -245,8 +296,8 @@ def test_decode_span_says_when_the_native_codec_took_the_layout(
         assert len(s.query("SELECT a, b FROM f WHERE b < 9").rows) == 3
     rec = [r for r in trace.ring_records() if "FROM f" in r["sql"]][-1]
     dec = [sp for sp in _walk(rec["root"], []) if sp.name == "copr.decode"]
-    native_built = decode_rows_native([], []) is not None
-    assert dec and {sp.tags["native"] for sp in dec} == {int(native_built)}
+    assert dec and {sp.tags["native"] for sp in dec} == \
+        {int(_native_built())}
 
 
 # -- the same spans on the profiler's clock ---------------------------------

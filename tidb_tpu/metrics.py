@@ -38,6 +38,7 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "DEVICE_UTILIZATION", "HBM_OCCUPANCY", "CHIP_UTILIZATION",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
            "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
+           "DECODE_ROWS",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
            "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS"]
 
@@ -351,6 +352,12 @@ KERNEL_DISPATCHES = "tidb_tpu_kernel_dispatch_total"
 # {path="scatter"} the segment scatters otherwise. The program decides
 # on the device from the count it already computes
 AGG_DISPATCHES = "tidb_tpu_agg_dispatch_total"
+# rows a coprocessor scan decoded, counted a batch at a time where the
+# copr.decode span is (store/copr.decode_cop_batch), by who built the
+# chunk: {path="native"} native/codec.cc, {path="python"} the Python
+# decoder (an index layout, a JSON or DURATION column, a wide-decimal
+# table, a row the walker declined, no compiler)
+DECODE_ROWS = "tidb_tpu_decode_rows_total"
 # the statement span trees as counters (trace.py folds every ended
 # root's tree here, span_totals above): self time — a span's duration
 # less what its same-thread children cover, so thread-seconds that
@@ -493,6 +500,9 @@ _HELP = {
     AGG_DISPATCHES:
         "Group-by dispatches read back, by the per-slot reduction "
         "their block took (dense|scatter).",
+    DECODE_ROWS:
+        "Rows a coprocessor scan decoded, by the decoder that built "
+        "the chunk (native|python).",
     SPAN_SELF_SECONDS:
         "Statement span self time (thread-seconds), by span name.",
     SPAN_COUNT: "Statement spans ended, by span name.",

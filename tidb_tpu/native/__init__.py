@@ -23,12 +23,13 @@ import numpy as np
 
 __all__ = ["lib", "decoder_kind", "decode_rows_native", "scan_rows_native",
            "NATIVE_KIND_INT", "NATIVE_KIND_FLOAT", "NATIVE_KIND_DECIMAL",
-           "NATIVE_KIND_HANDLE"]
+           "NATIVE_KIND_HANDLE", "NATIVE_KIND_BYTES"]
 
 NATIVE_KIND_INT = 0
 NATIVE_KIND_FLOAT = 1
 NATIVE_KIND_DECIMAL = 2
 NATIVE_KIND_HANDLE = 3
+NATIVE_KIND_BYTES = 4
 
 _lock = threading.Lock()
 _lib = None
@@ -64,7 +65,7 @@ def _build() -> ctypes.CDLL | None:
     cdll = _compile("codec")
     if cdll is None:
         return None
-    cdll.decode_rows.restype = ctypes.c_int
+    cdll.decode_rows.restype = ctypes.c_int64
     cdll.decode_rows.argtypes = [
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
@@ -73,6 +74,7 @@ def _build() -> ctypes.CDLL | None:
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint8),
         ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_double),
         ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_void_p),
+        ctypes.c_void_p,
     ]
     return cdll
 
@@ -166,25 +168,28 @@ def decode_rows_native(kvrows, col_specs):
     """Batch-decode record (key, value) pairs into columnar arrays.
 
     col_specs: list of (col_id, kind, frac, default_valid, default_value)
-    — kind NATIVE_KIND_*; for HANDLE the id/default are ignored.
-    Returns (datas, valids) lists of numpy arrays, or None when the native
-    path is unavailable or declined the input (caller uses the Python
-    decoder).
+    — kind NATIVE_KIND_*; for HANDLE the id/default are ignored, and a
+    BYTES column's default value is the caller's to place.
+    Returns (datas, valids, strbuf): numpy arrays per column — int64 or
+    float64 values, and for a BYTES column an int64[2, n] of each row's
+    [start, end) in `strbuf`, the batch's un-stuffed `bytes` (an empty
+    range for NULL, -1 where the row lacks the column and its default is
+    not NULL) — or None when the native path is unavailable or declined
+    the input (caller uses the Python decoder).
     """
     cdll = lib()
     if cdll is None:
         return None
     n = len(kvrows)
-    keys = b"".join(k for k, _v in kvrows)
-    values = b"".join(v for _k, v in kvrows)
+    ks, vs = zip(*kvrows) if n else ((), ())
+    keys = b"".join(ks)
+    values = b"".join(vs)
     key_offs = np.zeros(n + 1, dtype=np.int64)
     val_offs = np.zeros(n + 1, dtype=np.int64)
-    ko = vo = 0
-    for i, (k, v) in enumerate(kvrows):
-        ko += len(k)
-        vo += len(v)
-        key_offs[i + 1] = ko
-        val_offs[i + 1] = vo
+    np.cumsum(np.fromiter(map(len, ks), dtype=np.int64, count=n),
+              out=key_offs[1:])
+    np.cumsum(np.fromiter(map(len, vs), dtype=np.int64, count=n),
+              out=val_offs[1:])
 
     ncols = len(col_specs)
     col_ids = np.array([s[0] for s in col_specs], dtype=np.int64)
@@ -198,7 +203,7 @@ def decode_rows_native(kvrows, col_specs):
         if s[3] and s[4] is not None:
             if s[1] == NATIVE_KIND_FLOAT:
                 def_float[i] = float(s[4])
-            else:
+            elif s[1] != NATIVE_KIND_BYTES:
                 def_int[i] = int(s[4])
         elif s[3] and s[4] is None:
             def_valid[i] = 0   # default is NULL
@@ -208,15 +213,22 @@ def decode_rows_native(kvrows, col_specs):
     out_ptrs = (ctypes.c_void_p * ncols)()
     valid_ptrs = (ctypes.c_void_p * ncols)()
     for i, s in enumerate(col_specs):
-        dt = np.float64 if s[1] == NATIVE_KIND_FLOAT else np.int64
-        d = np.zeros(n, dtype=dt)
+        if s[1] == NATIVE_KIND_BYTES:
+            d = np.zeros((2, n), dtype=np.int64)
+        else:
+            d = np.zeros(n, dtype=np.float64 if s[1] == NATIVE_KIND_FLOAT
+                         else np.int64)
         m = np.zeros(n, dtype=np.uint8)
         datas.append(d)
         valids.append(m)
         out_ptrs[i] = d.ctypes.data_as(ctypes.c_void_p)
         valid_ptrs[i] = m.ctypes.data_as(ctypes.c_void_p)
+    # un-stuffing never grows a datum: the values' length holds every
+    # BYTES column of the batch
+    strbuf = np.empty(len(values) if NATIVE_KIND_BYTES in col_kind else 0,
+                      dtype=np.uint8)
 
-    rc = cdll.decode_rows(
+    used = cdll.decode_rows(
         values, val_offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         keys, key_offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         n, ncols,
@@ -226,7 +238,8 @@ def decode_rows_native(kvrows, col_specs):
         def_valid.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
         def_int.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         def_float.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        out_ptrs, valid_ptrs)
-    if rc != 0:
+        out_ptrs, valid_ptrs, strbuf.ctypes.data_as(ctypes.c_void_p))
+    if used < 0:
         return None
-    return datas, [m.astype(bool) for m in valids]
+    return (datas, [m.astype(bool) for m in valids],
+            strbuf[:used].tobytes())

@@ -4,9 +4,13 @@
 // bytes.go 8-byte-group stuffing, codec.go:387 DecodeOneToChunk) and
 // tablecodec.go EncodeRow/DecodeRow. The reference leans on Rust TiKV for
 // storage-side decode; this is the TPU build's C++ equivalent: it turns
-// raw KV record pairs straight into the columnar buffers (int64/float64 +
-// validity) that jax.device_put ships to HBM, replacing the per-datum
-// Python loop in table.kvrows_to_chunk.
+// raw KV record pairs straight into columnar buffers, replacing the
+// per-datum Python loop in table.kvrows_to_chunk: int64/float64 +
+// validity for the fixed-width columns (what jax.device_put ships to
+// HBM), and for byte strings (every STRING-eval column) the un-stuffed
+// bytes of the whole batch in one buffer + each row's [start, end) in it,
+// which the caller turns into the object column. Wide decimals, JSON and
+// DURATION columns stay with the Python decoder.
 //
 // Exposed via a plain C ABI consumed with ctypes (no pybind11 in the
 // image). All multi-byte integers in the encoding are big-endian.
@@ -48,18 +52,30 @@ inline double decode_float_payload(const uint8_t* p) {
   return d;
 }
 
-// Skip (or measure) one group-stuffed byte string. Returns bytes consumed,
-// or -1 on malformed input.
-inline int64_t skip_bytes_datum(const uint8_t* p, int64_t avail) {
+// Walk one group-stuffed byte string (8-byte groups, each followed by the
+// marker 0xFF - pad; the first group with pad > 0 ends it, its pad bytes
+// zero): exactly what codec.decode_bytes accepts. With `out` the
+// un-stuffed bytes are appended at *out (never more than were consumed)
+// and *out advanced; without, the datum is only skipped. Returns bytes
+// consumed, or -1 on malformed input.
+inline int64_t read_bytes_datum(const uint8_t* p, int64_t avail,
+                                uint8_t** out) {
   int64_t off = 0;
   while (true) {
     if (off + 9 > avail) return -1;
-    uint8_t marker = p[off + 8];
+    const uint8_t* group = p + off;
+    int pad = 0xFF - group[8];
     off += 9;
-    int pad = 0xFF - marker;
-    if (pad == 0) continue;
     if (pad > 8) return -1;
-    return off;
+    int real = 8 - pad;
+    for (int i = real; i < 8; i++) {
+      if (group[i] != 0) return -1;
+    }
+    if (out) {
+      std::memcpy(*out, group, real);
+      *out += real;
+    }
+    if (pad > 0) return off;
   }
 }
 
@@ -77,7 +93,7 @@ inline int64_t skip_datum(const uint8_t* p, int64_t avail) {
       return avail >= 10 ? 10 : -1;
     }
     case BYTES_FLAG: {
-      int64_t n = skip_bytes_datum(p + 1, avail - 1);
+      int64_t n = read_bytes_datum(p + 1, avail - 1, nullptr);
       return n < 0 ? -1 : n + 1;
     }
     default:
@@ -101,6 +117,7 @@ extern "C" {
 // 2 = decimal (scaled int64; rescaled to col_frac when the stored frac
 //     differs)
 // 3 = handle (value comes from the record key, not the row)
+// 4 = bytes (STRING eval: CHAR/VARCHAR/TEXT/BLOB/BINARY/ENUM/SET)
 
 // Decode n encoded rows into columnar buffers.
 //   values / val_offsets[n+1]: concatenated row values
@@ -108,16 +125,28 @@ extern "C" {
 //   ncols, col_ids[ncols], col_kind[ncols], col_frac[ncols]
 //   def_valid[ncols], def_int[ncols], def_float[ncols]: per-column default
 //     (applied when the row lacks the column id; def_valid 0 => NULL)
-//   out_data[ncols]: int64*/double* per column; out_valid[ncols]: uint8*
-// Returns 0 on success, -1 on malformed/unsupported input (caller falls
-// back to the Python decoder).
-int decode_rows(const uint8_t* values, const int64_t* val_offsets,
-                const uint8_t* keys, const int64_t* key_offsets,
-                int64_t n, int32_t ncols, const int64_t* col_ids,
-                const uint8_t* col_kind, const int32_t* col_frac,
-                const uint8_t* def_valid, const int64_t* def_int,
-                const double* def_float, int64_t** out_data,
-                uint8_t** out_valid) {
+//   out_data[ncols]: int64[n]/double[n] per column, int64[2n] for a
+//     bytes column: the row's start in str_buf at [r], its end at [n + r]
+//     (an empty range for NULL, both -1 where the caller is to put the
+//     column's non-NULL default); out_valid[ncols]: uint8[n]
+//   str_buf: room for the un-stuffed bytes of every bytes column of the
+//     batch together; val_offsets[n] bytes always suffice (un-stuffing
+//     never grows a datum). Unused without a bytes column.
+// Returns the bytes written to str_buf (0 without a bytes column) on
+// success, -1 on malformed/unsupported input (caller falls
+// back to the Python decoder, which gives the answer or raises the
+// error): a truncated datum, a bad group marker or nonzero padding, a
+// wide-decimal or unknown flag, a uint64 past int64, a >18-digit decimal
+// shift, a bytes datum under a fixed-width column, a non-bytes datum
+// under a bytes column, a float under an int or decimal column.
+int64_t decode_rows(const uint8_t* values, const int64_t* val_offsets,
+                    const uint8_t* keys, const int64_t* key_offsets,
+                    int64_t n, int32_t ncols, const int64_t* col_ids,
+                    const uint8_t* col_kind, const int32_t* col_frac,
+                    const uint8_t* def_valid, const int64_t* def_int,
+                    const double* def_float, int64_t** out_data,
+                    uint8_t** out_valid, uint8_t* str_buf) {
+  uint8_t* str_end = str_buf;
   for (int64_t r = 0; r < n; r++) {
     // handle: key = 't' + 9B(int flagged? no: raw encode_int 8B) + '_r' + 8B
     // record_key layout: 't' (1) + 8B sign-flipped table id + '_r' (2) +
@@ -132,6 +161,9 @@ int decode_rows(const uint8_t* values, const int64_t* val_offsets,
       if (col_kind[c] == 3) {
         out_data[c][r] = handle;
         out_valid[c][r] = 1;
+      } else if (col_kind[c] == 4) {
+        out_valid[c][r] = def_valid[c];
+        out_data[c][r] = out_data[c][n + r] = def_valid[c] ? -1 : 0;
       } else if (def_valid[c]) {
         out_valid[c][r] = 1;
         if (col_kind[c] == 1) {
@@ -170,6 +202,22 @@ int decode_rows(const uint8_t* values, const int64_t* val_offsets,
       }
       if (off >= avail) return -1;
       uint8_t flag = v[off];
+      if (col_kind[slot] == 4) {
+        int64_t start = str_end - str_buf;
+        int64_t used = 1;
+        if (flag == BYTES_FLAG) {
+          used = read_bytes_datum(v + off + 1, avail - off - 1, &str_end);
+          if (used < 0) return -1;
+          used += 1;
+        } else if (flag != NIL_FLAG) {
+          return -1;  // a number under a string column: python fallback
+        }
+        out_valid[slot][r] = flag == BYTES_FLAG;
+        out_data[slot][r] = start;
+        out_data[slot][n + r] = str_end - str_buf;
+        off += used;
+        continue;
+      }
       switch (flag) {
         case NIL_FLAG:
           out_valid[slot][r] = 0;
@@ -250,7 +298,7 @@ int decode_rows(const uint8_t* values, const int64_t* val_offsets,
       }
     }
   }
-  return 0;
+  return str_end - str_buf;
 }
 
 // Batch sign-flipped big-endian int64 encode (index/key building).

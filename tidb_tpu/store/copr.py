@@ -15,8 +15,8 @@ import threading
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 
-from tidb_tpu import (config, devplane, kv, memtrack, meter, profiler,
-                      runtime_stats, sched, tablecodec, trace)
+from tidb_tpu import (config, devplane, kv, memtrack, meter, metrics,
+                      profiler, runtime_stats, sched, tablecodec, trace)
 from tidb_tpu.kv import (CopRequest, CopResponse, KVRange, NotLeaderError,
                          RegionError, ReqType, ServerBusyError,
                          KeyLockedError)
@@ -30,7 +30,7 @@ from tidb_tpu.store.backoff import (BO_REGION_MISS, BO_RPC,
                                     BO_SERVER_BUSY, BO_TXN_LOCK,
                                     BackoffExhausted, Backoffer,
                                     COP_MAX_BACKOFF)
-from tidb_tpu.table import index_kvrows_to_chunk, kvrows_to_chunk
+from tidb_tpu.table import decode_kvrows, index_kvrows_to_chunk
 from tidb_tpu.util import failpoint
 from tidb_tpu.util.failpoint import DeviceFaultError
 
@@ -102,15 +102,23 @@ def decode_cop_batch(plan: CopPlan, batch):
     """Raw (key, value) rows -> decoded chunk for `plan` (row or index
     encoding), under a `copr.decode` span: the scan's second step.
     Shared by the materialized handler below and the framed producer in
-    store/stream.py. `native` says whether native/codec.cc took the
-    layout (kvrows_to_chunk stamps it; an index layout never does)."""
-    with trace.span("copr.decode", rows=len(batch), native=0):
+    store/stream.py. Whether native/codec.cc built the chunk (an index
+    layout never does) is the span's `native` tag and the path the
+    batch's rows are counted under in tidb_tpu_decode_rows_total."""
+    with trace.span("copr.decode", rows=len(batch), native=0) as sp:
+        native = False
         if plan.index is not None:
-            return index_kvrows_to_chunk(plan.table, plan.index,
-                                         plan.cols, batch,
-                                         handle_col=plan.handle_col)
-        return kvrows_to_chunk(plan.table, plan.cols, batch,
-                               with_handle_col=plan.handle_col)
+            chunk = index_kvrows_to_chunk(plan.table, plan.index,
+                                          plan.cols, batch,
+                                          handle_col=plan.handle_col)
+        else:
+            chunk, native = decode_kvrows(plan.table, plan.cols, batch,
+                                          with_handle_col=plan.handle_col)
+        sp.tags["native"] = int(native)
+        metrics.counter(metrics.DECODE_ROWS,
+                        {"path": "native" if native else "python"},
+                        len(batch))
+        return chunk
 
 
 def _resolve_block(plan: CopPlan, chunk, dev_ref):
@@ -512,7 +520,6 @@ def _cached_range_chunk(storage, region: Region, plan: CopPlan, s: bytes,
                     cache.drop(key, if_chunk=hit[1])
                     hit = None
                 else:
-                    from tidb_tpu import metrics
                     metrics.counter(metrics.CACHE_DELTA_SERVES)
                     hit = (pend.watermark, merged)
     if hit is not None:
@@ -734,7 +741,6 @@ class CopClient(kv.Client):
         tasks = self.cache.split_ranges_by_region(req.ranges)
         if not tasks:
             return
-        from tidb_tpu import metrics
         metrics.counter(metrics.COP_TASKS, inc=len(tasks))
         coll = runtime_stats.current()
         if coll is not None:

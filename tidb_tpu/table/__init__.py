@@ -27,7 +27,8 @@ from tidb_tpu.sqltypes import (EvalType, FieldType, TypeCode,
                                scaled_to_decimal)
 
 __all__ = ["Table", "DupKeyError", "encode_datum_for_col",
-           "decode_datum_for_col", "rows_to_chunk", "kvrows_to_chunk"]
+           "decode_datum_for_col", "rows_to_chunk", "kvrows_to_chunk",
+           "decode_kvrows"]
 
 
 class DupKeyError(kv.KVError):
@@ -163,11 +164,17 @@ def decode_datum_for_col(v, ft: FieldType):
             isinstance(v, bytes):
         # JSON text decodes here too: filters/joins on JSON columns must
         # see str, not bytes (presentation is too late)
-        try:
-            return v.decode("utf8")
-        except UnicodeDecodeError:
-            return v
+        return _utf8_or_bytes(v)
     return v
+
+
+def _utf8_or_bytes(v: bytes):
+    """A stored byte string as the chunk layer holds it: `str` where it
+    is valid UTF-8, the bytes themselves where not."""
+    try:
+        return v.decode("utf8")
+    except UnicodeDecodeError:
+        return v
 
 
 # auto-increment batch caches shared across per-statement Table objects:
@@ -465,14 +472,36 @@ def rows_to_chunk(fts: list[FieldType], rows: list[list]) -> Chunk:
     return Chunk(cols)
 
 
+def _strings_from_spans(raw: bytes, text: str | None, spans,
+                        default) -> np.ndarray:
+    """The object lane of one string column from the native decoder's
+    buffer: `raw[start:end]` per row as decode_datum_for_col gives it
+    (`''`, object_fill, in NULL slots: their range is empty), `default`
+    where the row lacks the column (start -1). `text` is `raw` decoded
+    when all of it is ASCII: byte and code-point offsets then coincide
+    and a value is one slice of it; per-value decodes otherwise."""
+    starts, ends = spans.tolist()
+    data = np.empty(len(starts), dtype=object)
+    if text is not None:
+        data[:] = [text[a:b] for a, b in zip(starts, ends)]
+    else:
+        data[:] = [_utf8_or_bytes(raw[a:b]) for a, b in zip(starts, ends)]
+    if default is not None:
+        data[spans[0] < 0] = default
+    return data
+
+
 def _kvrows_to_chunk_native(col_infos, kvrows,
                             with_handle_col: int | None) -> Chunk | None:
-    """C++ batch decode straight into columnar buffers (native/codec.cc).
-    Handles fixed-width columns only; None -> caller uses the Python
-    loop (varlen columns, unusual encodings, no compiler)."""
-    from tidb_tpu.native import (NATIVE_KIND_DECIMAL, NATIVE_KIND_FLOAT,
-                                 NATIVE_KIND_HANDLE, NATIVE_KIND_INT,
-                                 decode_rows_native)
+    """C++ batch decode straight into columnar buffers (native/codec.cc):
+    INT, DATETIME, REAL and narrow DECIMAL columns as int64/float64
+    lanes, STRING columns (CHAR, VARCHAR, TEXT, BLOB, BINARY, ENUM, SET)
+    as the object lane rows_to_chunk builds. None -> caller uses the
+    Python loop: a JSON or DURATION column, an encoding the walker
+    declines (its header lists them), no compiler."""
+    from tidb_tpu.native import (NATIVE_KIND_BYTES, NATIVE_KIND_DECIMAL,
+                                 NATIVE_KIND_FLOAT, NATIVE_KIND_HANDLE,
+                                 NATIVE_KIND_INT, decode_rows_native)
     from tidb_tpu.sqltypes import new_int_field
     ncols = len(col_infos) + (1 if with_handle_col is not None else 0)
     specs = []
@@ -492,21 +521,27 @@ def _kvrows_to_chunk_native(col_infos, kvrows,
             kind = NATIVE_KIND_FLOAT
         elif et == EvalType.DECIMAL:
             kind = NATIVE_KIND_DECIMAL
+        elif et == EvalType.STRING:
+            kind = NATIVE_KIND_BYTES
         else:
-            return None   # varlen: python path
+            return None   # JSON, DURATION: python path
         default = None
         if ci.has_default and ci.default is not None:
             default = encode_datum_for_col(ci.default, ci.ft)
             if isinstance(default, tuple):
                 default = default[1]   # scaled int at the column's frac
+            elif kind == NATIVE_KIND_BYTES:
+                default = decode_datum_for_col(default, ci.ft)
         specs.append((ci.id, kind, ci.ft.frac, ci.has_default, default))
         fts.append(ci.ft)
     out = decode_rows_native(kvrows, specs)
     if out is None:
         return None
-    datas, valids = out
-    return Chunk([Column(ft, d, v)
-                  for ft, d, v in zip(fts, datas, valids)])
+    datas, valids, raw = out
+    text = raw.decode("ascii") if raw.isascii() else None
+    return Chunk([Column(ft, _strings_from_spans(raw, text, d, s[4])
+                         if s[1] == NATIVE_KIND_BYTES else d, v)
+                  for ft, s, d, v in zip(fts, specs, datas, valids)])
 
 
 def kvrows_to_chunk(info: TableInfo, col_infos, kvrows,
@@ -514,22 +549,26 @@ def kvrows_to_chunk(info: TableInfo, col_infos, kvrows,
     """Decode raw (key, value) record pairs into a chunk of the requested
     columns. col_infos: list of ColumnInfo to emit, in order.
     with_handle_col: emit the row handle as an extra int column at this
-    output position (DML readers need it to address rows).
-    Fast path: the C++ batch decoder (ref: util/codec DecodeOneToChunk,
-    codec.go:387 — and the Rust TiKV decode the reference leans on)."""
+    output position (DML readers need it to address rows)."""
+    return decode_kvrows(info, col_infos, kvrows, with_handle_col)[0]
+
+
+def decode_kvrows(info: TableInfo, col_infos, kvrows,
+                  with_handle_col: int | None = None) -> tuple[Chunk, bool]:
+    """kvrows_to_chunk's chunk, and whether the C++ batch decoder built
+    it (ref: util/codec DecodeOneToChunk, codec.go:387 — and the Rust
+    TiKV decode the reference leans on), string columns included. The
+    Python loop below is its one fallback, and the reference its chunks
+    are held equal to: tables with a wide-decimal column, JSON and
+    DURATION columns, rows the walker declines, no compiler."""
     from tidb_tpu.sqltypes import new_int_field
     # wide-decimal datums use variable-length encodings the C++ walker
     # doesn't know; any such column in the ROW (even unrequested) gates
     # the whole table to the python decode path
-    ch = None
     if not any(c.ft.is_wide_decimal for c in info.columns):
         ch = _kvrows_to_chunk_native(col_infos, kvrows, with_handle_col)
-    if ch is not None:
-        # whether the C++ decoder took the layout, on the span that
-        # times the decode (store/copr.decode_cop_batch's copr.decode)
-        from tidb_tpu import trace
-        trace.annotate(native=1)
-        return ch
+        if ch is not None:
+            return ch, True
     ncols = len(col_infos) + (1 if with_handle_col is not None else 0)
     rows = []
     for k, v in kvrows:
@@ -560,4 +599,4 @@ def kvrows_to_chunk(info: TableInfo, col_infos, kvrows,
         else:
             fts.append(col_infos[src].ft)
             src += 1
-    return rows_to_chunk(fts, rows)
+    return rows_to_chunk(fts, rows), False
