@@ -37,6 +37,15 @@ TRANSPORT_SPANS = {"copr.task", "copr.stream"}
 SCAN_EXEC_SPANS = {"copr.exec"}
 COLD_SCAN_SPANS = {"copr.kv_scan", "copr.decode"}
 
+# the root executors' own spans (PR 33): exec.agg and exec.topn run at
+# every size (FinalAggExec / HashAggExec on one chip, MeshAggExec /
+# MeshLookupAggExec above it). exec.join names an OPERATOR the plane does
+# not run: on one chip Q3's customer-orders join is a HashJoinExec under
+# the fused aggregate, above one chip route_mesh folds every join into
+# MeshLookupAggExec's lookup chain, whose host work is that operator's
+# exec.agg
+ONE_CHIP_OPERATOR_SPANS = {"exec.join"}
+
 SIZES = (1, 8)
 
 
@@ -118,7 +127,8 @@ class TestTraceSpans:
         assert _fallbacks("mesh") == 0
         _assert_same_across_sizes(
             self._spans, plane,
-            tuple(sorted(names - TRANSPORT_SPANS - COLD_SCAN_SPANS)))
+            tuple(sorted(names - TRANSPORT_SPANS - COLD_SCAN_SPANS
+                         - ONE_CHIP_OPERATOR_SPANS)))
         _assert_same_across_sizes(self._rows, plane,
                                   (sorted(map(tuple, r1)),
                                    sorted(map(tuple, r3))))

@@ -391,6 +391,29 @@ class TestSqlHybrid:
         assert got == want
         assert _metric(metrics.DEVICE_FALLBACKS) == fb0
 
+    def test_cop_agg_capacity_miss_is_paid_once_a_plan(self, skew_sess,
+                                                       monkeypatch):
+        """The kernel a capacity miss escalated to stays on the cached
+        plan (store/copr._keep_escalated): the statement's next
+        execution, from any session, starts at the table that fitted and
+        does not run the 4,096-slot program to the same miss again."""
+        s, _host_rows, _q = skew_sess
+        q = "SELECT cid, SUM(amt) FROM o GROUP BY cid ORDER BY cid LIMIT 5"
+        retries = []
+        real = hybrid.agg_retry
+
+        def counted(*a, **kw):
+            retries.append(1)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(hybrid, "agg_retry", counted)
+        first = s.query(q).rows
+        assert len(retries) == 1
+        other = Session(s.storage)
+        other.execute("USE hj")
+        assert s.query(q).rows == first and other.query(q).rows == first
+        assert len(retries) == 1
+
     def test_explain_analyze_fallback_note(self, skew_sess):
         """A designed device rejection (string-computed group key) is
         counted and surfaces as a fallback note in the EXPLAIN ANALYZE

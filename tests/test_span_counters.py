@@ -641,3 +641,150 @@ def test_span_threads_are_recorded_for_worker_spans(scan_session):
     assert workers and all(sp.tid != me for sp in workers)
     for w in workers:
         assert all(c.tid == w.tid for c in _walk(w, []))
+
+
+# -- the root executors under spans (PR 33) ---------------------------------
+# exec.join / exec.apply (+ exec.apply.inner) / exec.agg / exec.topn are
+# open while the operator's own generator body runs and closed while it
+# waits for a child's chunk or its consumer holds one (executor._OwnSpan):
+# Q18 runs all five.
+
+EXEC_SPANS = ("exec.join", "exec.apply", "exec.apply.inner", "exec.agg",
+              "exec.topn")
+FINAL_GROUPS = "tidb_tpu_agg_final_groups_total"
+
+
+@pytest.fixture(scope="module")
+def q18_session():
+    import tpch
+    s = Session(new_mock_storage())
+    s.execute("CREATE DATABASE sc18")
+    s.execute("USE sc18")
+    s._data = tpch.Q18Data(customers=80, orders=1500, lineitems=6000)
+    tpch.load_q18(s, s._data)
+    s._sql = tpch.Q18.format(quantity=250)
+    s._want, s._qualified = tpch.truth_q18(s._data, 250)
+    yield s
+    s.close()
+
+
+def _traced_q18(s):
+    """One Q18, retained: -> (its root, the counters' moves)."""
+    trace.reset_for_tests()
+    before = metrics.snapshot()
+    with config.session_overlay({"tidb_tpu_trace_sample": 1}):
+        rows = s.query(s._sql).rows
+    after = metrics.snapshot()
+    assert len(rows) == len(s._want) > 0
+    rec = [r for r in trace.ring_records() if "l_quantity" in r["sql"]][-1]
+    return rec["root"], {k: v - before.get(k, 0) for k, v in after.items()
+                         if v != before.get(k, 0)}
+
+
+def test_root_executor_spans_fold_into_the_counters(q18_session):
+    root, moved = _traced_q18(q18_session)
+    assert trace.validate(root) == []
+    st = trace.self_times(root)
+    for name in EXEC_SPANS:
+        assert name in st, sorted(st)
+        ns, n = st[name]
+        assert moved[COUNT % name] == n >= 1
+        assert moved[SELF % name] == pytest.approx(ns / 1e9, abs=1e-9)
+        assert ns > 0
+    # an uncorrelated inner runs once
+    assert st["exec.apply.inner"][1] == 1
+
+
+def test_final_groups_counter_equals_the_groups_emitted(q18_session):
+    s = q18_session
+    root, moved = _traced_q18(s)
+    orders_with_lines = len(set(s._data.l_orderkey.tolist()))
+    # the subquery's FinalAgg emits every order, the HashAgg above the
+    # join the orders that qualified (LIMIT cuts later, in TopN)
+    assert s._qualified > 0
+    assert moved[FINAL_GROUPS] == orders_with_lines + s._qualified
+    tags = sorted(sp.tags["groups"] for sp in _walk(root, [])
+                  if sp.name == "exec.agg" and "groups" in sp.tags)
+    assert tags == sorted([orders_with_lines, s._qualified])
+
+
+def test_a_parents_self_time_excludes_its_children(q18_session):
+    root, _moved = _traced_q18(q18_session)
+    spans = _walk(root, [])
+    st = trace.self_times(root)
+    inner = [sp for sp in spans if sp.name == "exec.apply.inner"]
+    applies = [sp for sp in spans if sp.name == "exec.apply"]
+    assert len(inner) == 1 and inner[0] in [
+        c for a in applies for c in a.children]
+    # exec.apply's seconds do not contain the inner plan's run ...
+    assert st["exec.apply"][0] == \
+        sum(a.duration_ns for a in applies) - inner[0].duration_ns
+    # ... and the inner's own do not contain its aggregate's merge
+    nested = [c for c in inner[0].children if c.tid == inner[0].tid]
+    assert {c.name for c in nested} >= {"exec.agg"}
+    assert st["exec.apply.inner"][0] == \
+        inner[0].duration_ns - sum(c.duration_ns for c in nested)
+    assert st["exec.apply.inner"][0] < inner[0].duration_ns
+
+
+def test_no_root_executor_span_is_open_across_a_pull(q18_session):
+    """A child's chunk is pulled with the operator's span closed, so a
+    reader's fan-out (copr.*) and the operators below hang beside an
+    exec.* span, never under it; what an exec.* span holds is the device
+    work it started itself, and exec.apply its inner."""
+    root, _moved = _traced_q18(q18_session)
+    own_children = {"sched.slot", "dispatch", "finalize", "join.partition",
+                    "host.fallback"}
+    for sp in _walk(root, []):
+        if sp.name in ("exec.join", "exec.agg", "exec.topn"):
+            assert {c.name for c in sp.children} <= own_children, sp.name
+        elif sp.name == "exec.apply":
+            assert {c.name for c in sp.children} <= {"exec.apply.inner"}
+
+
+def test_abandoned_operators_leave_no_span_open(q18_session):
+    """LIMIT stops pulling with joins mid-stream: every exec.* span was
+    already closed at the yield, and the next statement's tree is whole."""
+    s = q18_session
+    trace.reset_for_tests()
+    with config.session_overlay({"tidb_tpu_trace_sample": 1}):
+        rows = s.query("SELECT o_orderkey, l_quantity FROM orders, lineitem "
+                       "WHERE o_orderkey = l_orderkey LIMIT 3").rows
+        assert len(rows) == 3
+        s.query("SELECT COUNT(*) FROM customer")
+    recs = trace.ring_records()
+    assert len(recs) == 2
+    for rec in recs:
+        assert trace.validate(rec["root"]) == []
+    assert "exec.join" in {sp.name for sp in _walk(recs[0]["root"], [])}
+    assert trace.current_root() is None
+
+
+def test_drive_closes_the_body_inside_the_span():
+    """A consumer that stops early closes `drive` at its yield; the
+    body's own clean-up (its `finally`) must run then, not whenever the
+    collector gets to it, and under the operator's span."""
+    from tidb_tpu.executor import _OwnSpan
+    events = []
+
+    class Cm:
+        def __exit__(self, *exc):
+            events.append("close")
+
+    def open_span():
+        events.append("open")
+        return Cm()
+
+    def body():
+        try:
+            yield 1
+            yield 2
+        finally:
+            events.append("cleanup")
+
+    own = _OwnSpan(open_span)
+    gen = own.drive(body())
+    assert next(gen) == 1
+    assert events == ["open", "close"]      # closed across the yield
+    gen.close()
+    assert events == ["open", "close", "open", "cleanup", "close"]

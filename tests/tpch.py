@@ -333,3 +333,99 @@ def truth_q12(d: TpchData):
         key = STATUSES[d.l_linestatus[i]]
         out[key] = out.get(key, 0) + 1
     return sorted((k, v) for k, v in out.items())
+
+
+# -- Q18 (large volume customer): tables of its own -------------------------
+# The tables above carry neither c_name nor o_totalprice, so Q18 gets a
+# database of its own: the columns it reads, with more orders than the
+# group-by's dense branch holds slots (ops/hashagg._DENSE_SLOTS).
+
+class Q18Data:
+    """Deterministic for (sizes, seed). An order's line count is Poisson
+    around 4, so a few orders pass 300 units; o_totalprice ties in pairs
+    (the second sort key, o_orderdate, decides them) and a pair that
+    would tie on the date too is one cent apart."""
+
+    def __init__(self, customers=150, orders=3000, lineitems=12000, seed=42):
+        rng = np.random.default_rng(seed)
+        self.c_custkey = np.arange(1, customers + 1)
+        self.o_orderkey = np.arange(1, orders + 1) * 4      # sparse keys
+        self.o_custkey = rng.integers(1, customers + 1, orders)
+        self.o_orderdate = rng.integers(0, 2405, orders)
+        rank = rng.permutation(orders)
+        self.o_totalprice = 1_000_000 + (rank // 2) * 137   # cents
+        by_rank = np.argsort(rank)
+        for a, b in zip(by_rank[0::2], by_rank[1::2]):
+            if self.o_orderdate[a] == self.o_orderdate[b]:
+                self.o_totalprice[b] += 1
+        self.l_order = rng.integers(0, orders, lineitems)   # position
+        self.l_orderkey = self.o_orderkey[self.l_order]
+        self.l_quantity = rng.integers(1, 51, lineitems)
+
+
+Q18_DDL = """
+CREATE TABLE customer (c_custkey BIGINT PRIMARY KEY, c_name VARCHAR(25));
+CREATE TABLE orders (o_orderkey BIGINT PRIMARY KEY, o_custkey BIGINT,
+                     o_totalprice DECIMAL(15,2), o_orderdate DATE);
+CREATE TABLE lineitem (l_id BIGINT PRIMARY KEY, l_orderkey BIGINT,
+                       l_quantity DECIMAL(15,2));
+"""
+
+
+def _cents(v) -> str:
+    return f"{int(v) // 100}.{int(v) % 100:02d}"
+
+
+def load_q18(session, data: Q18Data, batch=1000):
+    for stmt in Q18_DDL.strip().split(";"):
+        if stmt.strip():
+            session.execute(stmt)
+
+    def ins(table, rows):
+        rows = ["(" + ",".join(r) + ")" for r in rows]
+        for lo in range(0, len(rows), batch):
+            session.execute(f"INSERT INTO {table} VALUES "
+                            f"{','.join(rows[lo:lo + batch])}")
+
+    ins("customer", [(str(k), f"'Customer#{k:09d}'")
+                     for k in data.c_custkey])
+    ins("orders", [(str(k), str(c), _cents(p), f"'{_d(d)}'")
+                   for k, c, p, d in zip(data.o_orderkey, data.o_custkey,
+                                         data.o_totalprice,
+                                         data.o_orderdate)])
+    ins("lineitem", [(str(i), str(k), f"{q}.00")
+                     for i, (k, q) in enumerate(zip(data.l_orderkey,
+                                                    data.l_quantity))])
+
+
+# clause 2.4.18's text with LIMIT 100 for "the first 100 rows";
+# {quantity} is the substitution parameter (validation value 300)
+Q18 = """
+SELECT c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+       SUM(l_quantity)
+FROM customer, orders, lineitem
+WHERE o_orderkey IN (
+        SELECT l_orderkey FROM lineitem
+        GROUP BY l_orderkey HAVING SUM(l_quantity) > {quantity})
+  AND c_custkey = o_custkey
+  AND o_orderkey = l_orderkey
+GROUP BY c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+ORDER BY o_totalprice DESC, o_orderdate
+LIMIT 100
+"""
+
+
+def truth_q18(d: Q18Data, quantity: int):
+    """-> (rows in order, how many orders qualified before the LIMIT);
+    rows are (c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+    in cents, sum of l_quantity in whole units), python ints."""
+    qty = {}
+    for pos, q in zip(d.l_order, d.l_quantity):
+        qty[int(pos)] = qty.get(int(pos), 0) + int(q)
+    large = [pos for pos, q in qty.items() if q > quantity]
+    large.sort(key=lambda pos: (-int(d.o_totalprice[pos]),
+                                int(d.o_orderdate[pos])))
+    rows = [(f"Customer#{int(d.o_custkey[pos]):09d}", int(d.o_custkey[pos]),
+             int(d.o_orderkey[pos]), _d(d.o_orderdate[pos]),
+             int(d.o_totalprice[pos]), qty[pos]) for pos in large[:100]]
+    return rows, len(large)

@@ -14,8 +14,8 @@ import time
 
 import numpy as np
 
-from tidb_tpu import (config, kv, memtrack, profiler, runtime_stats,
-                      sched, tablecodec)
+from tidb_tpu import (config, kv, memtrack, metrics, profiler,
+                      runtime_stats, sched, tablecodec, trace)
 from tidb_tpu.chunk import Chunk, Column
 from tidb_tpu.expression import AggDesc, AggFunc, Expression
 from tidb_tpu.kv import CopRequest, KVRange, ReqType
@@ -96,6 +96,68 @@ class Executor:
 
     def close(self):
         pass
+
+
+class _OwnSpan:
+    """One root executor's own host work under spans of one name: open
+    while the operator's generator body runs, closed while it waits for
+    a child's chunk (`pull`) and while its consumer holds a chunk it
+    yielded (`drive`). The executors are generators: a span held open
+    across `for chunk in child.chunks(ctx)` would take the child's time
+    (a TableReader's wait for frames) into this operator's name, and one
+    open across a `yield` would interleave with the consumer's spans. So
+    no span is open wherever the generator is suspended, and what the
+    body dispatches to the device keeps its own child spans (sched.slot
+    / dispatch / finalize / join.partition): the self time left under
+    the name is the host's. A span a pull or a yield, never a row.
+    `open_span` is the call site's `lambda: trace.span("<literal>")`
+    (lint rule trace-names)."""
+
+    __slots__ = ("_open", "_cm")
+
+    def __init__(self, open_span):
+        self._open = open_span
+        self._cm = None
+
+    def resume(self) -> None:
+        self._cm = self._open()
+
+    def suspend(self) -> None:
+        cm, self._cm = self._cm, None
+        if cm is not None:
+            cm.__exit__(None, None, None)
+
+    def pull(self, it):
+        """A child's stream, each pull of it outside the span."""
+        it = iter(it)
+        while True:
+            self.suspend()
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+            finally:
+                self.resume()
+            yield item
+
+    def drive(self, body):
+        """The operator's generator `body`, run inside the span and
+        handing each chunk on outside it."""
+        self.resume()
+        try:
+            for out in body:
+                self.suspend()
+                try:
+                    yield out
+                finally:
+                    self.resume()
+        finally:
+            # a consumer that stops early closes this generator at the
+            # yield: the body's own clean-up runs now, inside the span
+            try:
+                body.close()
+            finally:
+                self.suspend()
 
 
 def build_executor(plan: ph.PhysPlan) -> Executor:
@@ -408,6 +470,10 @@ class FinalAggExec(Executor):
         self.reader = build_executor(plan.children[0])
 
     def chunks(self, ctx):
+        own = _OwnSpan(lambda: trace.span("exec.agg"))
+        yield from own.drive(self._merged(ctx, own))
+
+    def _merged(self, ctx, own):
         # partials arrive pre-grouped: key fts are the schema's leading
         # num_group_cols columns
         agg = HashAggregator(
@@ -416,7 +482,7 @@ class FinalAggExec(Executor):
              self.plan.schema.cols[:self.plan.num_group_cols]])
         tracked = 0
         try:
-            for gr in self.reader.partials(ctx):
+            for gr in own.pull(self.reader.partials(ctx)):
                 agg.update(gr)
                 tracked = memtrack.track_to(self.plan,
                                             agg.approx_bytes(), tracked)
@@ -424,6 +490,7 @@ class FinalAggExec(Executor):
             if not self.plan.num_group_cols and not results:
                 results = [((), [_empty_agg_value(a)
                                  for a in self.plan.aggs])]
+            _note_final_groups(len(results))
             yield _agg_results_to_chunk(self.schema,
                                         self.plan.num_group_cols,
                                         self.plan.aggs, results)
@@ -433,6 +500,14 @@ class FinalAggExec(Executor):
 
 def _empty_agg_value(a: AggDesc):
     return 0 if a.fn == AggFunc.COUNT else None
+
+
+def _note_final_groups(n: int) -> None:
+    """The groups a root aggregate is about to emit: the `groups` tag
+    of the exec.agg span open around the caller, and the counter that
+    divides that span's self time."""
+    trace.annotate(groups=n)
+    metrics.counter(metrics.AGG_FINAL_GROUPS, inc=n)
 
 
 class HashAggExec(Executor):
@@ -448,6 +523,10 @@ class HashAggExec(Executor):
         self._kernel = getattr(plan, "_root_kernel", None)
 
     def chunks(self, ctx):
+        own = _OwnSpan(lambda: trace.span("exec.agg"))
+        yield from own.drive(self._aggregated(ctx, own))
+
+    def _aggregated(self, ctx, own):
         agg = HashAggregator(self.plan.aggs, self.plan.group_exprs)
         distinct_ok = all(not a.distinct for a in self.plan.aggs)
         sc_rows = config.superchunk_rows()
@@ -460,9 +539,10 @@ class HashAggExec(Executor):
                 # partial agg — the joined intermediate never
                 # materializes in HBM or on the host
                 frag = self._fragment_kernel()
-                source = self._fused_partials(ctx, frag) \
+                source = self._fused_partials(ctx, frag, own) \
                     if frag is not None else \
-                    self._superchunk_partials(self.child.chunks(ctx))
+                    self._superchunk_partials(
+                        own.pull(self.child.chunks(ctx)))
                 # superchunk pipeline: child chunks coalesce into big
                 # padded batches and flow through the dispatch-ahead
                 # device queue; one partial-agg dispatch per superchunk
@@ -471,7 +551,7 @@ class HashAggExec(Executor):
                     tracked = memtrack.track_to(
                         self.plan, agg.approx_bytes(), tracked)
             else:
-                for chunk in self.child.chunks(ctx):
+                for chunk in own.pull(self.child.chunks(ctx)):
                     if chunk.num_rows == 0:
                         continue
                     gr = None
@@ -490,6 +570,7 @@ class HashAggExec(Executor):
                 results = [((), [_empty_agg_value(a)
                                  for a in self.plan.aggs])]
             num_g = len(self.plan.group_exprs)
+            _note_final_groups(len(results))
             yield _agg_results_to_chunk(self.schema, num_g,
                                         self.plan.aggs, results)
         finally:
@@ -531,7 +612,7 @@ class HashAggExec(Executor):
         except (DeviceRejectError, NotImplementedError, ValueError):
             return None
 
-    def _fused_partials(self, ctx, fk):
+    def _fused_partials(self, ctx, fk, own):
         """Partial GroupResults from the fused probe->agg fragment: the
         build side uploads once (used columns + key lanes), probe
         superchunks stream through the dispatch-ahead pipeline, and
@@ -539,13 +620,15 @@ class HashAggExec(Executor):
         miss escalates the fragment kernel once (later batches inherit
         it); a miss that survives — or a collision — falls back to the
         decoded per-batch path (host pair match + gather + host agg),
-        counted on tidb_tpu_device_fallback_total."""
+        counted on tidb_tpu_device_fallback_total. The fragment is this
+        operator's work, join included (`own`, its exec.agg span: the
+        join's two inputs are pulled outside it)."""
         plan = self.plan
         join = self.child
         jplan = join.plan
         nl = len(jplan.children[0].schema)
         width = nl + len(jplan.children[1].schema)
-        build = Chunk.concat_all(list(join.right.chunks(ctx)))
+        build = Chunk.concat_all(list(own.pull(join.right.chunks(ctx))))
         nb = build.num_rows if build is not None else 0
         if nb == 0:
             return      # inner join over an empty build: no input rows
@@ -565,7 +648,8 @@ class HashAggExec(Executor):
             # keys, hashes and hot set just computed ride along.
             try:
                 yield from self._superchunk_partials(join._probe_join(
-                    ctx, build, nb, prepared=(enc, bk, raw_bk, hot, h)))
+                    ctx, build, nb, own,
+                    prepared=(enc, bk, raw_bk, hot, h)))
             finally:
                 memtrack.release(plan, host=tracked)
             return
@@ -650,7 +734,7 @@ class HashAggExec(Executor):
                     plan, time.perf_counter_ns() - t0)
 
         sc_iter = op_runtime.superchunk_batches(
-            join.left.chunks(ctx), config.superchunk_rows(),
+            own.pull(join.left.chunks(ctx)), config.superchunk_rows(),
             tracker=mt_node)
         try:
             yield from op_runtime.pipeline_map(
@@ -1158,11 +1242,15 @@ class TopNExec(Executor):
         self.child = build_executor(plan.children[0])
 
     def chunks(self, ctx):
+        own = _OwnSpan(lambda: trace.span("exec.topn"))
+        yield from own.drive(self._best(ctx, own))
+
+    def _best(self, ctx, own):
         n = self.plan.count + self.plan.offset
         best = None
         tracked = 0
         try:
-            for chunk in self.child.chunks(ctx):
+            for chunk in own.pull(self.child.chunks(ctx)):
                 cand = chunk if best is None else best.concat(chunk)
                 if cand.num_rows > 0:
                     best = cand.take(_sort_order(self.plan.by, cand)[:n])
@@ -1293,23 +1381,29 @@ class HashJoinExec(Executor):
         return kernel
 
     def chunks(self, ctx):
-        plan = self.plan
-        if not plan.left_keys:
+        if not self.plan.left_keys:
             yield from self._cross_join(ctx)
             return
-        build = Chunk.concat_all(list(self.right.chunks(ctx)))
+        own = _OwnSpan(lambda: trace.span("exec.join"))
+        yield from own.drive(self._joined(ctx, own))
+
+    def _joined(self, ctx, own):
+        build = Chunk.concat_all(list(own.pull(self.right.chunks(ctx))))
         nb = build.num_rows if build is not None else 0
         # the materialized build side is the join's dominant host buffer:
         # hold it on this node's ledger for the whole probe phase
         tracked = memtrack.track_to(
             self.plan, memtrack.chunk_bytes(build) if nb else 0)
         try:
-            yield from self._probe_join(ctx, build, nb)
+            yield from self._probe_join(ctx, build, nb, own)
         finally:
             memtrack.release(self.plan, host=tracked)
 
-    def _probe_join(self, ctx, build, nb: int, prepared=None):
-        """`prepared` = (enc, bk, raw_bk, hot, h) from a caller that
+    def _probe_join(self, ctx, build, nb: int, own, prepared=None):
+        """`own` is the span of the operator driving this probe (this
+        join's exec.join, or the exec.agg of a fused aggregate standing
+        aside): the probe side is pulled outside it.
+        `prepared` = (enc, bk, raw_bk, hot, h) from a caller that
         already encoded the build keys and ran the hybrid-engage scan
         (the fused fragment's stand-aside path) — O(nb) key evaluation
         and heavy-hitter hashing must not run twice on exactly the
@@ -1327,7 +1421,7 @@ class HashJoinExec(Executor):
                 ci=[e.ft.is_ci for e in plan.right_keys]) if nb else None
             pre_hot = pre_h = None
         matched_build = np.zeros(nb, dtype=bool)
-        probe_iter = self.left.chunks(ctx)
+        probe_iter = own.pull(self.left.chunks(ctx))
         mesh_kernel = self._mesh_kernel(nb)
         if mesh_kernel is not None:
             # each shuffle-join call is one all_to_all repartition of both
@@ -2484,12 +2578,19 @@ class ApplyExec(Executor):
         self.child = build_executor(plan.children[0])
 
     def chunks(self, ctx: ExecContext):
+        # the predicate over the outer chunks is exec.apply; the inner
+        # plan's runs are its child exec.apply.inner (one span around an
+        # uncorrelated inner's single run, or around one outer chunk's
+        # row-by-row runs), the inner executors' own spans nesting there
+        own = _OwnSpan(lambda: trace.span("exec.apply"))
+        body = self._scalar_chunks if self.plan.mode == "scalar" \
+            else self._filtered
+        yield from own.drive(body(ctx, own))
+
+    def _filtered(self, ctx, own):
         plan = self.plan
-        if plan.mode == "scalar":
-            yield from self._scalar_chunks(ctx)
-            return
         cache = None            # uncorrelated: (vals, valid, has_rows)
-        for chunk in self.child.chunks(ctx):
+        for chunk in own.pull(self.child.chunks(ctx)):
             n = chunk.num_rows
             if n == 0:
                 continue
@@ -2499,35 +2600,38 @@ class ApplyExec(Executor):
                 left = (np.asarray(ld), np.asarray(lv))
             if not plan.corr:
                 if cache is None:
-                    cache = self._run_inner(ctx,
-                                            first_only=plan.mode == "exists")
+                    with trace.span("exec.apply.inner"):
+                        cache = self._run_inner(
+                            ctx, first_only=plan.mode == "exists")
                 keep = self._vector_predicate(left, n, *cache)
             else:
                 keep = np.zeros(n, dtype=bool)
-                for i in range(n):
-                    self._bind_corr(chunk, i)
-                    vals, valid, has = self._run_inner(
-                        ctx, first_only=plan.mode == "exists")
-                    row_left = None if left is None else \
-                        (left[0][i:i + 1], left[1][i:i + 1])
-                    keep[i] = bool(self._vector_predicate(
-                        row_left, 1, vals, valid, has)[0])
+                with trace.span("exec.apply.inner", rows=n):
+                    for i in range(n):
+                        self._bind_corr(chunk, i)
+                        vals, valid, has = self._run_inner(
+                            ctx, first_only=plan.mode == "exists")
+                        row_left = None if left is None else \
+                            (left[0][i:i + 1], left[1][i:i + 1])
+                        keep[i] = bool(self._vector_predicate(
+                            row_left, 1, vals, valid, has)[0])
             yield chunk.filter(keep)
 
-    def _scalar_chunks(self, ctx):
+    def _scalar_chunks(self, ctx, own):
         """mode="scalar": append the inner's single value as a new
         column (the planner's lifted scalar subquery)."""
         plan = self.plan
         ft = plan.schema.cols[-1].ft
         dtype = np_dtype_for(ft.tp, ft.flen)
         cache = None
-        for chunk in self.child.chunks(ctx):
+        for chunk in own.pull(self.child.chunks(ctx)):
             n = chunk.num_rows
             if n == 0:
                 continue
             if not plan.corr:
                 if cache is None:
-                    cache = self._scalar_value(ctx)
+                    with trace.span("exec.apply.inner"):
+                        cache = self._scalar_value(ctx)
                 val, ok = cache
                 data = np.full(n, val if ok else
                                ("" if dtype == np.dtype(object) else 0),
@@ -2539,12 +2643,13 @@ class ApplyExec(Executor):
                     if dtype != np.dtype(object) else \
                     np.full(n, "", dtype=object)
                 valid = np.zeros(n, dtype=bool)
-                for i in range(n):
-                    self._bind_corr(chunk, i)
-                    val, ok = self._scalar_value(ctx)
-                    if ok:
-                        data[i] = val
-                        valid[i] = True
+                with trace.span("exec.apply.inner", rows=n):
+                    for i in range(n):
+                        self._bind_corr(chunk, i)
+                        val, ok = self._scalar_value(ctx)
+                        if ok:
+                            data[i] = val
+                            valid[i] = True
             yield Chunk(chunk.columns + [Column(ft, data, valid)])
 
     def _scalar_value(self, ctx):

@@ -151,6 +151,7 @@ def _emit_agg(plan, agg, executor_mod):
     if not plan.group_exprs and not results:
         results = [((), [executor_mod._empty_agg_value(a)
                          for a in plan.aggs])]
+    executor_mod._note_final_groups(len(results))
     return executor_mod._agg_results_to_chunk(
         plan.schema, plan.num_group_cols, plan.aggs, results)
 
@@ -178,9 +179,18 @@ class _MeshExecBase:
         self.plan = plan
         self.schema = plan.schema
 
-    def _fallback(self, ctx):
+    def chunks(self, ctx):
+        # the plane's aggregates are root executors like the one-chip
+        # ones (executor._OwnSpan): the host's part of the statement
+        # under exec.agg, every pull of an operand scan or of the
+        # fallback subtree outside it
+        from tidb_tpu.executor import _OwnSpan
+        own = _OwnSpan(lambda: trace.span("exec.agg"))
+        yield from own.drive(self._chunks(ctx, own))
+
+    def _fallback(self, ctx, own):
         from tidb_tpu.executor import build_executor
-        return build_executor(self.plan.fallback).chunks(ctx)
+        return own.pull(build_executor(self.plan.fallback).chunks(ctx))
 
     @staticmethod
     def _cached_scan(reader, ctx):
@@ -389,17 +399,17 @@ class _MeshExecBase:
 class MeshAggExec(_MeshExecBase):
     """Group-by aggregation on the device plane (Q1 shape)."""
 
-    def chunks(self, ctx):
+    def _chunks(self, ctx, own):
         import tidb_tpu.executor as ex
 
         mesh = devplane.active_mesh()
         if mesh is None:
-            yield from self._fallback(ctx)
+            yield from self._fallback(ctx, own)
             return
         plan = self.plan
         schema = plan.children[0].schema
         reader = ex.build_executor(plan.children[0])
-        it = self._cached_scan(reader, ctx)
+        it = own.pull(self._cached_scan(reader, ctx))
         limit = sysconf.stream_rows()
         parts, total, exhausted = self._buffer_probe(it, limit)
 
@@ -458,10 +468,10 @@ class MeshAggExec(_MeshExecBase):
             except failpoint.DeviceFaultError:
                 sched.device_health().note_fault()
                 runtime_stats.note_fallback(plan, "fault")
-                yield from self._fallback(ctx)
+                yield from self._fallback(ctx, own)
                 return
             if gr is None:
-                yield from self._fallback(ctx)
+                yield from self._fallback(ctx, own)
                 return
             # the whole table went down as ONE maximally-coalesced batch
             runtime_stats.note_superchunk(
@@ -473,12 +483,12 @@ class MeshAggExec(_MeshExecBase):
 class MeshLookupAggExec(_MeshExecBase):
     """Star join + aggregation on the device plane (Q3/Q5 shape)."""
 
-    def chunks(self, ctx):
+    def _chunks(self, ctx, own):
         import tidb_tpu.executor as ex
 
         mesh = devplane.active_mesh()
         if mesh is None:
-            yield from self._fallback(ctx)
+            yield from self._fallback(ctx, own)
             return
         plan = self.plan
         try:
@@ -486,8 +496,9 @@ class MeshLookupAggExec(_MeshExecBase):
             for lk in plan.lookups:
                 bexec = ex.build_executor(lk.build_plan)
                 bchunk = _concat_chunks_cached(lk, "_chunk_cache",
-                                               list(self._cached_scan(
-                                                   bexec, ctx)),
+                                               list(own.pull(
+                                                   self._cached_scan(
+                                                       bexec, ctx))),
                                                lk.build_plan.schema)
                 specs.append(LookupSpec(
                     key_exprs=lk.key_exprs, build_chunk=bchunk,
@@ -497,7 +508,7 @@ class MeshLookupAggExec(_MeshExecBase):
                       for d, sp in zip(plan.lookups, specs)]
         except BuildError:
             # non-unique / NULL-heavy dimension keys: host join fallback
-            yield from self._fallback(ctx)
+            yield from self._fallback(ctx, own)
             return
 
         def make(capacity):
@@ -516,7 +527,7 @@ class MeshLookupAggExec(_MeshExecBase):
             return kernel
 
         reader = ex.build_executor(plan.children[0])
-        it = self._cached_scan(reader, ctx)
+        it = own.pull(self._cached_scan(reader, ctx))
         limit = sysconf.stream_rows()
         parts, total, exhausted = self._buffer_probe(it, limit)
 
@@ -570,10 +581,10 @@ class MeshLookupAggExec(_MeshExecBase):
             except failpoint.DeviceFaultError:
                 sched.device_health().note_fault()
                 runtime_stats.note_fallback(plan, "fault")
-                yield from self._fallback(ctx)
+                yield from self._fallback(ctx, own)
                 return
             if gr is None:
-                yield from self._fallback(ctx)
+                yield from self._fallback(ctx, own)
                 return
             runtime_stats.note_superchunk(
                 plan, probe.num_rows, bucket_size(max(probe.num_rows, 1)),

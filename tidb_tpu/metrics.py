@@ -38,7 +38,7 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "DEVICE_UTILIZATION", "HBM_OCCUPANCY", "CHIP_UTILIZATION",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
            "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
-           "DECODE_ROWS",
+           "DECODE_ROWS", "AGG_FINAL_GROUPS",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
            "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS"]
 
@@ -358,6 +358,11 @@ AGG_DISPATCHES = "tidb_tpu_agg_dispatch_total"
 # decoder (an index layout, a JSON or DURATION column, a wide-decimal
 # table, a row the walker declined, no compiler)
 DECODE_ROWS = "tidb_tpu_decode_rows_total"
+# groups the root's aggregates emitted (executor FinalAggExec: the merge
+# of the coprocessor's partials; HashAggExec: the complete aggregation
+# over child chunks), counted once a statement where span exec.agg is:
+# the divisor of that span's self time
+AGG_FINAL_GROUPS = "tidb_tpu_agg_final_groups_total"
 # the statement span trees as counters (trace.py folds every ended
 # root's tree here, span_totals above): self time — a span's duration
 # less what its same-thread children cover, so thread-seconds that
@@ -503,6 +508,9 @@ _HELP = {
     DECODE_ROWS:
         "Rows a coprocessor scan decoded, by the decoder that built "
         "the chunk (native|python).",
+    AGG_FINAL_GROUPS:
+        "Groups emitted by the root executors' aggregates "
+        "(FinalAggExec, HashAggExec).",
     SPAN_SELF_SECONDS:
         "Statement span self time (thread-seconds), by span name.",
     SPAN_COUNT: "Statement spans ended, by span name.",

@@ -664,12 +664,14 @@ def partitioned_agg(chunk, filter_expr, group_exprs, aggs, plan,
 
 
 def agg_retry(chunk, filter_expr, group_exprs, aggs, plan,
-              err) -> GroupResult:
+              err, keep=None) -> GroupResult:
     """Full recovery chain after a device agg miss `err`: one whole-
     chunk escalated retry on capacity (cheap — the common medium-
     cardinality case needs exactly one bigger table), then the radix-
     partitioned per-partition path. Never raises the miss onward: the
-    worst case is per-partition host aggregation."""
+    worst case is per-partition host aggregation. `keep(kernel)` hands
+    the caller the escalated kernel once it has served the chunk, so
+    that its next dispatch starts at the table that fitted."""
     reason = "collision" if isinstance(err, CollisionError) else "capacity"
     if isinstance(err, CapacityError):
         cap = escalated_capacity(getattr(err, "needed", 0))
@@ -679,7 +681,10 @@ def agg_retry(chunk, filter_expr, group_exprs, aggs, plan,
                                capacity=cap)
                 with sched.device_slot(), memtrack.device_scope(
                         plan, k.dispatch_nbytes(chunk)):
-                    return runtime_stats.device_call(plan, k, chunk)
+                    gr = runtime_stats.device_call(plan, k, chunk)
+                if keep is not None:
+                    keep(k)
+                return gr
             except (CapacityError, CollisionError) as e2:
                 reason = "collision" if isinstance(e2, CollisionError) \
                     else "capacity"

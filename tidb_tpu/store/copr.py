@@ -85,6 +85,17 @@ def _agg_kernels(plan: CopPlan):
     return k
 
 
+def _keep_escalated(plan: CopPlan, k) -> None:
+    """The kernel a capacity miss escalated to becomes the plan's (the
+    executors' rule, HashAggExec._set_kernel): the plan cache shares
+    plans across executions, so the next region task and the next
+    execution start at the table that fitted, on the resident block,
+    and do not first run the smaller program to the same miss."""
+    with _kernel_lock:
+        if k.capacity > plan._kernel.capacity:
+            plan._kernel = k
+
+
 def scan_batch(storage, cur: bytes, e: bytes, limit: int,
                req: CopRequest):
     """One `storage.engine.scan` call (MVCC iteration over at most
@@ -397,7 +408,7 @@ def _exec_cop_plan(plan: CopPlan, chunk, sources: int,
                     runtime_stats.note_mode(plan, "hybrid")
                     return CopResponse(chunk=agg_retry(
                         chunk, plan.filter, plan.group_exprs, plan.aggs,
-                        plan, e))
+                        plan, e, keep=lambda k2: _keep_escalated(plan, k2)))
                 reason = "collision" if isinstance(e, CollisionError) \
                     else "capacity"
                 runtime_stats.note_fallback(plan, reason)

@@ -103,6 +103,17 @@ SPAN_NAMES = {
     "delta.merge": "delta-store merge into new base blocks",
     # hybrid join/agg partition phases (ops/hybrid.py)
     "join.partition": "one radix partition's device chain",
+    # the root executors' own host work on the session thread
+    # (executor/__init__.py:_OwnSpan): open while the operator's
+    # generator body runs, closed while it waits for a child's chunk and
+    # while its consumer holds one it yielded, so a reader's wait for
+    # frames stays out of them; the device work they start keeps its own
+    # child spans (sched.slot / dispatch / finalize / join.partition)
+    "exec.join": "HashJoinExec: build concat + key encode, probe emit",
+    "exec.apply": "ApplyExec: the subquery predicate over outer chunks",
+    "exec.apply.inner": "ApplyExec: one run of the inner plan",
+    "exec.agg": "FinalAggExec / HashAggExec: the host's merge of partials",
+    "exec.topn": "TopNExec: sort + cut of each chunk against the best",
     # cross-process storage roots (store/remote.py)
     "storage:coprocessor_stream": "storage-side root of one COP stream",
     # cluster observability fan-out (util/statusclient.fetch_all): one
