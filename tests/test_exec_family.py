@@ -119,7 +119,7 @@ class TestStreamAgg:
 
 class TestMergeJoin:
     def _join(self, sess, jt="inner"):
-        left = _reader(sess, "SELECT id, g, v FROM t")
+        left = _reader(sess, "SELECT id, g, v, s FROM t")
         right = _reader(sess, "SELECT id, w FROM u")
         lk = [ColumnRef(0, left.schema.cols[0].ft)]
         rk = [ColumnRef(0, right.schema.cols[0].ft)]
@@ -159,7 +159,7 @@ class TestMergeJoin:
 
 class TestIndexJoin:
     def _join(self, sess, jt="inner"):
-        outer = _reader(sess, "SELECT id, g, v FROM t")
+        outer = _reader(sess, "SELECT id, g, v, s FROM t")
         inner = _reader(sess, "SELECT id, w FROM u")
         lk = [ColumnRef(1, outer.schema.cols[1].ft)]    # t.g
         rk = [ColumnRef(0, inner.schema.cols[0].ft)]    # u.id (pk handle)

@@ -398,6 +398,67 @@ class TestParity:
             _assert_chunks_equal(
                 got, _python_chunk(info, cols, kvrows, handle_col))
 
+    @pytest.mark.parametrize("seed", range(8))
+    def test_pruned_subsets_of_full_width_rows(self, seed, monkeypatch):
+        """What column pruning asks of the decoder: any subset of
+        lineitem's 16 columns, in any order, strings in or out of it,
+        with an explicit NULL, and rows written before two ADD COLUMNs
+        (an INT with a default, a VARCHAR with one): the C++ chunk equals
+        the Python decoder's, and an object lane is built for the string
+        columns asked for and for no other."""
+        import tidb_tpu.table as table_mod
+        layout = [(_tpch_ft(name, width), None, True)
+                  for name, width in _TPCH["lineitem"].items()]
+        layout += [(new_int_field(), 42, False),
+                   (new_string_field(8), "dflt", False)]
+        info = _mk_table(layout)
+        added = {c.id for c in info.columns[-2:]}
+        rng = random.Random(1000 + seed)
+        rows = []
+        for i in range(60):
+            r = {}
+            for ci in info.columns:
+                if ci.id in added and i < 30:
+                    continue            # written before ADD COLUMN
+                if rng.random() < 0.05 and ci.id not in added:
+                    r[ci.id] = None
+                elif ci.ft.tp == TypeCode.NEWDECIMAL:
+                    r[ci.id] = (2, rng.randint(-10**12, 10**12))
+                elif ci.ft.tp == TypeCode.VARCHAR:
+                    r[ci.id] = "".join(
+                        rng.choice("abc de,.\u00e9" if seed % 2 else "abc de,.")
+                        for _ in range(rng.randint(0, ci.ft.flen)))
+                else:
+                    r[ci.id] = rng.randint(0, 2**40)
+            rows.append(r)
+        rows[7][info.columns[0].id] = None          # a NULL for certain
+        kvrows = _encode_rows(info, rows)
+        strings = {c.id for c in info.columns
+                   if c.ft.tp == TypeCode.VARCHAR}
+        lanes = []
+        orig = table_mod._strings_from_spans
+        monkeypatch.setattr(
+            table_mod, "_strings_from_spans",
+            lambda *a, **k: lanes.append(1) or orig(*a, **k))
+        for _trial in range(6):
+            cols = rng.sample(info.columns,
+                              rng.randint(1, len(info.columns)))
+            if _trial == 0:             # no string asked for at all
+                cols = [c for c in cols if c.id not in strings] \
+                    or [info.columns[0]]
+            handle_col = rng.choice([None, 0, len(cols)])
+            del lanes[:]
+            got = _kvrows_to_chunk_native(cols, kvrows, handle_col)
+            assert got is not None
+            assert len(lanes) == sum(c.id in strings for c in cols)
+            want = _python_chunk(info, cols, kvrows, handle_col)
+            _assert_chunks_equal(got, want)
+            for c, lane in zip(cols, [col for j, col in
+                                      enumerate(got.columns)
+                                      if j != handle_col]):
+                if c.id in added:
+                    assert lane.data[:30].tolist() == [c.default] * 30
+
 
 class TestBatchPrimitives:
     def test_encode_decode_int_batch(self):

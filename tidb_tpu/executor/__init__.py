@@ -204,9 +204,17 @@ class TableReaderExec(Executor):
         from tidb_tpu import codec
         return [KVRange(lo, codec.prefix_next(lo))]
 
+    def _count_columns(self):
+        cop = self.plan.cop
+        metrics.counter(metrics.READER_COLUMNS, {"kind": "scanned"},
+                        len(cop.cols))
+        metrics.counter(metrics.READER_COLUMNS, {"kind": "table"},
+                        len(cop.table.public_columns()))
+
     def partials(self, ctx: ExecContext):
         """Agg mode: yields GroupResults."""
         cop = self.plan.cop
+        self._count_columns()
         if _txn_is_dirty(ctx, cop.table.id):
             for chunk in self._dirty_chunks(ctx):
                 yield exec_cop_plan(cop, chunk).chunk
@@ -220,6 +228,7 @@ class TableReaderExec(Executor):
     def chunks(self, ctx: ExecContext):
         cop = self.plan.cop
         assert not cop.is_agg
+        self._count_columns()
         if _txn_is_dirty(ctx, cop.table.id):
             for chunk in self._dirty_chunks(ctx):
                 yield exec_cop_plan(cop, chunk).chunk

@@ -38,7 +38,7 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "DEVICE_UTILIZATION", "HBM_OCCUPANCY", "CHIP_UTILIZATION",
            "COMPILE_CACHE_HITS", "COMPILE_CACHE_MISSES",
            "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
-           "DECODE_ROWS", "AGG_FINAL_GROUPS",
+           "DECODE_ROWS", "AGG_FINAL_GROUPS", "READER_COLUMNS",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
            "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS"]
 
@@ -363,6 +363,10 @@ DECODE_ROWS = "tidb_tpu_decode_rows_total"
 # over child chunks), counted once a statement where span exec.agg is:
 # the divisor of that span's self time
 AGG_FINAL_GROUPS = "tidb_tpu_agg_final_groups_total"
+# how far column pruning narrows a scan: once per table-reader execution
+# in a statement (not per region task), {kind="scanned"} the columns its
+# CopPlan asks for and {kind="table"} the table's public columns
+READER_COLUMNS = "tidb_tpu_reader_columns_total"
 # the statement span trees as counters (trace.py folds every ended
 # root's tree here, span_totals above): self time — a span's duration
 # less what its same-thread children cover, so thread-seconds that
@@ -511,6 +515,9 @@ _HELP = {
     AGG_FINAL_GROUPS:
         "Groups emitted by the root executors' aggregates "
         "(FinalAggExec, HashAggExec).",
+    READER_COLUMNS:
+        "Columns per table-reader execution: those its plan scans "
+        "and those the table has (scanned|table).",
     SPAN_SELF_SECONDS:
         "Statement span self time (thread-seconds), by span name.",
     SPAN_COUNT: "Statement spans ended, by span name.",

@@ -96,7 +96,9 @@ class PhysTableReader(PhysPlan):
     keep_order: bool = False   # handle-ordered delivery (merge join feeds)
 
     def _explain_info(self):
-        parts = [f" table:{self.cop.table.name}"]
+        parts = [f" table:{self.cop.table.name}",
+                 f" cols:{len(self.cop.cols)}"
+                 f"/{len(self.cop.table.public_columns())}"]
         if self.keep_order:
             parts.append(" keep_order")
         if self.cop.filter is not None:

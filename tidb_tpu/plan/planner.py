@@ -7,7 +7,9 @@ split (plan/task.go:116-499). Rules here run during construction:
 
 * predicate pushdown: WHERE/ON conjuncts sink into table readers (split
   into device-safe vs host-only parts), equi-conds become hash-join keys
-* column pruning: readers scan only referenced columns
+* column pruning: a query body's table readers ask for the columns its
+  statement names and no others (_ColumnReads, applied in build_reader
+  before positions are assigned, so nothing above is remapped)
 * aggregation pushdown: single-reader group-by ships as a storage-side
   partial agg (CopPlan.aggs) merged by a root PhysFinalAgg
 * TopN pushdown: ORDER BY + LIMIT over a bare reader pushes the limit
@@ -108,6 +110,9 @@ class Planner:
         self.db = current_db
         self.storage = storage   # membership registry for cluster_* fan-out
         self._handle_refs: set = set()   # multi-table DELETE targets
+        # what the query body being planned reads of its tables (set by
+        # _plan_query); None outside one: DML readers keep whole rows
+        self._reads: _ColumnReads | None = None
         # (level, code, message) notes the session surfaces as SHOW
         # WARNINGS — e.g. a cluster_* fan-out that degraded to partial
         # rows because a member was unreachable
@@ -181,7 +186,8 @@ class Planner:
         if db == "performance_schema":
             return self._build_perfschema(ts)
         _db, info = self._table_info(ts)
-        cols = info.public_columns()
+        cols = info.public_columns() if self._reads is None \
+            else self._reads.of(ts.ref_name, info)
         schema_cols = [
             SchemaCol(c.name.lower(), ts.ref_name.lower(), c.ft, c.id)
             for c in cols]
@@ -906,16 +912,17 @@ class Planner:
                 continue
             if restrict and idx.name.lower() not in restrict:
                 continue
+            # the index's leading columns that the (pruned) reader scans:
+            # a column the statement never names has no conjunct, and
+            # detach_index_conditions stops at the first column without one
             offsets, fts = [], []
-            ok = True
             for cname in idx.columns:
                 o = off_by_name.get(cname.lower())
                 if o is None:
-                    ok = False
                     break
                 offsets.append(o)
                 fts.append(reader.schema.cols[o].ft)
-            if not ok:
+            if not offsets:
                 continue
             path = rg.detach_index_conditions(conj, offsets, fts)
             if path.useful and path.ranges:
@@ -1450,9 +1457,16 @@ class Planner:
     # -- UNION ---------------------------------------------------------------
 
     def _plan_query(self, stmt) -> ph.PhysPlan:
-        """SELECT or UNION — every seam that accepts a query body."""
-        return self.plan_union(stmt) if isinstance(stmt, ast.UnionStmt) \
-            else self.plan_select(stmt)
+        """SELECT or UNION — every seam that accepts a query body. Its
+        readers are pruned to the columns the body names (a derived
+        table's or a UNION branch's own body narrows that again: neither
+        sees its siblings' or its parent's columns)."""
+        outer, self._reads = self._reads, _ColumnReads(stmt)
+        try:
+            return self.plan_union(stmt) if isinstance(stmt, ast.UnionStmt) \
+                else self.plan_select(stmt)
+        finally:
+            self._reads = outer
 
     def plan_union(self, stmt: ast.UnionStmt) -> ph.PhysPlan:
         """UNION as a real operator tree (ref: builder.go UnionExec):
@@ -2512,6 +2526,66 @@ def _iter_nodes(e, stop: tuple = ()):
                     for y in x:
                         if isinstance(y, ast.Node):
                             yield from _iter_nodes(y, stop)
+
+
+class _ColumnReads:
+    """The column-pruning rule: which columns of a table one query body
+    reads, decided from the names the body's AST holds — its nested
+    blocks included, so a correlated subquery's outer references and a
+    derived table's inputs are in — before the reader's schema exists.
+    A column stays if a ColName (or a USING name) could resolve to it:
+    same name, and no qualifier or the reader's own ref name, which is
+    how PlanSchema.find matches. A name that is in two tables keeps
+    both columns, so ambiguity errors and local-before-outer resolution
+    are what they were. Kept whole: the tables a block's `*` / `t.*`
+    expands, and both sides of a NATURAL join, whose keys are the names
+    the two full tables share."""
+
+    # a block's own table factors: not those of a derived table's body
+    # or of a subquery in an ON clause
+    _OWN = (ast.SubqueryTable, ast.ExprNode)
+
+    def __init__(self, stmt):
+        self.names: set[tuple[str, str]] = set()
+        self.whole: set[str] = set()
+        for n in _iter_nodes(stmt):
+            if isinstance(n, ast.ColName):
+                self.names.add((n.table.lower(), n.name.lower()))
+            elif isinstance(n, ast.Join):
+                self.names.update(("", u.lower()) for u in n.using)
+                if n.natural:
+                    self._keep_whole(n, {""})
+            elif isinstance(n, ast.SelectStmt) and n.from_clause is not None:
+                stars = {f.expr.table.lower() for f in n.fields
+                         if isinstance(f.expr, ast.Star)}
+                if stars:
+                    self._keep_whole(n.from_clause, stars)
+
+    def _keep_whole(self, from_node, refs: set) -> None:
+        for ts in _iter_nodes(from_node, self._OWN):
+            if isinstance(ts, ast.TableSource) and \
+                    ("" in refs or ts.ref_name.lower() in refs):
+                self.whole.add(ts.ref_name.lower())
+
+    def of(self, ref_name: str, info) -> list:
+        """The public columns of `info`, in order, that a reader named
+        `ref_name` has to produce. A body that names none (COUNT(*),
+        SELECT 1) still needs the rows counted: it gets the handle
+        column, which is decoded from the key, else the first
+        fixed-width column, else the first."""
+        cols = info.public_columns()
+        ref = ref_name.lower()
+        if ref in self.whole:
+            return cols
+        kept = [c for c in cols
+                if ("", c.name.lower()) in self.names
+                or (ref, c.name.lower()) in self.names]
+        if kept or not cols:
+            return kept
+        pk = info.pk_col_name.lower() if info.pk_is_handle and \
+            info.pk_col_name else None
+        return [min(cols, key=lambda c: (
+            c.name.lower() != pk, c.ft.eval_type == st.EvalType.STRING))]
 
 
 def _reads_table(e, db: str, name: str, cur_db: str) -> bool:
