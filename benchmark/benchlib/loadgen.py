@@ -2,7 +2,11 @@
 parameters (a data file) and drives the server's wire port from threads
 of this process: closed-loop streams that send their next statement when
 the last one returned, and open-loop streams that send on a schedule
-whatever the server does.
+whatever the server does. A closed-loop spec with `"rounds": true`
+sends in rounds: each of its streams sends its next statement when all
+of them have their answers, and a round starts only while the window
+lasts, so every stream completes the same count and no stream runs a
+statement alone at the end.
 
 Every seed gets the same work: an open loop's gaps are the quantiles of
 its arrival distribution and its keys the quantiles of its key
@@ -122,6 +126,24 @@ class _Conn:
         self.client.close()
 
 
+class _Rounds:
+    """The streams of one closed-loop spec that sends in rounds: each
+    waits for the others before its next statement, and the last to
+    arrive reads the window's clock once for all of them."""
+
+    def __init__(self, streams: int, win: Window, timeout_s: float):
+        self.win, self.timeout_s, self.go = win, timeout_s, False
+        self.barrier = threading.Barrier(streams, action=self._decide)
+
+    def _decide(self) -> None:
+        self.go = time.perf_counter() - self.win.t0 < self.win.seconds
+
+    def next(self) -> bool:
+        """Whether this stream sends another statement."""
+        self.barrier.wait(self.timeout_s)
+        return self.go
+
+
 def run_window(port: int, traffic: dict, statements: dict, counts: dict,
                seed: int, seconds: float, annotate=None) -> Window:
     """Drive every stream of `traffic` for `seconds`; statements then in
@@ -132,9 +154,10 @@ def run_window(port: int, traffic: dict, statements: dict, counts: dict,
     lock = threading.Lock()
     threads, conns = [], []
 
-    def closed_loop(name, conn, database, names, offset):
+    def closed_loop(name, conn, database, names, offset, rounds):
         k = 0
-        while time.perf_counter() - win.t0 < seconds:
+        while (rounds.next() if rounds else
+               time.perf_counter() - win.t0 < seconds):
             stmt = names[(offset + k) % len(names)]
             k += 1
             op = Op(name, "closed", database, stmt, None,
@@ -163,13 +186,16 @@ def run_window(port: int, traffic: dict, statements: dict, counts: dict,
     for si, spec in enumerate(traffic["streams"]):
         database = spec["database"]
         if spec["loop"] == "closed":
-            for k in range(int(spec.get("count", 1))):
+            count = int(spec.get("count", 1))
+            rounds = (_Rounds(count, win, timeout_s + _DRAIN_S)
+                      if spec.get("rounds") else None)
+            for k in range(count):
                 conn = _Conn(port, database, timeout_s)
                 conns.append(conn)
                 threads.append(threading.Thread(
                     target=closed_loop, daemon=True,
                     args=(f"s{si}.{k}", conn, database, spec["statements"],
-                          k * int(spec.get("offset_step", 0)))))
+                          k * int(spec.get("offset_step", 0)), rounds)))
         elif spec["loop"] == "open":
             stmt = spec["statement"]
             n_keys = counts[database][statements[stmt]["key_table"]]

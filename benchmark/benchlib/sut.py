@@ -69,6 +69,29 @@ class Sut:
             session.close()
         return {"rows": rows, "seconds": time.perf_counter() - t0}
 
+    def stats_version(self) -> int:
+        """The statistics handle's version: every ANALYZE saved and every
+        histogram feedback bumps it, and a cached plan is keyed by it."""
+        from tidb_tpu.session import Domain
+        return Domain.get(self.running.storage).stats_handle().version
+
+    def stats_pass(self) -> dict:
+        """One beat of the program's stats worker, run now: the call the
+        worker's tick makes (`Domain.auto_analyze_tick`), which analyzes
+        every table whose modifications crossed the auto-analyze ratio.
+        The worker itself runs on: its next tick finds what this one
+        analyzed no longer pending. -> {"analyzed": ["db.table", ...],
+        "version_before", "version_after"}."""
+        from tidb_tpu.session import Domain
+        domain = Domain.get(self.running.storage)
+        before = domain.stats_handle().version
+        done = domain.auto_analyze_tick()
+        ischema = domain.info_schema()
+        names = ["%s.%s" % (db, info.name)
+                 for db, info in map(ischema.table_by_id, done)]
+        return {"analyzed": names, "version_before": before,
+                "version_after": domain.stats_handle().version}
+
     # -- counters -----------------------------------------------------------
 
     def snapshot(self, client) -> dict:
@@ -92,6 +115,7 @@ class Sut:
             "compile_cache": st["compile_cache"],
             "chunk_cache": {"hits": cc.hits, "misses": cc.misses},
             "hbm_resident_bytes": storage.device_cache.resident_bytes(),
+            "stats_version": self.stats_version(),
             "digests": digests,
             "memory": [{"bytes_in_use": m.get("bytes_in_use", 0),
                         "peak_bytes_in_use": m.get("peak_bytes_in_use", 0)}
