@@ -37,14 +37,16 @@ TRANSPORT_SPANS = {"copr.task", "copr.stream"}
 SCAN_EXEC_SPANS = {"copr.exec"}
 COLD_SCAN_SPANS = {"copr.kv_scan", "copr.decode"}
 
-# the root executors' own spans (PR 33): exec.agg and exec.topn run at
-# every size (FinalAggExec / HashAggExec on one chip, MeshAggExec /
+# the root executors' own spans: exec.agg runs at every size
+# (FinalAggExec / HashAggExec on one chip, MeshAggExec /
 # MeshLookupAggExec above it). exec.join names an OPERATOR the plane does
 # not run: on one chip Q3's customer-orders join is a HashJoinExec under
 # the fused aggregate, above one chip route_mesh folds every join into
 # MeshLookupAggExec's lookup chain, whose host work is that operator's
-# exec.agg
-ONE_CHIP_OPERATOR_SPANS = {"exec.join"}
+# exec.agg. copr.release is a region task's device agg dropping its
+# result: above one chip route_mesh runs Q1's and Q3's aggregates in the
+# Mesh* executors, not in the coprocessor's region tasks
+ONE_CHIP_OPERATOR_SPANS = {"exec.join", "copr.release"}
 
 SIZES = (1, 8)
 

@@ -82,6 +82,20 @@ def _repeated_names():
         "execute": [100 - 8 * 4, 1], "dispatch": [8 * 4, 8]}
 
 
+def _frame_wait():
+    # the session thread drains a fanned-out stream: each copr.wait takes
+    # its interval out of execute's self time (30 + 55 = the 85 execute
+    # kept before the span), the worker beside it keeps its own
+    w = _span("copr.stream", 12, 80, tid=2, children=[
+        _span("copr.exec", 20, 30, tid=2)])
+    ex = _span("execute", 10, 95, children=[
+        w, _span("copr.wait", 12, 25), _span("copr.wait", 30, 50),
+        _span("copr.wait", 60, 82)])
+    return _span("statement", 0, 100, children=[ex]), {
+        "statement": [15, 1], "execute": [30, 1], "copr.wait": [55, 3],
+        "copr.stream": [58, 1], "copr.exec": [10, 1]}
+
+
 def _root_only():
     return _span("statement", 7, 19), {"statement": [12, 1]}
 
@@ -99,7 +113,7 @@ def _grafted_overlap():
 
 @pytest.mark.parametrize("build", [
     _nested, _cross_thread, _mixed, _repeated_names, _root_only,
-    _grafted_overlap], ids=lambda f: f.__name__.lstrip("_"))
+    _grafted_overlap, _frame_wait], ids=lambda f: f.__name__.lstrip("_"))
 def test_self_times_against_hand_counts(build):
     root, want = build()
     assert trace.self_times(root) == want
@@ -643,14 +657,100 @@ def test_span_threads_are_recorded_for_worker_spans(scan_session):
         assert all(c.tid == w.tid for c in _walk(w, []))
 
 
-# -- the root executors under spans (PR 33) ---------------------------------
-# exec.join / exec.apply (+ exec.apply.inner) / exec.agg / exec.topn are
-# open while the operator's own generator body runs and closed while it
-# waits for a child's chunk or its consumer holds one (executor._OwnSpan):
-# Q18 runs all five.
+def test_frame_wait_is_one_span_a_frame_on_the_draining_thread(
+        scan_session):
+    """A streamed TableReader over four regions and two stream workers:
+    the session thread opens one copr.wait per frame it takes from the
+    queue and one per worker's sentinel, under execute, and execute's
+    self time plus theirs is what execute kept before the span came."""
+    s = scan_session
+    trace.reset_for_tests()
+    with config.session_overlay({"tidb_tpu_chunk_cache": 0,
+                                 "tidb_tpu_copr_stream": 1,
+                                 "tidb_tpu_cop_concurrency": 2,
+                                 "tidb_tpu_copr_stream_frame_bytes": 4096,
+                                 "tidb_tpu_trace_sample": 1}):
+        rows = s.query("SELECT a, b, c FROM t WHERE b < 7").rows
+    assert len(rows) == N_ROWS
+    root = [r for r in trace.ring_records() if "FROM t" in r["sql"]][-1][
+        "root"]
+    spans = _walk(root, [])
+    me = threading.get_ident()
+    waits = [sp for sp in spans if sp.name == "copr.wait"]
+    workers = [sp for sp in spans if sp.name == "copr.stream"]
+    # every frame carries rows (no filter drops one): one copr.exec each
+    frames = [sp for sp in spans if sp.name == "copr.exec"]
+    assert len(workers) == 2 and len(frames) > 8
+    assert len(waits) == len(frames) + len(workers)
+    assert all(w.tid == me for w in waits)
+    [execute] = [c for c in root.children if c.name == "execute"]
+    assert all(w in execute.children for w in waits)
+    others = sum(c.duration_ns for c in execute.children
+                 if c.tid == me and c.name != "copr.wait")
+    st = trace.self_times(root)
+    assert st["copr.wait"][1] == len(waits)
+    assert st["execute"][0] + st["copr.wait"][0] == \
+        execute.duration_ns - others
+    assert trace.validate(root) == []
 
-EXEC_SPANS = ("exec.join", "exec.apply", "exec.apply.inner", "exec.agg",
-              "exec.topn")
+
+def test_release_is_a_child_of_exec_where_the_result_dies(scan_session,
+                                                          monkeypatch):
+    """A device agg in a region task drops its result pytree inside
+    copr.release, under copr.exec: when the span closes, no device array
+    of the result it read back is alive."""
+    import weakref
+
+    import jax
+
+    from tidb_tpu.ops import hashagg
+    s = scan_session
+    results = []
+    real_finalize = hashagg.HashAggKernel.finalize
+
+    def finalize(self, chunk, pending):
+        results.append([weakref.ref(a)
+                        for a in jax.tree_util.tree_leaves(pending)])
+        return real_finalize(self, chunk, pending)
+
+    alive = []
+
+    class watching(trace.span):
+        def __exit__(self, *exc):
+            if self._span.name == "copr.release":
+                alive.append(sum(r() is not None for r in results[-1]))
+            return super().__exit__(*exc)
+
+    monkeypatch.setattr(hashagg.HashAggKernel, "finalize", finalize)
+    monkeypatch.setattr(trace, "span", watching)
+    trace.reset_for_tests()
+    with config.session_overlay({"tidb_tpu_chunk_cache": 0,
+                                 "tidb_tpu_cop_concurrency": 1,
+                                 "tidb_tpu_device_min_rows": 1,
+                                 "tidb_tpu_trace_sample": 1}):
+        got = s.query("SELECT b, COUNT(*) FROM t GROUP BY b").rows
+    monkeypatch.undo()
+    assert sorted(int(n) for _b, n in got) == sorted(
+        (N_ROWS + 6 - b) // 7 for b in range(7))
+    assert results and all(len(r) > 1 for r in results)
+    assert alive == [0] * len(results)
+    root = [r for r in trace.ring_records() if "GROUP BY" in r["sql"]][-1][
+        "root"]
+    releases = [(parent, c) for parent in _walk(root, [])
+                for c in parent.children if c.name == "copr.release"]
+    assert len(releases) == len(results)
+    for parent, rel in releases:
+        assert parent.name == "copr.exec" and rel.tid == parent.tid
+    assert trace.validate(root) == []
+
+
+# -- the root executors under spans (PR 33) ---------------------------------
+# exec.join / exec.apply (+ exec.apply.inner) / exec.agg are open while
+# the operator's own generator body runs and closed while it waits for a
+# child's chunk or its consumer holds one (executor._OwnSpan): Q18 runs
+# all four.
+
+EXEC_SPANS = ("exec.join", "exec.apply", "exec.apply.inner", "exec.agg")
 FINAL_GROUPS = "tidb_tpu_agg_final_groups_total"
 
 
@@ -736,7 +836,7 @@ def test_no_root_executor_span_is_open_across_a_pull(q18_session):
     own_children = {"sched.slot", "dispatch", "finalize", "join.partition",
                     "host.fallback"}
     for sp in _walk(root, []):
-        if sp.name in ("exec.join", "exec.agg", "exec.topn"):
+        if sp.name in ("exec.join", "exec.agg"):
             assert {c.name for c in sp.children} <= own_children, sp.name
         elif sp.name == "exec.apply":
             assert {c.name for c in sp.children} <= {"exec.apply.inner"}

@@ -1251,15 +1251,11 @@ class TopNExec(Executor):
         self.child = build_executor(plan.children[0])
 
     def chunks(self, ctx):
-        own = _OwnSpan(lambda: trace.span("exec.topn"))
-        yield from own.drive(self._best(ctx, own))
-
-    def _best(self, ctx, own):
         n = self.plan.count + self.plan.offset
         best = None
         tracked = 0
         try:
-            for chunk in own.pull(self.child.chunks(ctx)):
+            for chunk in self.child.chunks(ctx):
                 cand = chunk if best is None else best.concat(chunk)
                 if cand.num_rows > 0:
                     best = cand.take(_sort_order(self.plan.by, cand)[:n])

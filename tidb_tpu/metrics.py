@@ -40,7 +40,9 @@ __all__ = ["counter", "histogram", "gauge", "expose", "snapshot",
            "KERNEL_COMPILE_SECONDS", "KERNEL_DISPATCHES", "AGG_DISPATCHES",
            "DECODE_ROWS", "AGG_FINAL_GROUPS", "READER_COLUMNS",
            "SPAN_SELF_SECONDS", "SPAN_COUNT", "H2D_BYTES",
-           "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS"]
+           "WIRE_WRITE_SECONDS", "WIRE_WRITE_BYTES", "WIRE_WRITE_CALLS",
+           "GC_PAUSE_SECONDS", "GC_COLLECTIONS", "WORKER_BEAT_SECONDS",
+           "WORKER_BEAT_COUNT", "COP_RANGES"]
 
 _lock = threading.Lock()
 _counters: dict[tuple[str, tuple], float] = {}       # guarded-by: _lock
@@ -51,6 +53,13 @@ _gauges: dict[tuple[str, tuple], float] = {}         # guarded-by: _lock
 # SPAN_SELF_SECONDS / SPAN_COUNT counter families): the vocabulary is
 # closed, trace.SPAN_NAMES, so this stays a few dozen entries
 _span_acc: dict[str, list] = {}                      # guarded-by: _lock
+
+# per collector generation: [pause nanoseconds, collections] (rendered as
+# the GC_PAUSE_SECONDS / GC_COLLECTIONS families). None until
+# trace.install_gc_hook() asks for it: a process without the hook has no
+# such series. Written without the lock, by the one gc callback: the
+# collector runs one collection at a time, under the GIL
+_gc_acc: list | None = None
 
 _BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0)
 
@@ -132,6 +141,27 @@ def _span_series() -> list:
     return out
 
 
+def gc_accumulator() -> list:
+    """The per-generation [pause ns, collections] lists the gc callback
+    adds to in place (trace.install_gc_hook); made on first call."""
+    global _gc_acc
+    with _lock:
+        if _gc_acc is None:
+            _gc_acc = [[0, 0] for _gen in range(3)]
+        return _gc_acc
+
+
+def _gc_series() -> list:
+    """[(family, label tuple, value)] of the gc accumulator; caller
+    holds _lock."""
+    out = []
+    for gen, (ns, n) in enumerate(_gc_acc or ()):
+        labels = (("generation", str(gen)),)
+        out.append((GC_PAUSE_SECONDS, labels, ns / 1e9))
+        out.append((GC_COLLECTIONS, labels, n))
+    return out
+
+
 def histogram(name: str, value: float, labels: dict | None = None) -> None:
     key = (name, _label_key(labels))
     with _lock:
@@ -165,7 +195,7 @@ def snapshot() -> dict:
         out = {}
         for (name, labels), v in _counters.items():
             out[name + _label_str(labels)] = v
-        for name, labels, v in _span_series():
+        for name, labels, v in _span_series() + _gc_series():
             out[name + _label_str(labels)] = v
         for (name, labels), v in _gauges.items():
             out[name + _label_str(labels)] = v
@@ -193,7 +223,7 @@ def expose() -> str:
         for (name, labels), v in sorted(
                 list(_counters.items()) +
                 [((name, labels), v)
-                 for name, labels, v in _span_series()]):
+                 for name, labels, v in _span_series() + _gc_series()]):
             meta(name, "counter")
             lines.append(f"{name}{_label_str(labels)} {v}")
         for (name, labels), v in sorted(_gauges.items()):
@@ -390,6 +420,24 @@ WIRE_WRITE_BYTES = "tidb_tpu_wire_write_bytes_total"
 # one per socket write the packet writer makes (server/packet.py
 # PacketIO.flush), whatever the reply: a response should cost one
 WIRE_WRITE_CALLS = "tidb_tpu_wire_write_calls_total"
+# host work outside any statement that holds the chip idle: the Python
+# collector's pauses, labeled {generation} (trace.install_gc_hook, one
+# gc.callbacks hook installed at server start), and the supervised
+# background beats, labeled {worker} (util/supervisor.supervise, around
+# each beat(), a beat that raises included)
+GC_PAUSE_SECONDS = "tidb_tpu_gc_pause_seconds_total"
+GC_COLLECTIONS = "tidb_tpu_gc_collections_total"
+WORKER_BEAT_SECONDS = "tidb_tpu_worker_beat_seconds_total"
+WORKER_BEAT_COUNT = "tidb_tpu_worker_beat_count_total"
+# how each region range of a coprocessor request was served, counted
+# once at the decision: {served="resident",why="hit"} from the columnar
+# caches; {served="streamed"} framed from the raw scan
+# (store/stream.region_stream) with why="cold" (no entry), "over_frame"
+# (the entry, or its result's remembered size, passes the client's
+# frame cap) or "uncacheable" (the cached path does not apply);
+# {served="scanned"} the materialized handler's whole-range read
+# (store/copr.py), why="cold" or "uncacheable"
+COP_RANGES = "tidb_tpu_cop_ranges_total"
 
 _HELP = {
     QUERY_DURATIONS: "Statement wall time through Session.execute.",
@@ -526,4 +574,14 @@ _HELP = {
         "Result-set encoding + socket write time on the wire.",
     WIRE_WRITE_BYTES: "Result-set bytes written to client sockets.",
     WIRE_WRITE_CALLS: "Socket writes made by the MySQL packet writer.",
+    GC_PAUSE_SECONDS:
+        "Time the Python garbage collector held the process, "
+        "by generation.",
+    GC_COLLECTIONS: "Python garbage collections, by generation.",
+    WORKER_BEAT_SECONDS:
+        "Time spent in supervised background beats, by worker.",
+    WORKER_BEAT_COUNT: "Supervised background beats run, by worker.",
+    COP_RANGES:
+        "Coprocessor region ranges, by how they were served "
+        "(resident|streamed|scanned) and why.",
 }

@@ -98,6 +98,10 @@ class Server:
         # supervised sampler in tidb_tpu/metrics_history.py; idempotent)
         from tidb_tpu import metrics_history
         metrics_history.ensure_started()
+        # and counts the collector's pauses, which stop every thread
+        # (trace.install_gc_hook; idempotent)
+        from tidb_tpu import trace
+        trace.install_gc_hook()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="mysql-accept")
         self._thread.start()

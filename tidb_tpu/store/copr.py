@@ -249,6 +249,10 @@ def _encoded_agg(plan: CopPlan, chunk, sources: int,
         runtime_stats.note_superchunk(
             plan, chunk.num_rows, bucket_size(max(chunk.num_rows, 1)),
             sources)
+    # finalize read the result back: `pending` holds the last references
+    # to its device arrays, which die here under a span of their own
+    with trace.span("copr.release"):
+        del pending
     return CopResponse(chunk=res)
 
 
@@ -373,6 +377,11 @@ def _exec_cop_plan(plan: CopPlan, chunk, sources: int,
                     runtime_stats.note_superchunk(
                         plan, chunk.num_rows,
                         bucket_size(max(chunk.num_rows, 1)), sources)
+                # finalize read the result back: `pending` holds the last
+                # references to its device arrays, which die here under a
+                # span of their own
+                with trace.span("copr.release"):
+                    del pending
                 return CopResponse(chunk=res)
             except failpoint.DispatchTimeoutError:
                 # the watchdog already cancel-latched the statement:
@@ -466,9 +475,11 @@ def _dev_pending_fn(dstore, plan: CopPlan, s: bytes, e: bytes):
 
 
 def _cached_range_chunk(storage, region: Region, plan: CopPlan, s: bytes,
-                        e: bytes, req: CopRequest):
+                        e: bytes, req: CopRequest, note_range: bool = False):
     """Whole-range decoded chunk with host-cache lookup/fill, served as
-    base ⋈ delta under OLTP writes (store/delta.py).
+    base ⋈ delta under OLTP writes (store/delta.py). `note_range` counts
+    the range in tidb_tpu_cop_ranges_total: resident on a hit, scanned
+    (cold) on a miss.
     -> (chunk, dev_ref): dev_ref parameterizes the HBM device cache
     (store/device_cache.py) for a fused dispatch over the same block —
     (cache, key, data_version, read_ts, fill_ts, pend_fn), with fill_ts
@@ -533,6 +544,12 @@ def _cached_range_chunk(storage, region: Region, plan: CopPlan, s: bytes,
                 else:
                     metrics.counter(metrics.CACHE_DELTA_SERVES)
                     hit = (pend.watermark, merged)
+    if note_range and hit is not None:
+        metrics.counter(metrics.COP_RANGES,
+                        {"served": "resident", "why": "hit"})
+    elif note_range:
+        metrics.counter(metrics.COP_RANGES,
+                        {"served": "scanned", "why": "cold"})
     if hit is not None:
         # the host entry's OWN fill snapshot (or delta watermark)
         # bounds the device entry: both caches share one validity
@@ -582,13 +599,17 @@ def _cached_range_chunk(storage, region: Region, plan: CopPlan, s: bytes,
 
 
 def exec_cached_cop(storage, region: Region, plan: CopPlan, s: bytes,
-                    e: bytes, req: CopRequest) -> list[CopResponse]:
+                    e: bytes, req: CopRequest,
+                    note_range: bool = False) -> list[CopResponse]:
     """One region task served through the columnar caches: whole-range
     decoded chunk (host chunk cache), HBM-resident block for fused agg
     dispatch (device cache), memoized filter results. Shared by the
     materialized handler and the streaming producer, so COP_STREAM
-    reads hit exactly the same cache hierarchy."""
-    chunk, dev_ref = _cached_range_chunk(storage, region, plan, s, e, req)
+    reads hit exactly the same cache hierarchy. The materialized
+    handler passes `note_range` (the streaming producer counts the
+    range at its own decision)."""
+    chunk, dev_ref = _cached_range_chunk(storage, region, plan, s, e, req,
+                                         note_range)
     if chunk.num_rows == 0:
         return []
     if not plan.is_agg and (plan.filter is not None or
@@ -664,7 +685,10 @@ def cop_handler(storage):
         rng: KVRange = req.ranges[0]   # client sends one range per task
         s, e = clamp_range(region, rng)
         if use_cached_path(storage, plan):
-            return exec_cached_cop(storage, region, plan, s, e, req)
+            return exec_cached_cop(storage, region, plan, s, e, req,
+                                   note_range=True)
+        metrics.counter(metrics.COP_RANGES,
+                        {"served": "scanned", "why": "uncacheable"})
         out = []
         cur = s
         remaining = plan.limit

@@ -49,7 +49,7 @@ import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
 
-from tidb_tpu import config, metrics
+from tidb_tpu import config, metrics, trace
 from tidb_tpu.kv import CopRequest, KVRange
 from tidb_tpu.util import failpoint
 
@@ -210,6 +210,9 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
     fill_handles: list | None = None
     fill_bytes = fill_billed = 0
     resident = None
+    # why this range streams, counted once at the decision
+    # (tidb_tpu_cop_ranges_total)
+    why = "uncacheable"
     if use_cached_path(storage, plan):
         from tidb_tpu.store.chunk_cache import ChunkCache
         cache = storage.chunk_cache
@@ -217,6 +220,7 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
         dv = storage.engine.data_version
         resident = cache.peek(key, dv, req.start_ts)
         known = _overcap_get(key, dv)
+        why = "cold" if resident is None else "over_frame"
         if resident is not None and (plan.is_agg or
                                      resident <= frame_bytes) and \
                 (known is None or known <= frame_bytes):
@@ -234,6 +238,8 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
             frame = _cached_frame(storage, region, req, plan, s, e,
                                   frame_bytes, key, dv)
             if frame is not None:
+                metrics.counter(metrics.COP_RANGES,
+                                {"served": "resident", "why": "hit"})
                 yield frame
                 return
         # cold: stream frames exactly as before (the client's memory
@@ -251,6 +257,7 @@ def region_stream(storage, region, req: CopRequest, frame_bytes: int):
                 # then patch forward as base⋈delta (store/delta.py)
                 # exactly like materialized fills
                 fill_handles = []
+    metrics.counter(metrics.COP_RANGES, {"served": "streamed", "why": why})
 
     remaining = plan.limit if not plan.is_agg else None
     pend: list[tuple[bytes, bytes]] = []
@@ -423,7 +430,10 @@ class BoundedFrameQueue:
         Exceptions put by producers re-raise in the consumer."""
         finished = 0
         while finished < producers:
-            item = self._q.get()
+            # the consumer's wait, one span a frame or sentinel, never
+            # around the yield below
+            with trace.span("copr.wait"):
+                item = self._q.get()
             if item is self._DONE:
                 finished += 1
             elif isinstance(item, BaseException):

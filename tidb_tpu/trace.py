@@ -38,7 +38,10 @@ tidb_tpu_span_self_seconds_total{span} / tidb_tpu_span_count_total
 not; and while a `jax.profiler` session is live every span is also a
 `jax.profiler.TraceAnnotation` carrying the root's trace id, so the
 program's spans sit on the device trace's clock (decided once per root,
-`_profiling`; nothing per span outside a session).
+`_profiling`; nothing per span outside a session). Host work that
+belongs to no statement — the collector's pauses (`install_gc_hook`)
+and the supervised background beats — is counted always and, inside a
+session, annotated too (`HOST_MARKS`, `host_annotation`).
 
 Cross-thread propagation follows the house pattern (the runtime_stats
 collector and the memtrack tracker): the coprocessor fan-out captures
@@ -57,10 +60,11 @@ import logging
 import threading
 import time
 
-__all__ = ["Span", "SPAN_NAMES", "begin", "end", "span", "event",
+__all__ = ["Span", "SPAN_NAMES", "HOST_MARKS", "begin", "end", "span", "event",
            "annotate", "current_root", "active", "detach", "restore",
            "attached", "propagate", "attach_remote", "origin",
            "phase_ns", "log_tree", "ensure_id", "finish_statement",
+           "host_annotation", "install_gc_hook",
            "tree", "validate", "self_times", "ring_snapshot",
            "ring_records", "ring_get", "to_chrome", "reset_for_tests"]
 
@@ -96,6 +100,13 @@ SPAN_NAMES = {
     "copr.kv_scan": "one storage.engine.scan call (MVCC iteration)",
     "copr.decode": "raw KV rows -> decoded chunk (decode_cop_batch)",
     "copr.exec": "the pushed filter/projection/partial agg over a chunk",
+    # a region task's device agg dropping its result pytree's device
+    # arrays (store/copr._exec_cop_plan), a child of copr.exec
+    "copr.release": "the last references to a device agg's result die",
+    # the consumer of a fanned-out stream blocked for its next frame or
+    # sentinel (store/stream.BoundedFrameQueue.drain), on the session
+    # thread or the inner plan's: one span a frame, never a row
+    "copr.wait": "the draining thread's wait for the next frame",
     # storage-side caches and deltas (store/device_cache.py, delta.py)
     "hbm.fill": "HBM region-block cache upload",
     "hbm.patch": "in-place delta patch of a resident HBM block",
@@ -113,13 +124,23 @@ SPAN_NAMES = {
     "exec.apply": "ApplyExec: the subquery predicate over outer chunks",
     "exec.apply.inner": "ApplyExec: one run of the inner plan",
     "exec.agg": "FinalAggExec / HashAggExec: the host's merge of partials",
-    "exec.topn": "TopNExec: sort + cut of each chunk against the best",
     # cross-process storage roots (store/remote.py)
     "storage:coprocessor_stream": "storage-side root of one COP stream",
     # cluster observability fan-out (util/statusclient.fetch_all): one
     # bounded-timeout sweep over live members' status ports serving a
     # cluster_* memtable or a /fleet/* endpoint
     "cluster.fetch": "fan-out fetch over live members' status ports",
+}
+
+# host work outside every statement, on the device trace's clock only:
+# while a jax.profiler session is live each is a TraceAnnotation on the
+# thread that did it (host_annotation), and counters count it always
+# (metrics.GC_* via install_gc_hook, metrics.WORKER_BEAT_* in
+# util/supervisor.supervise). Not spans: nothing folds them into a
+# statement's tree
+HOST_MARKS = {
+    "gc.pause": "one Python garbage collection, tagged generation",
+    "worker.beat": "one supervised background beat, tagged worker",
 }
 
 # retention bounds of the server-scope trace ring: records and an
@@ -346,6 +367,76 @@ def _annotation(name: str, root: Span):
     ann = _TraceMe(name, trace_id=ensure_id(root))
     ann.__enter__()
     return ann
+
+
+# jax.profiler.TraceAnnotation for the host marks, bound apart from
+# _TraceMe: the statements' decision and these marks never share state
+_HostMe = None
+
+
+def _host_traceme():
+    global _HostMe
+    if _HostMe is None:
+        from jax.profiler import TraceAnnotation
+        _HostMe = TraceAnnotation
+    return _HostMe
+
+
+def host_annotation(name: str, **meta):
+    """An entered TraceAnnotation named `name` (a HOST_MARKS key) on the
+    calling thread while a jax.profiler session is live, for host work
+    that belongs to no statement; None outside a session, after one
+    check of the recorder's flag. The caller exits it where the work
+    ends, on the same thread."""
+    tm = _HostMe or _host_traceme()
+    if not tm.is_enabled():
+        return None
+    ann = tm(name, **meta)
+    ann.__enter__()
+    return ann
+
+
+# -- garbage-collector pauses ------------------------------------------------
+
+_gc_installed = False
+_gc_acc: list | None = None     # metrics.gc_accumulator(), once installed
+_gc_t0 = 0
+_gc_ann = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The gc.callbacks hook: a clock read at "start", two in-place adds
+    at "stop", and the gc.pause annotation only inside a profiler
+    session. The collector runs one collection at a time under the GIL,
+    so the module globals need no lock."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        _gc_ann = host_annotation("gc.pause",
+                                  generation=info["generation"])
+        _gc_t0 = time.perf_counter_ns()
+        return
+    acc = _gc_acc[info["generation"]]
+    acc[0] += time.perf_counter_ns() - _gc_t0
+    acc[1] += 1
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+
+
+def install_gc_hook() -> None:
+    """Count every collection's pause, by generation
+    (tidb_tpu_gc_pause_seconds_total / tidb_tpu_gc_collections_total),
+    from now on. Once a process: the server calls it at start, beside
+    its other process-wide counters."""
+    global _gc_installed, _gc_acc
+    import gc
+    with _seq_lock:
+        if _gc_installed:
+            return
+        _gc_installed = True
+    _host_traceme()         # bound here: the callback never imports
+    _gc_acc = (_metrics_mod or _metrics()).gc_accumulator()
+    gc.callbacks.append(_on_gc)
 
 
 def active() -> bool:

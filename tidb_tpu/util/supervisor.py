@@ -24,6 +24,13 @@ supervisor owns that policy in one place:
 Each supervised beat first evaluates the `worker/tick` failpoint
 (util/failpoint.py) with the worker's name, so tests and the chaos
 harness can crash any worker by name and watch it come back.
+
+Every `beat()` is timed, a beat that raises included:
+`tidb_tpu_worker_beat_seconds_total{worker}` and
+`tidb_tpu_worker_beat_count_total{worker}`, and while a jax.profiler
+session is live a `worker.beat` annotation on the worker's thread
+(trace.host_annotation). Beats run beside the statements, under the
+same GIL and outside every statement's span tree.
 """
 
 from __future__ import annotations
@@ -32,7 +39,7 @@ import logging
 import threading
 import time
 
-from tidb_tpu import metrics
+from tidb_tpu import metrics, trace
 from tidb_tpu.util import failpoint
 
 __all__ = ["supervise", "run_once", "BACKOFF_BASE_S", "BACKOFF_CAP_S"]
@@ -55,6 +62,22 @@ def supervise(name: str, beat, stop: threading.Event,
     with counted exponential backoff. The thread is named `name` so
     the testleak allowlist and thread dumps identify it."""
 
+    # the series exist from the start: a window with no beat reads 0
+    metrics.counter(metrics.WORKER_BEAT_SECONDS, {"worker": name}, 0)
+    metrics.counter(metrics.WORKER_BEAT_COUNT, {"worker": name}, 0)
+
+    def timed_beat() -> None:
+        ann = trace.host_annotation("worker.beat", worker=name)
+        t0 = time.perf_counter_ns()
+        try:
+            beat()
+        finally:
+            metrics.counter(metrics.WORKER_BEAT_SECONDS, {"worker": name},
+                            (time.perf_counter_ns() - t0) / 1e9)
+            metrics.counter(metrics.WORKER_BEAT_COUNT, {"worker": name})
+            if ann is not None:
+                ann.__exit__(None, None, None)
+
     def loop() -> None:
         crashes = 0
         # backoff SLOWS a crashing beat, never accelerates it: a 30s
@@ -63,7 +86,7 @@ def supervise(name: str, beat, stop: threading.Event,
                             else max(interval, _backoff_s(crashes))):
             try:
                 failpoint.eval("worker/tick", name)
-                beat()
+                timed_beat()
                 crashes = 0
             except Exception as e:  # noqa: BLE001 - the supervisor IS
                 # the crash handler: count + back off + keep the worker
