@@ -233,7 +233,7 @@ class TestDenseBranch:
                              "scatter": int(want == "scatter")}
             got.append({key: (int(gr.partials[0][0][i]),
                               int(gr.partials[1][0][i]))
-                        for i, key in enumerate(gr.keys)})
+                        for i, key in enumerate(gr.key_tuples())})
         names = dict(build)
         want = {}
         for i, k_, amt, q in probe:

@@ -171,7 +171,7 @@ class TestPartitionedAgg:
     def _norm(gr):
         return {key: (int(gr.partials[0][0][i]),
                       round(float(gr.partials[1][0][i]), 6))
-                for i, key in enumerate(gr.keys)}
+                for i, key in enumerate(gr.key_tuples())}
 
     @pytest.mark.parametrize("case", ["highcard", "onekey", "nulls",
                                       "pow2"])

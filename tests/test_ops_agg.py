@@ -400,7 +400,7 @@ def test_dense_with_no_live_row(monkeypatch, mode, shape):
     raws, results, moved = _run_both(monkeypatch, mode, ch, filt)
     nuniq, dense = _assert_same_block(raws, results, moved, ch, mode, filt)
     assert nuniq == 1 and dense        # the masked slot alone
-    assert results[0].keys == [] and results[1].keys == []
+    assert results[0].num_groups == 0 and results[1].num_groups == 0
 
 
 @pytest.mark.parametrize("mode", ["direct", "cond", "hash"])
@@ -421,3 +421,263 @@ def test_capacity_error_on_both_branches(mode, groups, capacity, path):
     after = _dispatches()
     assert {p: after[p] - before[p] for p in after} == \
         {"dense": int(path == "dense"), "scatter": int(path == "scatter")}
+
+
+# -- the root merge: columnar partials against the dict merge -----------------
+
+from dataclasses import replace as _replace  # noqa: E402
+
+from tidb_tpu.executor import _agg_chunk  # noqa: E402
+from tidb_tpu.ops.hashagg import GroupResult  # noqa: E402
+from tidb_tpu.plan.resolver import PlanSchema, SchemaCol  # noqa: E402
+
+DT = st.new_datetime_field()
+# value columns of every merge case: int, DECIMAL(15,2) (scaled), REAL,
+# and a REAL of signed zeros (a MAX that python's max and numpy's
+# maximum answer differently: -0.0 or 0.0)
+_VALS = [INT, DEC2, DBL, DBL]
+_KEY_FTS = {"int": [INT], "decimal": [DEC2], "datetime": [DT],
+            "real": [DBL], "nullable": [INT], "two": [INT, DT],
+            "scalar": []}
+
+
+def _merge_aggs(nk: int):
+    """Every columnar aggregate over the value columns after `nk` keys."""
+    v_int, v_dec, v_dbl, v_zero = (col(nk + i, ft)
+                                   for i, ft in enumerate(_VALS))
+    return [AggDesc(AggFunc.COUNT, None), AggDesc(AggFunc.COUNT, v_dbl),
+            AggDesc(AggFunc.SUM, v_dec), AggDesc(AggFunc.SUM, v_dbl),
+            AggDesc(AggFunc.AVG, v_dec), AggDesc(AggFunc.AVG, v_dbl),
+            AggDesc(AggFunc.MIN, v_int), AggDesc(AggFunc.MAX, v_dbl),
+            AggDesc(AggFunc.MAX, v_zero), AggDesc(AggFunc.MIN, v_zero),
+            AggDesc(AggFunc.FIRST_ROW, v_int),
+            AggDesc(AggFunc.FIRST_ROW, v_zero)]
+
+
+def _merge_chunk(rng, keys: str, rows: int):
+    """`rows` rows: keys of the kind over a domain every partial shares
+    (so groups overlap across partials), values with NULLs, negative
+    decimals (AVG's half-up), and doubles whose sum depends on the
+    order it is taken in."""
+    fts = _KEY_FTS[keys]
+    if keys == "two":
+        key = [rng.integers(0, 4, rows),
+               1_700_000_000_000_000 + 86_400_000_000 *
+               rng.integers(0, 3, rows)]
+    elif keys == "real":
+        key = [rng.choice([-0.0, 0.0, 0.5, -2.25, 1e300], rows)]
+    elif keys == "datetime":
+        key = [1_700_000_000_000_000 + rng.integers(0, 9, rows)]
+    elif keys == "decimal":
+        key = [rng.integers(-900, 900, rows) * 7]
+    elif keys != "scalar":
+        key = [rng.integers(0, 12, rows)]
+    else:
+        key = []
+    kvalid = [np.ones(rows, bool) for _ in key]
+    if keys in ("nullable", "two"):
+        kvalid[-1] = rng.random(rows) > 0.2
+    vals = [rng.integers(-10**6, 10**6, rows),
+            rng.integers(-10**7, 10**7, rows),
+            rng.choice([0.1, 0.2, 0.3, 1e16, -1e16, -0.0, 2.5], rows),
+            rng.choice([-0.0, 0.0, -1.0], rows)]
+    vvalid = [rng.random(rows) > 0.15 for _ in vals]
+    ch = Chunk.from_arrays(fts + _VALS, key + vals, kvalid + vvalid)
+    return ch, [col(i, ft) for i, ft in enumerate(fts)]
+
+
+def _partials(keys: str, nparts: int, seed: int):
+    """Host partials of `nparts` chunks over one key domain; the second
+    of several is empty (its filter keeps no row)."""
+    rng = np.random.default_rng(seed)
+    nk = len(_KEY_FTS[keys])
+    aggs = _merge_aggs(nk)
+    out = []
+    for i in range(nparts):
+        ch, groups = _merge_chunk(rng, keys, int(rng.integers(1, 300)))
+        filt = col(nk, INT).lt(-10**7) if i == 1 else None
+        out.append(host_hash_agg(ch, filt, groups, aggs))
+    return aggs, [g.ft for g in groups], out
+
+
+def _dict_merger(aggs, meta=None):
+    """A HashAggregator held to the dict merge (the reference)."""
+    agg = HashAggregator(aggs, meta)
+    agg._cols = None
+    return agg
+
+
+def _chunk_of(agg, aggs, key_fts):
+    schema = PlanSchema([SchemaCol(f"c{i}", "", ft) for i, ft in
+                         enumerate(key_fts + [a.result_ft for a in aggs])])
+    return _agg_chunk(schema, len(key_fts), aggs, agg, note=False)
+
+
+def _assert_same_merge(aggs, key_fts, parts):
+    got, ref = HashAggregator(aggs, key_fts), _dict_merger(aggs, key_fts)
+    for p in parts:
+        got.update(p)
+        ref.update(p)
+    assert (got.path, ref.path) == ("columnar", "dict")
+    want = ref.results()
+    assert got.results() == want
+    # every value exact, of one kind (no float where the dict has an
+    # int) and of one sign (-0.0 is not 0.0 here)
+    for (gk, gv), (wk, wv) in zip(got.results(), want):
+        assert [_exact(x) for x in gk + tuple(gv)] == \
+            [_exact(x) for x in wk + tuple(wv)]
+    a, b = _chunk_of(got, aggs, key_fts), _chunk_of(ref, aggs, key_fts)
+    assert a.num_rows == b.num_rows == max(len(want), int(not key_fts))
+    for ca, cb in zip(a.columns, b.columns):
+        assert ca.data.dtype == cb.data.dtype
+        np.testing.assert_array_equal(ca.valid, cb.valid)
+        assert [_exact(x) for x in ca.data.tolist()] == \
+            [_exact(x) for x in cb.data.tolist()]
+    return want
+
+
+def _exact(x):
+    if isinstance(x, (float, np.floating)):
+        return float, float(x), np.signbit(x)
+    return type(x) is float, x
+
+
+@pytest.mark.parametrize("keys", list(_KEY_FTS))
+@pytest.mark.parametrize("nparts", [1, 2, 5])
+def test_columnar_merge_equals_dict_merge(keys, nparts):
+    aggs, key_fts, parts = _partials(keys, nparts, seed=nparts * 31)
+    want = _assert_same_merge(aggs, key_fts, parts)
+    if keys != "scalar":
+        # rows in key order, NULL last in each key part
+        order = [tuple((x is None, x) for x in k) for k, _v in want]
+        assert order == sorted(order) and len(set(order)) == len(order)
+    if keys == "real":
+        # -0.0 and 0.0 are one group, under the first-seen variant
+        assert sum(k == (0.0,) for k, _v in want) == 1
+
+
+@pytest.mark.parametrize("keys", ["int", "two", "scalar"])
+def test_columnar_merge_folds_in_arrival_order(monkeypatch, keys):
+    """Partials folded a few at a time (the held partials' bound) give
+    the one-pass merge's rows, bit for bit."""
+    aggs, key_fts, parts = _partials(keys, 9, seed=7)
+    monkeypatch.setattr(hashagg, "_FOLD_PARTS", 2)
+    _assert_same_merge(aggs, key_fts, parts)
+
+
+def test_columnar_merge_of_device_partials():
+    """Partials the device kernel built (keys evaluated on the
+    representative rows) merge as the host's do."""
+    rng = np.random.default_rng(5)
+    aggs, key_fts, parts = _partials("two", 1, seed=3)
+    groups = [col(i, ft) for i, ft in enumerate(key_fts)]
+    k = HashAggKernel(None, groups, aggs)
+    for _ in range(3):
+        ch, _g = _merge_chunk(rng, "two", 400)
+        parts.append(k(ch))
+    _assert_same_merge(aggs, key_fts, parts)
+
+
+def test_columnar_merge_empty_and_scalar_over_nothing():
+    aggs, key_fts, _parts = _partials("int", 1, seed=1)
+    agg = HashAggregator(aggs, key_fts)
+    agg.update(GroupResult(keys=[(np.zeros(0, np.int64), np.zeros(0, bool))],
+                           partials=[[] for _ in aggs],
+                           counts=np.zeros(0, np.int64)))
+    assert agg.path == "columnar" and agg.results() == []
+    assert _chunk_of(agg, aggs, key_fts).num_rows == 0
+    # a scalar aggregate over no rows still answers one row
+    out = _chunk_of(HashAggregator(aggs), aggs, [])
+    assert out.num_rows == 1 and out.columns[0].data.tolist() == [0]
+    assert not out.columns[2].valid[0]
+
+
+def _final_groups():
+    snap = metrics.snapshot()
+    return {p: snap.get('tidb_tpu_agg_final_groups_total{path="%s"}' % p,
+                        0) for p in ("columnar", "dict")}
+
+
+STR_CI = _replace(STR, collation="utf8mb4_general_ci")
+
+
+@pytest.mark.parametrize("case,path", [
+    ("int", "columnar"), ("string", "dict"), ("ci", "dict"),
+    ("group_concat", "dict"), ("mixed_lanes", "dict")])
+def test_root_merge_routing(case, path):
+    """The merge follows what the partials show: numeric keys and lanes
+    merge as columns; string and _ci keys, GROUP_CONCAT and partials
+    whose lane dtypes differ take the dict, with the same answer."""
+    kft = {"string": STR, "ci": STR_CI}.get(case, INT)
+    rows = [("Ab" if kft is not INT else 1, 1), ("aB" if kft is not INT
+                                                  else 2, 2),
+            (None, 3), ("ab" if kft is not INT else 1, 4)]
+    ch = Chunk.from_rows([kft, INT], rows)
+    aggs = [AggDesc(AggFunc.SUM, col(1, INT)), AggDesc(AggFunc.COUNT, None)]
+    if case == "group_concat":
+        aggs.append(AggDesc(AggFunc.GROUP_CONCAT, col(1, INT)))
+    parts = [host_hash_agg(ch, None, [col(0, kft)], aggs) for _ in range(2)]
+    if case == "mixed_lanes":
+        p = parts[1]
+        parts[1] = _replace(p, partials=[[p.partials[0][0].astype(float),
+                                          p.partials[0][1]],
+                                         p.partials[1]])
+    agg, ref = HashAggregator(aggs, [kft]), _dict_merger(aggs, [kft])
+    for p in parts:
+        agg.update(p)
+        ref.update(p)
+    assert agg.path == path
+    assert agg.results() == ref.results()
+    before = _final_groups()
+    out = _chunk_of(agg, aggs, [kft])
+    schema = PlanSchema([SchemaCol(f"c{i}", "", c.ft)
+                         for i, c in enumerate(out.columns)])
+    _agg_chunk(schema, 1, aggs, agg)
+    after = _final_groups()
+    moved = {p: after[p] - before[p] for p in after}
+    assert moved == {path: out.num_rows,
+                     ({"columnar", "dict"} - {path}).pop(): 0}
+
+
+def _tuple_form_bytes(gr) -> int:
+    """memtrack.result_bytes as it counted a partial whose keys were
+    tuples: 8 bytes a key part a group (8 for a scalar partial's one),
+    a string key's length, the lanes' and counts' nbytes."""
+    total = gr.counts.nbytes + sum(np.asarray(lane).nbytes
+                                   for ls in gr.partials for lane in ls)
+    for key in gr.key_tuples():
+        total += 8 * max(len(key), 1)
+        total += sum(len(x) for x in key if isinstance(x, (str, bytes)))
+    return total
+
+
+@pytest.mark.parametrize("keys", ["int", "two", "string", "scalar",
+                                  "empty"])
+def test_result_bytes_of_key_columns_as_of_tuples(keys):
+    """A columnar partial is billed what its tuple form was, so the
+    streamed frame cap (store/stream.py) decides as it did."""
+    from tidb_tpu import memtrack
+    rows = [(i % 7, None if i % 5 == 0 else "x" * (i % 4), i)
+            for i in range(60)]
+    ch = Chunk.from_rows([INT, STR, INT], rows)
+    groups = {"int": [col(0, INT)], "string": [col(1, STR)],
+              "two": [col(0, INT), col(1, STR)], "scalar": [],
+              "empty": [col(0, INT)]}[keys]
+    filt = col(2, INT).lt(0) if keys == "empty" else None
+    aggs = [AggDesc(AggFunc.SUM, col(2, INT)), AggDesc(AggFunc.COUNT, None)]
+    gr = host_hash_agg(ch, filt, groups, aggs)
+    assert memtrack.result_bytes(gr) == _tuple_form_bytes(gr)
+
+
+def test_result_bytes_of_a_q18_block():
+    """TPC-H Q18's inner partial of one resident block: 112,500 groups
+    of one int64 key, a DECIMAL SUM's two lanes and the counts — 32
+    bytes a group, 3.6 MB, under the 4 MiB stream frame."""
+    from tidb_tpu import memtrack
+    n = 112_500
+    gr = GroupResult(keys=[(np.arange(n, dtype=np.int64),
+                            np.ones(n, dtype=bool))],
+                     partials=[[np.zeros(n, np.int64), np.ones(n, np.int64)]],
+                     counts=np.ones(n, np.int64))
+    assert memtrack.result_bytes(gr) == _tuple_form_bytes(gr) == 32 * n
+    assert 32 * n < 4 << 20

@@ -125,7 +125,7 @@ def test_dist_agg_empty_chunk(mesh):
     aggs = [AggDesc(AggFunc.COUNT, None)]
     k = MeshAggKernel(mesh, None, [gcol], aggs, capacity=8)
     gr = k(ch)
-    assert gr.keys == []
+    assert gr.num_groups == 0
 
 
 def test_dist_agg_float_group_keys(mesh):

@@ -140,6 +140,35 @@ class TestStructs:
         assert np.array_equal(got.columns[0].valid, c1.valid)
         assert list(got.columns[1].data) == list(c2.data)
 
+    @pytest.mark.parametrize("keys", ["int", "two", "string", "scalar"])
+    def test_group_result_key_columns_round_trip(self, keys):
+        """An agg partial's key columns (struct 22) cross as buffers:
+        dtypes, validity and group order come back as they left."""
+        from tidb_tpu.expression import AggDesc, AggFunc, col
+        from tidb_tpu.ops.hostagg import host_hash_agg
+        fts = [new_int_field(), new_string_field(), new_int_field()]
+        rows = [(i % 5, None if i % 4 == 0 else f"s{i % 3}", i)
+                for i in range(40)]
+        ch = Chunk.from_rows(fts, rows)
+        groups = {"int": [col(0, fts[0])], "string": [col(1, fts[1])],
+                  "two": [col(0, fts[0]), col(1, fts[1])],
+                  "scalar": []}[keys]
+        aggs = [AggDesc(AggFunc.SUM, col(2, fts[2])),
+                AggDesc(AggFunc.COUNT, None)]
+        gr = host_hash_agg(ch, None, groups, aggs)
+        got = rt(gr)
+        assert type(got) is type(gr) and got.num_groups == gr.num_groups
+        assert len(got.keys) == len(groups)
+        for (d, v), (gd, gv) in zip(gr.keys, got.keys):
+            assert gd.dtype == d.dtype and gv.dtype == np.bool_
+            assert gd.tolist() == d.tolist()
+            np.testing.assert_array_equal(gv, v)
+        assert got.key_tuples() == gr.key_tuples()
+        for ls, gls in zip(gr.partials, got.partials):
+            for lane, glane in zip(ls, gls):
+                np.testing.assert_array_equal(glane, lane)
+        np.testing.assert_array_equal(got.counts, gr.counts)
+
 
 class TestErrors:
     @pytest.mark.parametrize("e", [

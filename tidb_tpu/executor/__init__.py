@@ -469,6 +469,45 @@ def _agg_results_to_chunk(schema, num_group: int, aggs: list[AggDesc],
     return Chunk([Column(ft, d, v) for ft, (d, v) in zip(fts, arrays)])
 
 
+# lint: exempt[memtrack-alloc] group-count-sized outputs, bounded by the tracked agg state (HashAggregator.approx_bytes)
+def _agg_columns_to_chunk(schema, cols) -> Chunk:
+    """The columnar merge's (data, valid) columns as the output chunk,
+    each cast to its column's storage dtype (object columns hold host
+    python values, "" under NULL, as _agg_results_to_chunk writes)."""
+    out = []
+    for ft, (d, v) in zip((c.ft for c in schema.cols), cols):
+        dtype = np_dtype_for(ft.tp, ft.flen)
+        if dtype == np.dtype(object):
+            data = np.empty(len(d), dtype=object)
+            data[:] = [x if ok else "" for x, ok in
+                       zip(d.tolist(), v.tolist())]
+        else:
+            data = d.astype(dtype)
+        out.append(Column(ft, data, v))
+    return Chunk(out)
+
+
+def _agg_chunk(schema, num_group: int, aggs: list[AggDesc],
+               agg: HashAggregator, note: bool = True) -> Chunk:
+    """A root aggregate's one output chunk: straight from the columns on
+    the columnar path, row by row from results() on the dict path; a
+    scalar aggregate over no rows still answers one row. `note`: count
+    the groups emitted (_note_final_groups)."""
+    got = agg.result_columns()
+    if got is None:
+        results = agg.results()
+        n, cols = len(results), None
+    else:
+        (n, cols), results = got, []
+    if not n and not num_group:
+        results, n, cols = [((), [_empty_agg_value(a) for a in aggs])], 1, None
+    if note:
+        _note_final_groups(n, "columnar" if cols else "dict")
+    if cols:
+        return _agg_columns_to_chunk(schema, cols)
+    return _agg_results_to_chunk(schema, num_group, aggs, results)
+
+
 class FinalAggExec(Executor):
     """Merges storage-side partials (ref: final HashAgg over partial agg,
     executor/aggregate.go + aggregation.GetPartialResult protocol)."""
@@ -495,14 +534,8 @@ class FinalAggExec(Executor):
                 agg.update(gr)
                 tracked = memtrack.track_to(self.plan,
                                             agg.approx_bytes(), tracked)
-            results = agg.results()
-            if not self.plan.num_group_cols and not results:
-                results = [((), [_empty_agg_value(a)
-                                 for a in self.plan.aggs])]
-            _note_final_groups(len(results))
-            yield _agg_results_to_chunk(self.schema,
-                                        self.plan.num_group_cols,
-                                        self.plan.aggs, results)
+            yield _agg_chunk(self.schema, self.plan.num_group_cols,
+                             self.plan.aggs, agg)
         finally:
             memtrack.release(self.plan, host=tracked)
 
@@ -511,12 +544,14 @@ def _empty_agg_value(a: AggDesc):
     return 0 if a.fn == AggFunc.COUNT else None
 
 
-def _note_final_groups(n: int) -> None:
+def _note_final_groups(n: int, path: str) -> None:
     """The groups a root aggregate is about to emit: the `groups` tag
     of the exec.agg span open around the caller, and the counter that
-    divides that span's self time."""
+    divides that span's self time — its total, and by the merge that
+    built them (`path`: HashAggregator.path)."""
     trace.annotate(groups=n)
     metrics.counter(metrics.AGG_FINAL_GROUPS, inc=n)
+    metrics.counter(metrics.AGG_FINAL_GROUPS, {"path": path}, inc=n)
 
 
 class HashAggExec(Executor):
@@ -574,14 +609,8 @@ class HashAggExec(Executor):
                     agg.update(gr)
                     tracked = memtrack.track_to(
                         self.plan, agg.approx_bytes(), tracked)
-            results = agg.results()
-            if not self.plan.group_exprs and not results:
-                results = [((), [_empty_agg_value(a)
-                                 for a in self.plan.aggs])]
-            num_g = len(self.plan.group_exprs)
-            _note_final_groups(len(results))
-            yield _agg_results_to_chunk(self.schema, num_g,
-                                        self.plan.aggs, results)
+            yield _agg_chunk(self.schema, len(self.plan.group_exprs),
+                             self.plan.aggs, agg)
         finally:
             memtrack.release(self.plan, host=tracked)
 
@@ -1047,13 +1076,8 @@ class StreamAggExec(Executor):
                     feed(sc.chunk)
                     tracked = memtrack.track_to(
                         self.plan, agg.approx_bytes(), tracked)
-            results = agg.results()
-            if not self.plan.group_exprs and not results:
-                results = [((), [_empty_agg_value(a)
-                                 for a in self.plan.aggs])]
-            yield _agg_results_to_chunk(self.schema,
-                                        len(self.plan.group_exprs),
-                                        self.plan.aggs, results)
+            yield _agg_chunk(self.schema, len(self.plan.group_exprs),
+                             self.plan.aggs, agg, note=False)
         finally:
             memtrack.release(self.plan, host=tracked)
 

@@ -147,13 +147,8 @@ def _concat_chunks_cached(holder, slot: str, parts, schema) -> Chunk:
 
 
 def _emit_agg(plan, agg, executor_mod):
-    results = agg.results()
-    if not plan.group_exprs and not results:
-        results = [((), [executor_mod._empty_agg_value(a)
-                         for a in plan.aggs])]
-    executor_mod._note_final_groups(len(results))
-    return executor_mod._agg_results_to_chunk(
-        plan.schema, plan.num_group_cols, plan.aggs, results)
+    return executor_mod._agg_chunk(plan.schema, plan.num_group_cols,
+                                   plan.aggs, agg)
 
 
 def _emit_results(plan, gr_or_none, executor_mod):

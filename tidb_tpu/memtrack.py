@@ -495,7 +495,8 @@ def chunk_bytes(chunk) -> int:
 def result_bytes(res) -> int:
     """Host footprint of a coprocessor response payload: a decoded
     Chunk (chunk_bytes), or an agg partial shaped like
-    ops.hashagg.GroupResult (keys / per-agg lane arrays / counts).
+    ops.hashagg.GroupResult (key columns / per-agg lane arrays /
+    counts).
     Anything else — scalar partials are a handful of lanes — rounds to
     its lane arrays alone."""
     if getattr(res, "columns", None) is not None:
@@ -508,9 +509,15 @@ def result_bytes(res) -> int:
     counts = getattr(res, "counts", None)
     if counts is not None:
         total += counts.nbytes
-    for key in getattr(res, "keys", None) or []:
-        total += 8 * max(len(key), 1)
-        total += sum(len(x) for x in key if isinstance(x, (str, bytes)))
+    keys = getattr(res, "keys", None)
+    if counts is not None and keys is not None:
+        # 8 bytes a key part a group (a scalar partial's one group: 8),
+        # and a string key's length where it is not NULL
+        total += 8 * max(len(keys), 1) * len(counts)
+        for data, valid in keys:
+            if data.dtype == object:
+                total += sum(len(x) for x in data[valid]
+                             if isinstance(x, (str, bytes)))
     return total
 
 

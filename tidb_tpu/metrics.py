@@ -391,7 +391,9 @@ DECODE_ROWS = "tidb_tpu_decode_rows_total"
 # groups the root's aggregates emitted (executor FinalAggExec: the merge
 # of the coprocessor's partials; HashAggExec: the complete aggregation
 # over child chunks), counted once a statement where span exec.agg is:
-# the divisor of that span's self time
+# the divisor of that span's self time. The unlabeled series is the
+# total; {path="columnar"|"dict"} splits it by the merge that built the
+# groups (ops/hashagg.HashAggregator.path)
 AGG_FINAL_GROUPS = "tidb_tpu_agg_final_groups_total"
 # how far column pruning narrows a scan: once per table-reader execution
 # in a statement (not per region task), {kind="scanned"} the columns its
@@ -562,7 +564,8 @@ _HELP = {
         "the chunk (native|python).",
     AGG_FINAL_GROUPS:
         "Groups emitted by the root executors' aggregates "
-        "(FinalAggExec, HashAggExec).",
+        "(FinalAggExec, HashAggExec): the total, and by the merge "
+        "that built them (columnar|dict).",
     READER_COLUMNS:
         "Columns per table-reader execution: those its plan scans "
         "and those the table has (scanned|table).",

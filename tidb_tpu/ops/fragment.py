@@ -272,7 +272,7 @@ class ProbeAggKernel:
                           build.take(pri).columns)
         order = np.arange(len(gidx), dtype=np.int64)
         return finalize_group_result(rep_chunk, self.group_exprs,
-                                     self.aggs, order, order, lanes_at,
+                                     self.aggs, order, lanes_at,
                                      counts[gidx])
 
 # process-wide fragment-kernel cache, keyed on the structural identity

@@ -523,30 +523,44 @@ def _validate_device_exprs(filter_expr, group_exprs, aggs) -> None:
 
 @dataclass
 class GroupResult:
-    """Partial aggregation result of one chunk."""
+    """Partial aggregation result of one chunk, a row per group.
 
-    keys: list[tuple]            # group key tuples (host python values)
-    partials: list[np.ndarray]   # per agg: [lanes][num_groups] arrays
-    counts: np.ndarray           # rows per group
+    keys: per group-key part, a (data, valid) numpy column in group
+    order (none for a scalar aggregate, whose groups `counts` counts);
+    partials: per agg, its [lanes][num_groups] arrays; counts: rows per
+    group."""
+
+    keys: list[tuple[np.ndarray, np.ndarray]]
+    partials: list[np.ndarray]
+    counts: np.ndarray
+
+    @property
+    def num_groups(self) -> int:
+        return len(self.counts)
+
+    def key_tuples(self) -> list[tuple]:
+        """The group keys as tuples of host python values (None for
+        NULL), for the consumers that merge or print row by row."""
+        parts = [[x if ok else None for x, ok in
+                  zip(d.tolist(), v.tolist())] for d, v in self.keys]
+        return list(zip(*parts)) if parts else [()] * self.num_groups
 
 
-def finalize_group_result(chunk: Chunk, group_exprs, aggs, gidx: np.ndarray,
+def finalize_group_result(chunk: Chunk, group_exprs, aggs,
                           rep_rows: np.ndarray, lanes_per_agg,
                           counts: np.ndarray) -> GroupResult:
-    """Shared host tail of the device kernels: recover exact group-key
-    values from representative rows (strings included — host path),
-    materialize FIRST_ROW values, and package a GroupResult.
+    """Shared host tail of the device kernels: evaluate the group keys'
+    exact values on the representative rows (strings included — host
+    path) as the result's key columns, materialize FIRST_ROW values,
+    and package a GroupResult.
 
-    lanes_per_agg: per agg, the [num_live_groups]-length lane arrays
-    (already gathered at gidx)."""
+    rep_rows: each live group's representative row; lanes_per_agg:
+    per agg, the lane arrays already gathered at the live groups."""
     sub = chunk.take(rep_rows)
-    key_cols = []
+    keys = []
     for g in group_exprs:
         d, v = g.eval(sub)
-        key_cols.append([None if not v[i] else
-                         (d[i].item() if hasattr(d[i], "item") else d[i])
-                         for i in range(len(gidx))])
-    keys = list(zip(*key_cols)) if key_cols else [()] * len(gidx)
+        keys.append((np.asarray(d), np.asarray(v, dtype=bool)))
     partials = []
     for a, ls in zip(aggs, lanes_per_agg):
         if a.fn == AggFunc.FIRST_ROW:
@@ -741,7 +755,7 @@ class HashAggKernel:
         gidx = np.flatnonzero(live)
         lanes_at = [[l[gidx] for l in ls] for ls in lanes]
         return finalize_group_result(chunk, self.group_exprs, self.aggs,
-                                     gidx, rep[gidx], lanes_at, counts[gidx])
+                                     rep[gidx], lanes_at, counts[gidx])
 
     def __call__(self, chunk: Chunk, dev_cols=None) -> GroupResult:
         return self.finalize(chunk, self.dispatch(chunk,
@@ -811,7 +825,7 @@ class ScalarAggKernel:
                     val = 0
                 ls = [np.array([val]), hasv.astype(np.int64)]
             partials.append(ls)
-        return GroupResult(keys=[()], partials=partials, counts=count)
+        return GroupResult(keys=[], partials=partials, counts=count)
 
     def __call__(self, chunk: Chunk, dev_cols=None) -> GroupResult:
         return self.finalize(chunk, self.dispatch(chunk,
@@ -888,10 +902,121 @@ def kernel_for(filter_expr, group_exprs, aggs, capacity: int = 4096):
     return k
 
 
+# What the columnar merge serves: the aggregates whose merge is a fold
+# of fixed-width lanes (GROUP_CONCAT joins strings, the rest have no
+# lane merge here)
+_COLUMNAR_FNS = frozenset({AggFunc.COUNT, AggFunc.SUM, AggFunc.AVG,
+                           AggFunc.MIN, AggFunc.MAX, AggFunc.FIRST_ROW})
+# Columnar partials wait unmerged until they hold this many rows beyond
+# four times the merged state's, or this many partials: the held rows
+# stay within a few times the groups, as the dict's entries do
+_FOLD_ROWS = 1 << 20
+_FOLD_PARTS = 64
+
+
+def _fold_step(fn, st, x, g) -> None:
+    """One partial's lanes `x` folded into the state rows `g` (at most
+    one element per row), as HashAggregator._update_dict folds them."""
+    if fn == AggFunc.COUNT:
+        st[0][g] += x[0]
+    elif fn in (AggFunc.SUM, AggFunc.AVG):
+        st[0][g] += x[0]
+        if fn == AggFunc.SUM:
+            st[1][g] = np.maximum(st[1][g], x[1])
+        else:
+            st[1][g] += x[1]
+    elif fn in (AggFunc.MIN, AggFunc.MAX):
+        cur, has, live = st[0][g], st[1][g], x[1] > 0
+        # python's min(cur, new) keeps cur unless new < cur (NaN, -0.0)
+        better = x[0] < cur if fn == AggFunc.MIN else x[0] > cur
+        st[0][g] = np.where(live & ((has <= 0) | better), x[0], cur)
+        st[1][g] = np.where(live, 1, has)
+    else:   # FIRST_ROW: the first partial that has a value
+        take = (st[1][g] == 0) & (x[1] > 0)
+        st[0][g] = np.where(take, x[0], st[0][g])
+        st[1][g] = np.where(take, 1, st[1][g])
+
+
+# lint: exempt[memtrack-alloc] the held partials' rows, billed by the caller through HashAggregator.approx_bytes
+def _fold(parts: list[GroupResult], aggs) -> GroupResult:
+    """Merge columnar partials into one partial with a row per distinct
+    key, in key order (each key part ascending, NULL last): concatenate,
+    one stable lexsort, segment starts, then every lane folded in
+    arrival order, so each group's state is bit for bit what the dict
+    merge accumulates and its key the first-seen variant (-0.0 / 0.0)."""
+    keys = []
+    for j in range(len(parts[0].keys)):
+        d = np.concatenate([p.keys[j][0] for p in parts])
+        v = np.concatenate([p.keys[j][1] for p in parts]).astype(bool)
+        keys.append((np.where(v, d, d.dtype.type(0)), v))
+    lanes = [[np.concatenate([np.asarray(p.partials[ai][li])
+                              for p in parts])
+              for li in range(len(parts[0].partials[ai]))]
+             for ai in range(len(aggs))]
+    counts = np.concatenate([np.asarray(p.counts) for p in parts])
+    n = len(counts)
+    by = []
+    for d, v in reversed(keys):     # lexsort: the last key is primary
+        by.append(d)
+        if not v.all():
+            by.append(~v)
+    order = np.lexsort(by) if by else np.arange(n)
+    keys = [(d[order], v[order]) for d, v in keys]
+    new = np.zeros(n, dtype=bool)
+    new[0] = True
+    for d, v in keys:
+        new[1:] |= (d[1:] != d[:-1]) | (v[1:] != v[:-1])
+    starts = np.flatnonzero(new)
+    lanes = [[lane[order] for lane in ls] for ls in lanes]
+    state = [[lane[starts] for lane in ls] for ls in lanes]
+    rest = np.flatnonzero(~new)
+    if rest.size:
+        # a segment's k-th element folds in pass k: one element a group
+        # a pass, and the passes run in arrival order
+        sid = np.cumsum(new) - 1
+        rank = rest - starts[sid[rest]]
+        by_rank = np.argsort(rank, kind="stable")
+        rest, rank = rest[by_rank], rank[by_rank]
+        for pos in np.split(rest, np.flatnonzero(np.diff(rank)) + 1):
+            g = sid[pos]
+            for agg, st, ls in zip(aggs, state, lanes):
+                _fold_step(agg.fn, st, [lane[pos] for lane in ls], g)
+    return GroupResult(keys=[(d[starts], v[starts]) for d, v in keys],
+                       partials=state,
+                       counts=np.add.reduceat(counts[order], starts))
+
+
+def _avg_decimal(agg: AggDesc, s, c) -> int:
+    """Scaled-int AVG: rescale the sum by the extra frac, then divide in
+    EXACT integer arithmetic (half-up; float division corrupts wide
+    decimals)."""
+    extra = agg.result_ft.frac - agg.arg.ft.frac
+    num = int(s) * (10 ** extra)
+    den = int(c)
+    q, r = divmod(abs(num), den)
+    if 2 * r >= den:
+        q += 1
+    return q if num >= 0 else -q
+
+
+def _columns_nbytes(r: GroupResult) -> int:
+    return r.counts.nbytes + sum(d.nbytes + v.nbytes for d, v in r.keys) \
+        + sum(np.asarray(lane).nbytes for ls in r.partials for lane in ls)
+
+
 class HashAggregator:
     """Stateful final aggregator: merges chunk partials on the host and
     finalizes per-group values. Mirrors Aggregation.GetPartialResult
-    merging (expression/aggregation/aggregation.go:32-47)."""
+    merging (expression/aggregation/aggregation.go:32-47).
+
+    Two merges, chosen by what the partials show. Columnar: every key
+    part and lane a fixed-width numpy column of one dtype across the
+    partials, no _ci key, no NaN key, only COUNT / SUM / AVG / MIN /
+    MAX / FIRST_ROW — the partials are held as columns and folded by
+    one sort (_fold). Otherwise a dict entry a group, merged row by
+    row; a partial that does not fit moves the columns held so far into
+    the dict, in arrival order. Both give the same rows in the same
+    order."""
 
     def __init__(self, aggs: Sequence[AggDesc], group_meta=None):
         """group_meta: the group-key expressions OR FieldTypes, in key
@@ -904,6 +1029,19 @@ class HashAggregator:
         # first-seen variant
         self._ci = [getattr(g, "ft", g).is_ci for g in group_meta] \
             if group_meta else None
+        columnar = all(a.fn in _COLUMNAR_FNS for a in self.aggs) and \
+            not any(self._ci or ())
+        # the columnar state: partials held in arrival order (the first
+        # may be the fold of earlier ones); None on the dict path
+        self._cols: list[GroupResult] | None = [] if columnar else None
+        self._dtypes = None        # the key and lane dtypes they share
+        self._held = 0             # rows in _cols
+        self._folded = 0           # of which the fold at _cols[0] holds
+
+    @property
+    def path(self) -> str:
+        """Which merge this aggregator's results come from."""
+        return "dict" if self._cols is None else "columnar"
 
     def _group_key(self, key: tuple) -> tuple:
         if not self._ci or not any(self._ci):
@@ -913,11 +1051,14 @@ class HashAggregator:
                      for x, c in zip(key, self._ci))
 
     def approx_bytes(self) -> int:
-        """Rough host footprint of the merged state — dict slots, key
-        tuples and per-agg lane scalars at CPython object costs. This is
-        the number memtrack bounds under tidb_tpu_mem_quota_query: it
-        scales with the live GROUP COUNT (the quantity that actually
-        grows without bound on a runaway aggregation), not the input."""
+        """Rough host footprint of the merged state. Columnar: the held
+        arrays' nbytes. Dict: dict slots, key tuples and per-agg lane
+        scalars at CPython object costs. This is the number memtrack
+        bounds under tidb_tpu_mem_quota_query: it scales with the live
+        GROUP COUNT (the quantity that actually grows without bound on
+        a runaway aggregation), not the input."""
+        if self._cols is not None:
+            return sum(_columns_nbytes(r) for r in self._cols)
         n = len(self._state)
         if n == 0:
             return 0
@@ -926,8 +1067,40 @@ class HashAggregator:
         key = next(iter(self._orig.values()))
         return n * (96 + 56 * len(key) + 48 * lanes)
 
+    def _fits(self, res: GroupResult) -> bool:
+        """Whether `res` can join the columnar state."""
+        dtypes = tuple(d.dtype for d, _v in res.keys) + tuple(
+            np.asarray(lane).dtype for ls in res.partials for lane in ls)
+        if self._dtypes is None:
+            if any(dt.kind not in "biuf" for dt in dtypes):
+                return False
+        elif dtypes != self._dtypes:
+            return False
+        if any(d.dtype.kind == "f" and np.isnan(d[v]).any()
+               for d, v in res.keys):
+            return False        # NaN keys: a group each, as in the dict
+        self._dtypes = dtypes
+        return True
+
     def update(self, res: GroupResult) -> None:
-        for gi, key in enumerate(res.keys):
+        if not res.num_groups:
+            return
+        if self._cols is not None:
+            if self._fits(res):
+                self._cols.append(res)
+                self._held += res.num_groups
+                if len(self._cols) > _FOLD_PARTS or \
+                        self._held > 4 * self._folded + _FOLD_ROWS:
+                    self._cols = [_fold(self._cols, self.aggs)]
+                    self._held = self._folded = self._cols[0].num_groups
+                return
+            held, self._cols = self._cols, None
+            for r in held:
+                self._update_dict(r)
+        self._update_dict(res)
+
+    def _update_dict(self, res: GroupResult) -> None:
+        for gi, key in enumerate(res.key_tuples()):
             gkey = self._group_key(key)
             st = self._state.get(gkey)
             if st is None:
@@ -966,9 +1139,53 @@ class HashAggregator:
                         else:
                             cur[0], cur[1] = lanes[0][gi], 1
 
+    # lint: exempt[memtrack-alloc] group-count-sized outputs, bounded by the tracked agg state (approx_bytes)
+    def result_columns(self):
+        """Columnar path: -> (groups, [(data, valid)] the key parts then
+        each aggregate's final value), rows in results() order, data 0
+        under NULL; AVG finalized, SUM/AVG of decimals scaled ints;
+        (0, []) before any group. None on the dict path."""
+        if self._cols is None:
+            return None
+        if not self._cols:
+            return 0, []
+        merged = _fold(self._cols, self.aggs)
+        self._cols = [merged]
+        self._held = self._folded = merged.num_groups
+        out = list(merged.keys)
+        for agg, st in zip(self.aggs, merged.partials):
+            if agg.fn == AggFunc.COUNT:
+                out.append((st[0], np.ones(len(st[0]), dtype=bool)))
+                continue
+            valid = st[1] != 0
+            data = st[0]
+            if agg.fn == AggFunc.AVG:
+                if agg.result_ft.eval_type == EvalType.DECIMAL:
+                    # exact, in python ints: once an output group
+                    data = np.array([_avg_decimal(agg, x, k) if ok else 0
+                                     for x, k, ok in zip(data, st[1], valid)],
+                                    dtype=object)
+                else:
+                    data = np.divide(data.astype(np.float64),
+                                     st[1].astype(np.float64),
+                                     out=np.zeros(len(data)), where=valid)
+            out.append((np.where(valid, data, data.dtype.type(0)), valid))
+        return merged.num_groups, out
+
     def results(self) -> list[tuple[tuple, list]]:
-        """-> [(key, [final agg values])] with AVG finalized; SUM/AVG of
-        decimals stay scaled ints (callers format via the agg result_ft)."""
+        """-> [(key, [final agg values])] in key order (NULL last in
+        each key part) with AVG finalized; SUM/AVG of decimals stay
+        scaled ints (callers format via the agg result_ft)."""
+        got = self.result_columns()
+        if got is not None:
+            n, cols = got
+            if not n:
+                return []
+            vals = [[x if ok else None for x, ok in
+                     zip(d.tolist(), v.tolist())] for d, v in cols]
+            nk = len(cols) - len(self.aggs)
+            return [(tuple(c[i] for c in vals[:nk]),
+                     [c[i] for c in vals[nk:]]) for i in range(n)]
         out = []
         for key, st in sorted(self._state.items(),
                               key=lambda kv: tuple(
@@ -985,16 +1202,7 @@ class HashAggregator:
                     if cur[1] == 0:
                         vals.append(None)
                     elif agg.result_ft.eval_type == EvalType.DECIMAL:
-                        # scaled-int avg: rescale sum by extra frac then
-                        # divide in EXACT integer arithmetic (half-up;
-                        # float division corrupts wide decimals)
-                        extra = agg.result_ft.frac - agg.arg.ft.frac
-                        num = int(cur[0]) * (10 ** extra)
-                        den = int(cur[1])
-                        q, r = divmod(abs(num), den)
-                        if 2 * r >= den:
-                            q += 1
-                        vals.append(q if num >= 0 else -q)
+                        vals.append(_avg_decimal(agg, cur[0], cur[1]))
                     else:
                         vals.append(float(cur[0]) / float(cur[1]))
                 elif fn in (AggFunc.MIN, AggFunc.MAX, AggFunc.FIRST_ROW,

@@ -56,7 +56,7 @@ def _host_agg_vectorized(chunk: Chunk, mask, group_exprs, aggs
     nlive = len(live)
     gcols = [(d, v) for d, v in _eval_cols(group_exprs, chunk)]
     if nlive == 0:
-        return GroupResult(keys=[], partials=[
+        return GroupResult(keys=_key_columns(gcols, live), partials=[
             _states_to_lanes(a, []) for a in aggs],
             counts=np.zeros(0, dtype=np.int64))
     lanes = []
@@ -85,21 +85,19 @@ def _host_agg_vectorized(chunk: Chunk, mask, group_exprs, aggs
     rows = live[order]                    # original row index per position
     counts = np.add.reduceat(np.ones(nlive, dtype=np.int64), starts)
 
-    # group keys from each segment's first row
-    rep = rows[starts]
-    keys_cols = []
-    for d, v in gcols:
-        dv, vv = np.asarray(d)[rep], np.asarray(v)[rep]
-        keys_cols.append([None if not vv[i] else
-                          (dv[i].item() if hasattr(dv[i], "item") else dv[i])
-                          for i in range(ngroups)])
-    keys = list(zip(*keys_cols)) if keys_cols else [()] * ngroups
-
     partials = []
     for a in aggs:
         partials.append(_agg_lanes_vectorized(a, chunk, rows, starts, gid,
                                               ngroups, counts))
-    return GroupResult(keys=keys, partials=partials, counts=counts)
+    # group keys from each segment's first row
+    return GroupResult(keys=_key_columns(gcols, rows[starts]),
+                       partials=partials, counts=counts)
+
+
+def _key_columns(gcols, rows) -> list:
+    """GroupResult key columns: each group column at `rows`."""
+    return [(np.asarray(d)[rows], np.asarray(v, dtype=bool)[rows])
+            for d, v in gcols]
 
 
 # lint: exempt[memtrack-alloc] group-count-scaled agg outputs, bounded by the tracked agg state
@@ -219,7 +217,7 @@ def _host_agg_rowloop(chunk: Chunk, mask, group_exprs,
              for a in aggs]
 
     groups: dict[tuple, int] = {}
-    keys: list[tuple] = []
+    reps: list[int] = []        # per group: its first row
     states: list[list] = []     # per group: per agg: lanes
     counts: list[int] = []
 
@@ -238,9 +236,9 @@ def _host_agg_rowloop(chunk: Chunk, mask, group_exprs,
                      for x, c in zip(key, ci))
         gi = groups.get(gkey)
         if gi is None:
-            gi = len(keys)
+            gi = len(reps)
             groups[gkey] = gi
-            keys.append(key)
+            reps.append(i)
             counts.append(0)
             states.append([_init_state(a) for a in aggs])
         counts[gi] += 1
@@ -251,7 +249,8 @@ def _host_agg_rowloop(chunk: Chunk, mask, group_exprs,
     for ai, a in enumerate(aggs):
         lanes = _states_to_lanes(a, [s[ai] for s in states])
         partials.append(lanes)
-    return GroupResult(keys=keys, partials=partials,
+    return GroupResult(keys=_key_columns(gcols, np.array(reps, np.int64)),
+                       partials=partials,
                        counts=np.array(counts, dtype=np.int64))
 
 
@@ -273,7 +272,7 @@ def host_scalar_agg(chunk: Chunk, filter_expr: Expression | None,
             _update_state(a, states[ai], acols[ai], i)
     partials = [_states_to_lanes(a, [states[ai]])
                 for ai, a in enumerate(aggs)]
-    return GroupResult(keys=[()], partials=partials,
+    return GroupResult(keys=[], partials=partials,
                        counts=np.array([cnt], dtype=np.int64))
 
 

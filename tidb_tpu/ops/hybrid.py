@@ -567,16 +567,16 @@ def concat_group_results(results: list[GroupResult],
                          aggs) -> GroupResult:
     """Merge per-partition GroupResults whose key sets are DISJOINT
     (the partition id is a function of the full key hash, so a group
-    never spans partitions) by plain concatenation."""
-    results = [r for r in results if r is not None and len(r.keys)]
+    never spans partitions) by plain concatenation, column by column."""
+    results = [r for r in results if r is not None and r.num_groups]
     if len(results) == 1:
         return results[0]
     if not results:
         return GroupResult(keys=[], partials=[[] for _ in aggs],
                            counts=np.empty(0, dtype=np.int64))
-    keys = []
-    for r in results:
-        keys.extend(r.keys)
+    keys = [tuple(np.concatenate([r.keys[j][i] for r in results])
+                  for i in (0, 1))
+            for j in range(len(results[0].keys))]
     partials = []
     for ai in range(len(aggs)):
         nlanes = len(results[0].partials[ai])

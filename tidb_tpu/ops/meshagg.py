@@ -277,9 +277,9 @@ class MeshAggKernel(MeshKernelBase):
 
     def finish(self, outs, chunk: Chunk) -> GroupResult:
         """Blocking half: one batched device→host readback + host tail."""
-        gidx, rep_rows, lanes_at, counts = self.finalize(outs)
+        _gidx, rep_rows, lanes_at, counts = self.finalize(outs)
         return finalize_group_result(chunk, self.group_exprs, self.aggs,
-                                     gidx, rep_rows, lanes_at, counts)
+                                     rep_rows, lanes_at, counts)
 
     def __call__(self, chunk: Chunk) -> GroupResult:
         return self.finish(self.launch(chunk), chunk)

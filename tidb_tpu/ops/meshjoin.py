@@ -485,7 +485,7 @@ class MeshLookupAggKernel(MeshKernelBase):
             else:
                 fixed_lanes.append(ls)
         return finalize_group_result(mini, self.group_exprs, self.aggs,
-                                     gidx, rep_local, fixed_lanes, counts)
+                                     rep_local, fixed_lanes, counts)
 
     def _host_chain(self, mini: Chunk) -> Chunk:
         """Append payload columns for the (matched) mini rows on the host,

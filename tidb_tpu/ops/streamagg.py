@@ -121,8 +121,7 @@ class SegmentAggKernel:
         gidx = np.arange(nseg)
         lanes_at = [[l[gidx] for l in ls] for ls in lanes]
         return finalize_group_result(chunk, self.group_exprs, self.aggs,
-                                     gidx, rep[gidx], lanes_at,
-                                     counts[gidx])
+                                     rep[gidx], lanes_at, counts[gidx])
 
     def __call__(self, chunk: Chunk, dev_cols=None) -> GroupResult:
         return self.finalize(chunk, self.dispatch(chunk,

@@ -455,7 +455,7 @@ def _wrap_iter(fn, st: OpStats):
             if n is None:
                 # agg-pushdown readers yield GroupResult partials: count
                 # the groups they carry, not zero
-                n = len(getattr(out, "keys", ()) or ())
+                n = getattr(out, "num_groups", 0)
             st.act_rows += n
             yield out
 
